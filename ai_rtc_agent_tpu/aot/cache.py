@@ -1,4 +1,4 @@
-"""AOT compilation + serialized-executable cache.
+"""AOT export + serialized-program cache.
 
 TPU-native replacement for the reference's TensorRT engine layer: the
 ONNX->TRT compile pipeline (reference lib/wrapper.py:712-915), the engine
@@ -6,10 +6,13 @@ cache key discipline (:732-746), the on-disk layout
 ``engines--<model>/{unet,vae_encoder,vae_decoder}.engine`` (:593-597,
 896-910) and the "load engines without base weights" fast path (:409-512).
 
-Here an "engine" is a serialized ``jax.export`` artifact (StableHLO +
-calling convention): portable across processes, loaded without re-tracing
-the python model code.  On first use per (key x platform) we export, compile
-and persist; subsequent server starts deserialize and run.
+Here an "engine" is a serialized ``jax.export`` artifact: StableHLO plus the
+calling convention, NOT a compiled executable.  Adopting one skips python
+tracing and lowering of the model code; the XLA compile of that StableHLO
+still runs in every process that loads it, and it is the minutes-long part
+at real geometry.  What makes a second boot fast is XLA's persistent compile
+cache (``utils/device.configure_compile_cache``), which holds the compiled
+executable for the AOT and the plain-jit path alike.
 
 Key discipline mirrors the reference exactly:
     model x mode x min/max batch x resolution x dtype x code-version
@@ -94,7 +97,7 @@ def _digest(key: str, args_spec: str, platform: str) -> str:
 
 @dataclass
 class EngineCache:
-    """Directory-backed cache of serialized XLA executables."""
+    """Directory-backed cache of serialized ``jax.export`` programs."""
 
     cache_dir: str | None = None
 
@@ -136,17 +139,25 @@ class EngineCache:
         d, blob_path, meta_path = self._paths(key, digest)
 
         if os.path.exists(blob_path):
+            with open(blob_path, "rb") as f:
+                blob = f.read()
             try:
-                with open(blob_path, "rb") as f:
-                    blob = f.read()
                 exp = jax_export.deserialize(blob)
+            except Exception as e:
+                # a truncated or foreign blob: the flatbuffer reader raises
+                # whatever it trips over (struct.error, AttributeError,
+                # ValueError ...), so this one call is caught broadly — a
+                # logged miss, and the caller compiles as if it were absent
+                logger.warning(
+                    "engine cache entry %s unreadable (%s: %s)",
+                    blob_path, type(e).__name__, e,
+                )
+            else:
                 logger.info("engine cache HIT %s (%s)", key, digest)
                 # device telemetry (obs/devtel.py): hit counter + the
                 # on-disk inventory gauges refresh at this (rare) touch
                 devtel.note_aot("hit", cache=self)
                 return _donating_call(exp, donate_argnums)
-            except Exception as e:  # corrupted/incompatible
-                logger.warning("engine cache entry unreadable (%s)", e)
         devtel.note_aot("miss", cache=self)
         if not build:
             return None
@@ -154,8 +165,7 @@ class EngineCache:
         logger.info("engine cache MISS %s — compiling (first run is slow)", key)
         t0 = time.time()
         # the compile watchdog attributes the build's XLA compile to the
-        # engine key; in the no-monitoring fallback the measured build
-        # time below doubles as the compile record (note_aot "build")
+        # engine key
         with devtel.compile_scope(key):
             jitted = jax.jit(fn, donate_argnums=donate_argnums)
             exp = jax_export.export(jitted)(*specs)
@@ -180,9 +190,7 @@ class EngineCache:
                 indent=2,
             )
         logger.info("engine built in %.1fs -> %s", time.time() - t0, blob_path)
-        devtel.note_aot(
-            "build", seconds=time.time() - t0, cache=self, context=key,
-        )
+        devtel.note_aot("build", seconds=time.time() - t0, cache=self)
         return _donating_call(exp, donate_argnums)
 
     def stats(self) -> tuple:

@@ -220,6 +220,9 @@ def main(argv=None):
              "occupancy; already-cached buckets are skipped)",
     )
     args = ap.parse_args(argv)
+    from ..utils.device import require_device
+
+    require_device()  # an engine is built for the device that will serve it
     lora_dict = {}
     for spec in args.lora:
         path, _, scale = spec.rpartition(":")

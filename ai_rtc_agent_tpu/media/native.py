@@ -1,9 +1,10 @@
 """ctypes bindings to the native media runtime (native/libtpurtc.so).
 
-Auto-builds the library with make on first use when a toolchain is present
-(the library itself has zero build-time deps; libavcodec is dlopen'd at
-runtime).  All consumers must handle ``None`` returns from the loaders and
-fall back to pure-python paths (media/codec.py NullCodec, media/rtp.py).
+The library is built from ``native/*.cpp`` with make on first use and is
+never committed (the library itself has zero build-time deps; libavcodec is
+dlopen'd at runtime).  Consumers that have a pure-python path handle a
+``None`` from :func:`load` (media/codec.py NullCodec, media/rtp.py); the
+native-rtp provider has none and calls :func:`require`.
 """
 
 from __future__ import annotations
@@ -15,54 +16,66 @@ import subprocess
 
 logger = logging.getLogger(__name__)
 
-_NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
-_LIB_PATH = os.path.abspath(os.path.join(_NATIVE_DIR, "libtpurtc.so"))
+_NATIVE_DIR = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", "..", "native")
+)
+_LIB_PATH = os.path.join(_NATIVE_DIR, "libtpurtc.so")
 
 _lib = None
 _lib_tried = False
+_load_error = ""
+
+
+def _build():
+    """make the library under a private name, then rename it into place:
+    several processes may start in a fresh checkout at once, and none may
+    dlopen a half-written file."""
+    tmp = f"libtpurtc.so.{os.getpid()}.tmp"
+    try:
+        subprocess.run(
+            ["make", "-C", _NATIVE_DIR, f"OUT={tmp}"],
+            check=True, capture_output=True, text=True, timeout=300,
+        )
+        os.replace(os.path.join(_NATIVE_DIR, tmp), _LIB_PATH)
+    finally:
+        try:
+            os.remove(os.path.join(_NATIVE_DIR, tmp))
+        except FileNotFoundError:
+            pass
 
 
 def load() -> ctypes.CDLL | None:
-    global _lib, _lib_tried
+    global _lib, _lib_tried, _load_error
     if _lib_tried:
         return _lib
     _lib_tried = True
-    stale = False
-    if os.path.exists(_LIB_PATH):
-        # rebuild when any source is newer than the library (a stale .so
-        # missing newly added symbols would poison every native consumer)
-        so_mtime = os.path.getmtime(_LIB_PATH)
-        for f in os.listdir(_NATIVE_DIR):
-            if f.endswith((".cpp", ".h")) and os.path.getmtime(
-                os.path.join(_NATIVE_DIR, f)
-            ) > so_mtime:
-                stale = True
-                break
-    if not os.path.exists(_LIB_PATH) or stale:
+    if not os.path.exists(_LIB_PATH):
         try:
-            subprocess.run(
-                ["make", "-C", os.path.abspath(_NATIVE_DIR)],
-                check=True,
-                capture_output=True,
-                timeout=120,
+            _build()
+        except (subprocess.SubprocessError, OSError) as e:
+            _load_error = f"building {_LIB_PATH} failed: {e}" + (
+                f"\n{e.stderr}" if getattr(e, "stderr", None) else ""
             )
-        except (subprocess.SubprocessError, FileNotFoundError) as e:
-            if not os.path.exists(_LIB_PATH):
-                logger.warning("native build failed (%s); using python fallbacks", e)
-                return None
-            # stale-but-present: prefer the committed .so over nothing —
-            # git checkouts randomize mtimes, so "stale" is often noise on
-            # boxes without a toolchain (code-review r3)
-            logger.warning(
-                "native rebuild failed (%s); loading the existing library", e
-            )
+            logger.warning("%s; using python fallbacks", _load_error)
+            return None
     try:
         lib = ctypes.CDLL(_LIB_PATH)
     except OSError as e:
-        logger.warning("cannot load %s (%s)", _LIB_PATH, e)
+        _load_error = f"cannot load {_LIB_PATH} ({e})"
+        logger.warning("%s", _load_error)
         return None
     _declare(lib)
     _lib = lib
+    return lib
+
+
+def require() -> ctypes.CDLL:
+    """The library or a RuntimeError carrying why it could not be built or
+    loaded — for callers with no python fallback (the native-rtp provider
+    when it was asked for by name)."""
+    lib = load()
+    if lib is None:
+        raise RuntimeError(f"native media runtime unavailable: {_load_error}")
     return lib
 
 

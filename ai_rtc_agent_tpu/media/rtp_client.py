@@ -1,8 +1,10 @@
 """Reusable native-RTP client: the peer-side loop of the media plane.
 
-Shared by the live example (examples/native_rtp_client.py) and the
-glass-to-glass measurement (scripts/glass_check.py) so the offer envelope,
-socket plumbing and the feed/poll drain discipline exist exactly once.
+Shared by the live example (examples/native_rtp_client.py), the
+glass-to-glass measurement (scripts/glass_check.py) and chip_smoke.py so the
+offer envelope, socket plumbing and the feed/poll drain discipline exist
+exactly once.  Nothing here imports JAX: the smoke's parent process drives
+this client while a child process holds the chip.
 
 The drain interleaves ``feed_packet`` with ``poll``: the receive ring is a
 4-slot latest-wins buffer, so feeding a whole burst before popping would
@@ -126,10 +128,23 @@ class NativeRtpClient:
         socket (sendmmsg when available, sendto loop otherwise)."""
         self._out.flush(pkts)
 
-    def drain(self) -> int:
+    def drain(self, on_frame=None) -> int:
         """Feed every queued packet, polling decoded frames AFTER EACH feed
-        (latest-wins ring: batch-feeding would evict).  -> frames received."""
+        (latest-wins ring: batch-feeding would evict).  -> frames received.
+        ``on_frame(rgb, pts)`` sees each decoded frame (a client that only
+        counts passes nothing)."""
         got = 0
+
+        def poll_all():
+            nonlocal got
+            while True:
+                frame = self.back.poll()
+                if frame is None:
+                    return
+                got += 1
+                if on_frame is not None:
+                    on_frame(*frame)
+
         while True:
             entry = self._recv_q.pop()
             if entry is None:
@@ -140,14 +155,11 @@ class NativeRtpClient:
                 # drain is synchronous — schedule-late == deliver-late)
                 for d, _delay in self._rx_faults.apply(data):
                     self.back.feed_packet(d)
-                    while self.back.poll() is not None:
-                        got += 1
+                    poll_all()
                 continue
             self.back.feed_packet(data)
-            while self.back.poll() is not None:
-                got += 1
-        while self.back.poll() is not None:
-            got += 1
+            poll_all()
+        poll_all()
         return got
 
     def close(self):

@@ -126,10 +126,10 @@ def default_stream_config(model_id: str, **overrides) -> StreamConfig:
         )
     base.update(overrides)
     # fused Pallas epilogue on real TPUs (interpret-mode is slow on CPU).
-    # FUSED_EPILOGUE=0 is the operator kill-switch: if the kernel miscompiles
-    # at a new geometry the agent can be relaunched on the composed-XLA path
-    # without a code change (the serving pipeline also auto-falls-back at
-    # build time — stream/pipeline._probe_pallas_fallback).
+    # FUSED_EPILOGUE=0 is the operator kill-switch: if the kernel fails to
+    # compile at a new geometry the boot fails with the compiler's message
+    # (stream/pipeline._warm_up) and the agent can be relaunched on the
+    # composed-XLA path without a code change.
     base.setdefault("use_fused_epilogue", current_fused_epilogue())
     # bf16 compute on real TPUs (fp32 elsewhere): the SERVING default must
     # match what the bench measures — fp32 serving on TPU would halve MXU

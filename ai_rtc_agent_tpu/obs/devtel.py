@@ -34,6 +34,11 @@ hot-path hook is one module-global read + None test, banked as
   on-disk inventory (``aot_cache_entries``/``aot_cache_bytes``) emitted
   by aot/cache.py at each (rare) cache touch, so scrapes never scan
   disk.
+* **Persistent compile cache** — hits and misses of XLA's on-disk
+  executable cache (``compile_cache_hits_total`` /
+  ``compile_cache_misses_total``, from ``jax.monitoring``'s
+  ``/jax/compilation_cache/*`` events): a warm boot shows hits, a boot
+  that compiled everything shows misses.
 * **Transfer accounting** — H2D bytes/count from the single
   :func:`~..stream.engine.stage_frame` staging path, D2H bytes/count
   from the blessed readback sites (the scheduler's per-row resolve, the
@@ -45,13 +50,6 @@ hot-path hook is one module-global read + None test, banked as
   CPU returns nothing) and the live-buffer count, sampled on the
   overload ladder tick (``DEVTEL_MEM_INTERVAL_S`` rate limit; the
   /metrics scrape itself only reads the cached sample).
-
-Fallback ("wrap the cache"): when ``jax.monitoring`` has no listener
-API, the compile sites this repo owns still feed the watchdog — the AOT
-cache build path reports its measured build time and the scheduler's
-prewarm loop times its eager ``.compile()`` calls
-(``compile_scope(..., fallback_record=True)``).  Only raw lazy-jit
-compiles outside those sites go unseen in that mode.
 
 Knobs (docs/environment.md "Device telemetry"): ``DEVTEL_ENABLE``,
 ``DEVTEL_RETRACE_MIN_MS``, ``DEVTEL_MEM_INTERVAL_S``,
@@ -73,10 +71,14 @@ logger = logging.getLogger(__name__)
 PHASE_WARMUP = "warmup"
 PHASE_SERVING = "serving"
 
-# the jax.monitoring event one XLA compile fires exactly once (verified
-# against jax 0.4.x; lowering/tracing durations ride separate events we
-# deliberately ignore — backend compile time IS the serve-time freeze)
+# the jax.monitoring event one XLA compile fires exactly once — also when
+# the persistent cache serves the executable, then with the retrieval time
+# (lowering/tracing durations ride separate events we deliberately ignore:
+# backend compile time IS the serve-time freeze)
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# persistent compile cache: one event per executable found / written
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
 
 class DevTelPlane:
@@ -128,6 +130,9 @@ class DevTelPlane:
         self.aot_build_seconds = 0.0
         self.aot_entries = 0
         self.aot_bytes = 0
+        # XLA persistent compile cache (jax.monitoring events)
+        self.compile_cache_hits = 0
+        self.compile_cache_misses = 0
         # transfer accounting (fed by the blessed staging/readback paths)
         self.h2d_transfers = 0
         self.h2d_bytes = 0
@@ -155,7 +160,7 @@ class DevTelPlane:
 
     def record_compile(self, duration_s: float, context=None,
                        expected: bool = False):
-        """One XLA compile (listener dispatch or fallback site).  Breach
+        """One XLA compile (listener dispatch).  Breach
         iff serving-phase, not blessed by an expected scope, and at
         least ``DEVTEL_RETRACE_MIN_MS`` (host-side state builds compile
         tiny eager ops; a sub-threshold compile is recorded but is not
@@ -200,6 +205,13 @@ class DevTelPlane:
                     cb(dict(entry))
                 except Exception:  # observability must never break serving
                     logger.exception("devtel on_breach handler failed")
+
+    def note_compile_cache(self, hit: bool):
+        with self._lock:
+            if hit:
+                self.compile_cache_hits += 1
+            else:
+                self.compile_cache_misses += 1
 
     # -- AOT accounting (aot/cache.py) -----------------------------------------
 
@@ -292,6 +304,8 @@ class DevTelPlane:
             "aot_cache_builds_total": self.aot_builds,
             "aot_cache_entries": self.aot_entries,
             "aot_cache_bytes": self.aot_bytes,
+            "compile_cache_hits_total": self.compile_cache_hits,
+            "compile_cache_misses_total": self.compile_cache_misses,
             "devtel_h2d_transfers_total": self.h2d_transfers,
             "devtel_h2d_bytes_total": self.h2d_bytes,
             "devtel_d2h_transfers_total": self.d2h_transfers,
@@ -339,22 +353,7 @@ class DevTelPlane:
 
 _ACTIVE: DevTelPlane | None = None
 _LISTENER_INSTALLED = False
-_MONITORING_OK: bool | None = None
 _CTX = threading.local()  # .label / .expected: the compile attribution
-
-
-def monitoring_available() -> bool:
-    global _MONITORING_OK
-    if _MONITORING_OK is None:
-        try:
-            from jax import monitoring
-
-            _MONITORING_OK = hasattr(
-                monitoring, "register_event_duration_secs_listener"
-            )
-        except Exception:
-            _MONITORING_OK = False
-    return _MONITORING_OK
 
 
 def _dispatch(event: str, duration_s: float, **_kw):
@@ -370,23 +369,27 @@ def _dispatch(event: str, duration_s: float, **_kw):
     )
 
 
+def _dispatch_event(event: str, **_kw):
+    if event != _CACHE_HIT_EVENT and event != _CACHE_MISS_EVENT:
+        return
+    plane = _ACTIVE
+    if plane is not None and plane.enabled:
+        plane.note_compile_cache(event == _CACHE_HIT_EVENT)
+
+
 def activate(plane: DevTelPlane) -> DevTelPlane:
     """Make ``plane`` the process's telemetry sink and (once) register
-    the monitoring listener.  Disabled planes are still activated so
+    the monitoring listeners.  Disabled planes are still activated so
     their no-op hooks are the measured off-path."""
     global _ACTIVE, _LISTENER_INSTALLED
     _ACTIVE = plane
-    if plane.enabled and not _LISTENER_INSTALLED and monitoring_available():
+    if plane.enabled and not _LISTENER_INSTALLED:
         from jax import monitoring
 
         monitoring.register_event_duration_secs_listener(_dispatch)
+        monitoring.register_event_listener(_dispatch_event)
         _LISTENER_INSTALLED = True
-    if not plane.enabled:
-        plane.watchdog = "disabled"
-    elif _LISTENER_INSTALLED:
-        plane.watchdog = "jax-monitoring"
-    else:
-        plane.watchdog = "cache-wrap"  # fallback: owned compile sites only
+    plane.watchdog = "jax-monitoring" if plane.enabled else "disabled"
     return plane
 
 
@@ -403,12 +406,6 @@ def active() -> DevTelPlane | None:
     return _ACTIVE
 
 
-def fallback_recording() -> bool:
-    """True when compiles are only visible through the owned sites
-    (the wrap-the-cache mode) — those sites then self-report timings."""
-    return not _LISTENER_INSTALLED
-
-
 # -- hot-path hooks (one global read + None test when off) -------------------
 
 def note_h2d(nbytes: int):
@@ -423,22 +420,14 @@ def note_d2h(nbytes: int):
         plane.note_d2h(int(nbytes))
 
 
-def note_aot(event: str, seconds: float = 0.0, cache=None, context=None):
+def note_aot(event: str, seconds: float = 0.0, cache=None):
     """AOT cache touch (aot/cache.py).  ``cache``: the EngineCache, so
     the inventory gauges refresh at the (rare) touch instead of per
-    scrape (entry bytes live there — cache.stats()).  A ``build`` in
-    fallback mode doubles as the compile record — the literal
-    wrap-the-cache watchdog."""
+    scrape (entry bytes live there — cache.stats())."""
     plane = _ACTIVE
     if plane is None or not plane.enabled:
         return
     plane.note_aot(event, seconds=seconds)
-    if event == "build" and fallback_recording():
-        plane.record_compile(
-            seconds,
-            context=context or getattr(_CTX, "label", None),
-            expected=getattr(_CTX, "expected", False),
-        )
     if cache is not None:
         try:
             entries, total = cache.stats()
@@ -468,13 +457,11 @@ class _Scope:
     so nested scopes compose — a scheduler state build (expected) inside
     a prewarm attribution keeps both truthful."""
 
-    __slots__ = ("label", "expected", "_record", "_prev", "_t0")
+    __slots__ = ("label", "expected", "_prev")
 
-    def __init__(self, label, expected, fallback_record):
+    def __init__(self, label, expected):
         self.label = label
         self.expected = expected
-        self._record = fallback_record and fallback_recording()
-        self._t0 = None
 
     def __enter__(self):
         self._prev = (
@@ -482,30 +469,17 @@ class _Scope:
         )
         _CTX.label = self.label
         _CTX.expected = self.expected
-        if self._record:
-            self._t0 = time.monotonic()
         return self
 
-    def __exit__(self, exc_type, *exc):
-        if self._t0 is not None and exc_type is None:
-            plane = _ACTIVE
-            if plane is not None and plane.enabled:
-                plane.record_compile(
-                    time.monotonic() - self._t0,
-                    context=self.label, expected=self.expected,
-                )
+    def __exit__(self, *exc):
         _CTX.label, _CTX.expected = self._prev
         return False
 
 
-def compile_scope(label: str, fallback_record: bool = False,
-                  expected: bool = False):
+def compile_scope(label: str, expected: bool = False):
     """Attribute any compile fired inside the body to ``label`` (an
     engine/AOT key or a bucket ``sbucket-<k>:<variant>`` — sharded
     geometries carry the mesh shape as ``sbucket-<k>:<variant>:dp<N>``).
-    With ``fallback_record=True`` and no monitoring listener, the body is
-    timed and reported as the compile itself — ONLY for bodies that are
-    eager compiles by construction (the prewarm ``.compile()`` loop).
     ``expected=True`` additionally blesses the body's compiles (recorded
     + attributed, never a breach): the prewarm sites, which are
     legitimate even at serve time when an operator reshapes the mesh and
@@ -514,7 +488,7 @@ def compile_scope(label: str, fallback_record: bool = False,
     plane = _ACTIVE
     if plane is None or not plane.enabled:
         return _NULL
-    return _Scope(label, expected, fallback_record)
+    return _Scope(label, expected)
 
 
 def expected_scope(label: str = "host-state-build"):
@@ -525,4 +499,4 @@ def expected_scope(label: str = "host-state-build"):
     plane = _ACTIVE
     if plane is None or not plane.enabled:
         return _NULL
-    return _Scope(label, True, False)
+    return _Scope(label, True)

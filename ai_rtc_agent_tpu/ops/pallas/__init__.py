@@ -1,5 +1,59 @@
 """Pallas TPU kernels for the hot fused ops (see /opt/skills/guides/pallas_guide.md).
 
-Kernels ship with an `interpret=True` CPU path so the test suite exercises
-them without TPU hardware.
+On a TPU the kernels compile through Mosaic; a kernel the compiler refuses
+fails the build with the compiler's message.  Interpret mode exists for the
+CPU test suite only and is chosen only where the CPU was asked for by name
+(``JAX_PLATFORMS=cpu``): a process that wanted a chip and silently landed
+on the CPU must not quietly interpret its kernels instead.
 """
+
+from __future__ import annotations
+
+import functools
+import logging
+
+import jax
+
+logger = logging.getLogger(__name__)
+
+
+@functools.cache
+def _log_interpret_mode() -> None:  # once per process
+    logger.info("Pallas kernels run in interpret mode (JAX_PLATFORMS=cpu)")
+
+
+def interpret_default() -> bool:
+    """Interpret mode iff the backend is the CPU *and* the CPU was requested
+    explicitly; a CPU nobody asked for is an error, any other backend
+    compiles the kernel."""
+    if jax.default_backend() != "cpu":
+        return False
+    if "cpu" not in (jax.config.jax_platforms or ""):
+        raise RuntimeError(
+            "a Pallas kernel was reached on a CPU backend nobody asked for "
+            "(JAX_PLATFORMS is unset and no TPU was found); set "
+            "JAX_PLATFORMS=cpu to run the kernels in interpret mode"
+        )
+    _log_interpret_mode()
+    return True
+
+
+# the ``name=`` each kernel in this package gives its pallas_call; the name
+# rides the custom call's op_name through jit, vmap and the scalar-prefetch
+# batching loop
+KERNEL_NAMES = ("flash_attention", "fused_stream_epilogue")
+
+
+def mosaic_kernel_counts(hlo_text: str) -> dict:
+    """{kernel name -> number of Mosaic custom calls} in a compiled
+    executable's HLO text (``compiled.as_text()``): the evidence that a
+    step really contains the kernels its config names.  Interpret-mode and
+    composed-XLA graphs have none."""
+    counts: dict = {}
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        op_name = line.partition('op_name="')[2].partition('"')[0]
+        name = next((k for k in KERNEL_NAMES if k in op_name), "unnamed")
+        counts[name] = counts.get(name, 0) + 1
+    return counts

@@ -7,8 +7,8 @@ running max/denominator, so the [Lq, Lk] score matrix never materializes in
 HBM.  Matters at SDXL@1024 (16k latent tokens: dense scores would be
 16k x 16k x heads).
 
-Non-causal (diffusion attention has no mask).  Falls back to interpret mode
-off-TPU so the hermetic suite exercises the same code path.
+Non-causal (diffusion attention has no mask).  Interpret mode on an
+explicitly requested CPU so the hermetic suite exercises the same code path.
 """
 
 from __future__ import annotations
@@ -19,7 +19,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from . import interpret_default
 
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 256
@@ -70,7 +71,7 @@ def flash_attention(
     if mask is not None:
         raise NotImplementedError("flash_attention is non-causal/unmasked")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     b, lq, h, d = q.shape
     lk = k.shape[1]
     block_q = min(block_q, lq)
@@ -110,6 +111,7 @@ def flash_attention(
         out_specs=pl.BlockSpec((None, block_q, d), lambda g, i: (g, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, lq_p, d), q.dtype),
         interpret=interpret,
+        name="flash_attention",
     )(qt, kt, vt)
 
     out = out.reshape(b, h, lq_p, d).transpose(0, 2, 1, 3)

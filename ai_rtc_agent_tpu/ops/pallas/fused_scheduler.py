@@ -10,7 +10,10 @@ Done naively that's 5+ HBM round-trips of the latent tensors; this kernel
 does ONE read of (x_t, eps_c, stock, noise) and one write of (denoised,
 advanced, stock'), with the per-batch-entry scheduler coefficients prefetched
 to SMEM.  Grid = batch entries; each program owns one latent slab in VMEM
-(64x64x4 fp32 = 64 KiB, well under the ~16 MiB VMEM budget).
+(64x64x4 fp32 = 64 KiB, well under the ~16 MiB VMEM budget).  A slab is
+presented as ``[N/128, 128]`` rows of lanes: Mosaic wants a block's last two
+dimensions divisible by (8, 128) or equal to the array's, and a ``(1, N)``
+block over a ``[B, N]`` array is neither once B > 1.
 
 Runs under ``interpret=True`` on CPU for the hermetic test suite.
 """
@@ -24,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import interpret_default
+
 LANE = 128
 
 
@@ -36,7 +41,7 @@ def _kernel(
     next_alpha_ref,
     next_sigma_ref,
     gd_ref,  # [2] = (guidance, delta)
-    # VMEM tensor refs, one [1, N] slab per program
+    # VMEM tensor refs, one [N/128, 128] slab per program
     x_ref,
     eps_ref,
     stock_ref,
@@ -91,22 +96,22 @@ def fused_stream_epilogue(
 ):
     """x_t/eps_c/stock/noise: [B, h, w, c] -> (denoised, advanced, stock').
 
-    ``coeffs``: ops.lcm.StepCoeffs (jnp).  Shapes are flattened to [B, N]
-    slabs (N padded to the 128-lane minor dimension).
+    ``coeffs``: ops.lcm.StepCoeffs (jnp).  Shapes are flattened to
+    [B, N/128, 128] slabs (N padded to the 128-lane minor dimension).
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     B = x_t.shape[0]
     shape = x_t.shape
     n = int(jnp.size(x_t) // B)
     pad = (-n) % LANE
-    N = n + pad
+    rows = (n + pad) // LANE
 
     def flat(a):
         a = a.reshape(B, n).astype(jnp.float32)
         if pad:
             a = jnp.pad(a, ((0, 0), (0, pad)))
-        return a
+        return a.reshape(B, rows, LANE)
 
     gd = jnp.stack(
         [jnp.asarray(guidance, jnp.float32), jnp.asarray(delta, jnp.float32)]
@@ -114,15 +119,16 @@ def fused_stream_epilogue(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
         grid=(B,),
-        in_specs=[pl.BlockSpec((1, N), lambda b, *_: (b, 0))] * 4,
-        out_specs=[pl.BlockSpec((1, N), lambda b, *_: (b, 0))] * 3,
+        in_specs=[pl.BlockSpec((None, rows, LANE), lambda b, *_: (b, 0, 0))] * 4,
+        out_specs=[pl.BlockSpec((None, rows, LANE), lambda b, *_: (b, 0, 0))] * 3,
     )
-    out_shape = [jax.ShapeDtypeStruct((B, N), jnp.float32)] * 3
+    out_shape = [jax.ShapeDtypeStruct((B, rows, LANE), jnp.float32)] * 3
     den, adv, stock_new = pl.pallas_call(
         partial(_kernel, cfg_type=cfg_type),
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="fused_stream_epilogue",
     )(
         coeffs.alpha.astype(jnp.float32),
         coeffs.sigma.astype(jnp.float32),
@@ -138,6 +144,6 @@ def fused_stream_epilogue(
     )
 
     def unflat(a):
-        return a[:, :n].reshape(shape).astype(x_t.dtype)
+        return a.reshape(B, rows * LANE)[:, :n].reshape(shape).astype(x_t.dtype)
 
     return unflat(den), unflat(adv), unflat(stock_new)

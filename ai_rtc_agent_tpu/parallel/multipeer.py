@@ -426,12 +426,8 @@ class MultiPeerEngine:
             )
             idx_s = jax.ShapeDtypeStruct((k,), jnp.int32)
             for variant in variants:
-                # devtel attribution (the scheduler's prewarm contract):
-                # the body IS a compile, so the no-monitoring fallback
-                # self-times it
-                with devtel.compile_scope(
-                    f"peers-{k}:{variant}", fallback_record=True
-                ):
+                # devtel attribution (the scheduler's prewarm contract)
+                with devtel.compile_scope(f"peers-{k}:{variant}"):
                     compiled = (
                         self._bucket_step(k, variant)
                         .lower(params_s, states_s, frames_s, idx_s)

@@ -29,14 +29,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def _ring_body(q, k, v, axis: str):
     """Per-shard body: q,k,v [B, Lloc, H, D] -> out [B, Lloc, H, D]."""
-    # psum(1) is the portable axis-size spelling — lax.axis_size does not
-    # exist on the pinned jax (0.4.x); this folds to a constant at trace
-    n = lax.psum(1, axis)
+    n = lax.axis_size(axis)
     scale = 1.0 / math.sqrt(q.shape[-1])
     qf = q.astype(jnp.float32)
 
@@ -70,12 +67,12 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "sp", batch_axis=None):
     """q,k,v: [B, L, H, D] globally; L sharded over `axis`.  ``batch_axis``
     optionally co-shards the batch dim (composes with dp under one jit)."""
     spec = P(batch_axis, axis, None, None)
-    f = shard_map(
+    f = jax.shard_map(
         partial(_ring_body, axis=axis),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
     return f(q, k, v)
 
@@ -99,12 +96,12 @@ def _ulysses_body(q, k, v, axis: str):
 def ulysses_attention(q, k, v, mesh: Mesh, axis: str = "sp", batch_axis=None):
     """q,k,v: [B, L, H, D] globally; L sharded over `axis`; needs H % n == 0."""
     spec = P(batch_axis, axis, None, None)
-    f = shard_map(
+    f = jax.shard_map(
         partial(_ulysses_body, axis=axis),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
     return f(q, k, v)
 
@@ -126,12 +123,12 @@ def sp_cross_attention(q, k, v, mesh: Mesh, axis: str = "sp", batch_axis=None):
     """q: [B, Lq, H, D] sharded over `axis`; k,v: [B, Lk, H, D] replicated."""
     qspec = P(batch_axis, axis, None, None)
     kvspec = P(batch_axis, None, None, None)
-    f = shard_map(
+    f = jax.shard_map(
         partial(_cross_body, axis=axis),
         mesh=mesh,
         in_specs=(qspec, kvspec, kvspec),
         out_specs=qspec,
-        check_rep=False,
+        check_vma=False,
     )
     return f(q, k, v)
 

@@ -1513,6 +1513,8 @@ async def health_detail(request):
     body = {
         "status": worst_state(s["state"] for s in sessions.values()),
         "sessions": sessions,
+        # platform / device_kind / device_count and the graph that serves
+        "serving": app.get("serving", {}),
     }
     # broadcast fan-out plane: audience size next to session health —
     # a publisher with zero engine pressure can still be at viewer cap
@@ -2061,6 +2063,7 @@ async def on_startup(app):
             **({"use_controlnet": True} if app.get("controlnet") else {}),
         )
 
+    built_scheduler = False  # an injected (test) scheduler is left as given
     if app.get("multipeer", 0) and app.get("multipeer_pipeline") is None:
         from .multipeer_serving import MultiPeerPipeline
 
@@ -2126,31 +2129,26 @@ async def on_startup(app):
         ):
             from ..stream.scheduler import BatchScheduler
 
-            try:
-                # per-session style adapters (adapters/, ISSUE 20): load
-                # the ADAPTER_DIR catalog against THIS pipeline's UNet and
-                # bind its factor bank into the scheduler's stacked state.
-                # A bad catalog refuses the scheduler (shared-engine
-                # fallback below), never serves half-loaded styles.
-                adapters = None
-                adir = env.adapter_dir()
-                if adir:
-                    from ..adapters import build_registry
+            # per-session style adapters (adapters/, ISSUE 20): load the
+            # ADAPTER_DIR catalog against THIS pipeline's UNet and bind its
+            # factor bank into the scheduler's stacked state.  With
+            # BATCHSCHED on, a scheduler (or catalog) that cannot be built
+            # is a failed boot — never a quiet switch to another plane.
+            adapters = None
+            adir = env.adapter_dir()
+            if adir:
+                from ..adapters import build_registry
 
-                    pipe = app["pipeline"]
-                    adapters = build_registry(
-                        pipe.engine.params["unet"], pipe._bundle.unet_cfg,
-                        adir,
-                    )
-                app["batch_scheduler"] = BatchScheduler.from_pipeline(
-                    app["pipeline"], dp=env.batchsched_dp(),
-                    adapters=adapters,
+                pipe = app["pipeline"]
+                adapters = build_registry(
+                    pipe.engine.params["unet"], pipe._bundle.unet_cfg,
+                    adir,
                 )
-            except Exception:
-                logger.exception(
-                    "batch scheduler unavailable — serving the shared "
-                    "single-engine path"
-                )
+            app["batch_scheduler"] = BatchScheduler.from_pipeline(
+                app["pipeline"], dp=env.batchsched_dp(),
+                adapters=adapters,
+            )
+            built_scheduler = True
     app["pcs"] = set()
     app["supervisors"] = {}
     app["stream_event_handler"] = StreamEventHandler()
@@ -2330,6 +2328,13 @@ async def on_startup(app):
             on_transition=_engine_transition,
             on_exhausted=lambda: _evacuate_agent(app),
         )
+    if built_scheduler:
+        # the last warm-up act: compile the small eager programs around the
+        # bucket steps NOW (the guard is attached, so the snapshot bank's
+        # are in), and the first real session compiles nothing
+        sched.rehearse()
+    app["serving"] = _serving_info(app)
+    logger.info("serving: %s", json.dumps(app["serving"], sort_keys=True))
     if devtel_plane is not None:
         if app["overload"] is not None:
             # device-memory snapshot rides the ladder tick (rate-limited
@@ -2342,6 +2347,41 @@ async def on_startup(app):
         # first step WILL be reported: that config genuinely does
         # compile at serve time, and the watchdog's job is to say so.)
         devtel_plane.serving()
+
+
+def _serving_info(app) -> dict:
+    """What serves, for ``/health`` and the start-up log: the device as JAX
+    reports it, the serving plane, and the graph variant that plane was
+    built with — dtype, attention implementation, fused epilogue, and per
+    prewarmed bucket executable the Mosaic kernels found in its compiled
+    HLO.  Read once at the end of startup; nothing here changes while the
+    process serves."""
+    from ..utils.device import device_info
+
+    info = device_info()
+    mp = app.get("multipeer_pipeline")
+    sched = app.get("batch_scheduler")
+    info["model_id"] = app["model_id"]
+    info["plane"] = (
+        "multipeer" if mp is not None
+        else "batchsched" if sched is not None
+        else "shared-engine"
+    )
+    # injected test doubles carry no stream config
+    cfg = getattr(mp if mp is not None else app["pipeline"], "config", None)
+    if cfg is not None:
+        from ..stream.engine import current_attn_impl
+
+        info["height"], info["width"] = cfg.height, cfg.width
+        info["t_index_list"] = list(cfg.t_index_list)
+        info["num_inference_steps"] = cfg.num_inference_steps
+        info["dtype"] = cfg.dtype
+        info["attn_impl"] = cfg.attn_impl or current_attn_impl()
+        info["fused_epilogue"] = bool(cfg.use_fused_epilogue)
+    kernels = getattr(sched, "mosaic_kernels", None)
+    if kernels is not None:
+        info["mosaic_kernels"] = dict(kernels)
+    return info
 
 
 def _evacuate_agent(app):
@@ -2588,6 +2628,11 @@ def main(argv=None):
     )
     args = parser.parse_args(argv)
     logging.basicConfig(level=args.log_level.upper())
+    # the program runs where JAX_PLATFORMS says; unset means the TPU, and a
+    # silent CPU fallback exits here instead of serving (utils/device.py)
+    from ..utils.device import require_device
+
+    require_device()
     if args.profile_port:
         from ..utils.profiling import start_profiler_server
 

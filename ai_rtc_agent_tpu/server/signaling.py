@@ -307,6 +307,11 @@ def get_provider(name: str | None = None):
     if name == "loopback":
         return LoopbackProvider()
     if name == "native-rtp":
+        # asked for by name: a runtime that cannot be built is an error
+        # here, with the compiler's output, not a session that dies later
+        from ..media import native as native_rt
+
+        native_rt.require()
         return native()
     if name and name != "aiortc":
         # three tiers with materially different security properties — a

@@ -98,6 +98,7 @@ import numpy as np
 
 from ..obs import devtel
 from ..obs.trace import get_trace, safe_list
+from ..ops.pallas import mosaic_kernel_counts
 from ..parallel.multipeer import CapacityError, make_bucket_step
 from ..resilience import faults as _faults
 from ..resilience.overload import DeadlineQueue, ShedFrame
@@ -651,6 +652,10 @@ class BatchScheduler:
         sizes.append(S)
         self._bucket_sizes = sizes
         self._bucket_steps: dict = {}
+        # bucket label -> {kernel name: Mosaic custom calls in its compiled
+        # HLO}; filled by prewarm_buckets (an AOT-adopted or lazily
+        # compiled bucket has no compiled object to read, and no entry)
+        self.mosaic_kernels: dict = {}
         # ONE template prepare, tiled: inactive rows are placeholders —
         # claim() installs a freshly prepared state before any frame runs
         self._template.prepare(
@@ -742,25 +747,19 @@ class BatchScheduler:
         # time: adopt serialized engines when the cache has them (build
         # them with AOT_ENGINES=1 / the build CLI), then optionally
         # eager-compile whatever is still cold
-        if model_id:
-            try:
-                if self.use_aot_cache(
-                    model_id,
-                    cache_dir=cache_dir,
-                    build_on_miss=(
-                        env.get_bool("AOT_ENGINES", False)
-                        if aot_build_on_miss is None
-                        else aot_build_on_miss
-                    ),
-                ):
-                    logger.info(
-                        "batch scheduler serving from AOT engine cache "
-                        "(buckets %s)", self._bucket_sizes,
-                    )
-            except Exception as e:  # cache trouble must never block serving
-                logger.warning(
-                    "batch-scheduler AOT adoption failed (%s); using jit", e
-                )
+        if model_id and self.use_aot_cache(
+            model_id,
+            cache_dir=cache_dir,
+            build_on_miss=(
+                env.get_bool("AOT_ENGINES", False)
+                if aot_build_on_miss is None
+                else aot_build_on_miss
+            ),
+        ):
+            logger.info(
+                "batch scheduler serving from AOT engine cache "
+                "(buckets %s)", self._bucket_sizes,
+            )
         if prewarm is None:
             prewarm = env.get_bool("BATCHSCHED_PREWARM", True)
         # remembered so rebuild_engine() re-warms the way the boot did
@@ -776,9 +775,9 @@ class BatchScheduler:
     def from_pipeline(cls, pipeline, **kw) -> "BatchScheduler":
         """Build a scheduler that serves the same model/config as an
         already-built :class:`StreamDiffusionPipeline` — the bundle
-        (weights, encode_prompt) and the post-Pallas-probe config are
-        reused, so the scheduler compiles exactly the graphs the probe
-        validated."""
+        (weights, encode_prompt) and the config are reused, so the
+        scheduler compiles the graph variant the pipeline's warm-up step
+        already ran."""
         eng = pipeline.engine
         if eng.mesh is not None and any(
             n > 1 for n in eng.mesh.shape.values()
@@ -1587,28 +1586,88 @@ class BatchScheduler:
                     continue
                 params_s, states_s, frames_s, idx_s = self._bucket_specs(k)
                 # devtel: attribute the eager compile to its bucket (the
-                # sharded label carries :dp<N>); the body IS a compile by
-                # construction, so in the no-monitoring fallback it
-                # self-times (fallback_record) — and it is EXPECTED: a
+                # sharded label carries :dp<N>).  It is EXPECTED: a
                 # legitimate operator-triggered prewarm (e.g. after a
                 # mesh reshape) must never false-alarm the watchdog even
                 # in the serving phase, while a LAZY dispatch compile
                 # (_step_batch_locked) keeps breach semantics
-                with devtel.compile_scope(
-                    self._bucket_label(k, v), fallback_record=True,
-                    expected=True,
-                ):
+                label = self._bucket_label(k, v)
+                with devtel.compile_scope(label, expected=True):
                     compiled = (
                         self._bucket_step(k, v)
                         .lower(params_s, states_s, frames_s, idx_s)
                         .compile()
                     )
+                # what the executable that will serve really contains:
+                # /health reports it, chip_smoke.py asserts on it
+                self.mosaic_kernels[label] = mosaic_kernel_counts(
+                    compiled.as_text()
+                )
                 self._bucket_steps[(k, v)] = compiled
                 self._warmed_buckets.add((k, v))
                 logger.info(
                     "prewarmed batchsched bucket %d/%d (%s, dp=%d)",
                     k, self.max_sessions, v, self.dp,
                 )
+
+    def rehearse(self):
+        """Walk throw-away sessions through everything a real one will do —
+        claim, one frame through every bucket size, a prompt / t-index /
+        guidance write, release — while the process is still warming up.
+
+        ``prewarm_buckets`` compiles the bucket STEPS; what is left are the
+        small eager per-slot programs around them (row-install scatters,
+        the frame-batch stack, per-row readback slices, snapshot-bank row
+        slices), which JAX compiles on first use.  On a v5e each takes
+        70-300 ms (PERF.md "Bring-up on the chip"): unrehearsed, the first
+        sessions of a process pay seconds of them and the compile watchdog
+        rightly calls the slow ones serve-time breaches.  After this,
+        serving compiles nothing (``devtel_serving_compiles_total == 0``).
+
+        The agent calls it once at the end of startup, after the engine
+        guard is attached (so the snapshot bank's slices are rehearsed too)
+        and before the first real claim.  The gauges it moved are reset."""
+        rng = np.random.default_rng(0)
+        shape = (self.height, self.width, 3)
+        # a window nobody outwaits: only FULL batches dispatch — the last
+        # live session's submit completes the batch inline at occupancy k
+        window, self.window_s = self.window_s, 3600.0
+        on_step, self.on_step = self.on_step, None  # not a capacity signal
+        sessions: list = []
+        try:
+            for k in self._bucket_sizes:
+                while len(sessions) < k:
+                    sessions.append(self.claim(f"rehearsal-{len(sessions)}"))
+                # distinct noise per round: a similarity filter must not
+                # skip a rider and leave the batch forever incomplete
+                handles = [
+                    s.submit_batch([
+                        rng.integers(0, 256, shape, dtype=np.uint8)
+                        for _ in range(self.fbs)
+                    ])
+                    for s in sessions
+                ]
+                for s, hs in zip(sessions, handles):
+                    s.fetch_batch(hs)
+            self.update_prompt(self.prompt)
+            self.update_t_index_list(self.t_index_list)
+            self.update_guidance(self.guidance_scale, self.delta)
+        finally:
+            for s in sessions:
+                s.release()
+            self.window_s, self.on_step = window, on_step
+        with self._lock:
+            self._snap_rows, self._last_snap_t = {}, 0.0
+            self._tick = 0
+        with self._stats_lock:
+            self.steps_total = 0
+            self._occ.clear()
+            self._waits.clear()
+            self._occ_hist = {}
+        logger.info(
+            "batchsched rehearsed %d session(s) through buckets %s",
+            len(sessions), self._bucket_sizes,
+        )
 
     # -- engine fault domain (resilience/engine_guard.py) ----------------------
 

@@ -1,11 +1,10 @@
 """Contract-line plumbing shared by the measurement CLIs.
 
-Every script the TPU watcher (scripts/tpu_watch.sh) or the round driver
-runs must print exactly one JSON line on EVERY exit path — the round-1
-failure mode was a bench that died before any JSON.  The finally-block
-pattern handles exceptions; this helper covers the remaining hole: a
-SIGTERM from timeout(1) would otherwise kill the process without running
-the finally block, losing the error detail of the attempt.
+The scripts/*_bench.py and check scripts print one JSON line on every exit
+path from a finally block.  The finally-block pattern handles exceptions;
+this helper covers the remaining hole: a SIGTERM from timeout(1) would
+otherwise kill the process without running the finally block, losing the
+error detail of the attempt.
 """
 
 from __future__ import annotations
@@ -18,10 +17,9 @@ def sigterm_to_exception(source: str = "driver timeout") -> None:
 
     The exception unwinds into the caller's ``except/finally`` so the
     contract line is still emitted.  Note the known limit: if the main
-    thread is blocked inside a C call (e.g. a wedged remote TPU dispatch),
+    thread is blocked inside a C call (e.g. a wedged device dispatch),
     the Python-level handler cannot run until that call returns — the
-    watcher escalates to SIGKILL after a grace period for exactly that
-    case (scripts/tpu_watch.sh run_item).
+    caller's ``timeout -k`` escalates to SIGKILL for exactly that case.
     """
 
     def _raise(signum, frame):

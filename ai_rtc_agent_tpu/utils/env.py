@@ -9,8 +9,8 @@ Recognised variables (superset of reference docs/environment.md):
   AUTH_TOKEN, WEBHOOK_URL            webhook eventing (lib/events.py parity)
   TWILIO_ACCOUNT_SID/_AUTH_TOKEN     ephemeral TURN credentials
   WARMUP_FRAMES, DROP_FRAMES         track warm-up / OBS stutter workaround
-  XLA_ENGINES_CACHE                  AOT executable cache dir (was
-                                     TRT_ENGINES_CACHE, lib/pipeline.py:35)
+  XLA_ENGINES_CACHE                  AOT engine (jax.export) cache dir (the
+                                     reference's TRT_ENGINES_CACHE role)
   CIVITAI_CACHE, HF_HUB_CACHE        weight caches (lib/utils.py:6-10)
   HW_ENCODE, HW_DECODE               native codec toggles (was NVENC/NVDEC,
                                      Dockerfile:53-56); on TPU these select
@@ -22,6 +22,13 @@ Recognised variables (superset of reference docs/environment.md):
 from __future__ import annotations
 
 import os
+
+# the checkout: default home of everything the program builds at run time
+# (AOT engines, the XLA compile cache) — anchored here, not to the working
+# directory, so two launches from different directories share one cache
+REPO_ROOT = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), os.pardir, os.pardir)
+)
 
 
 def get_str(name: str, default: str | None = None) -> str | None:
@@ -80,10 +87,8 @@ def get_int_aliased(name: str, alias: str, default: int) -> int:
 
 # Graph-variant resolvers (jax-free) ----------------------------------------
 # THE single definitions of the serving-graph variant defaults, parameterized
-# on the backend name so they are usable where jax must not be imported (the
-# bench replay path runs precisely when the accelerator is unreachable).
-# stream/engine.current_attn_impl / current_fused_epilogue bind them to
-# jax.default_backend(); bench._replay_from_perf_log binds them to "tpu".
+# on the backend name; stream/engine.current_attn_impl /
+# current_fused_epilogue bind them to jax.default_backend().
 
 
 def attn_impl_default(backend: str) -> str:
@@ -108,11 +113,8 @@ def drop_frames() -> int:
 
 
 def engines_cache() -> str:
-    # accept the reference's TRT_ENGINES_CACHE name as an alias for migration
-    return (
-        get_str("XLA_ENGINES_CACHE")
-        or get_str("TRT_ENGINES_CACHE")
-        or "./models/engines"
+    return get_str("XLA_ENGINES_CACHE") or os.path.join(
+        REPO_ROOT, "models", "engines"
     )
 
 
@@ -274,8 +276,7 @@ def mesh_shape() -> tuple:
 def perf_log_path(default: str) -> str:
     """PERF_LOG_PATH with the bench-banking semantics: unset -> the
     caller's default (the repo log); an EMPTY value -> ``""`` (banking
-    disabled — the watcher's own append-and-commit is the sole writer).
-    Plain :func:`get_str` would collapse empty to the default and
+    disabled).  Plain :func:`get_str` would collapse empty to the default and
     silently re-enable self-banking."""
     v = os.getenv("PERF_LOG_PATH")
     return default if v is None else v

@@ -8,11 +8,9 @@ implementation they all import.  :func:`paired` is the same story for
 the throttle-jitter measurement discipline (batch_scheduler,
 device_path and mesh_sched each carried a copy).
 
-Semantics (relied on by scripts/tpu_watch.sh):
+Semantics:
 * ``PERF_LOG_PATH`` unset -> append to the repo's ``PERF_LOG.jsonl``;
-* ``PERF_LOG_PATH`` set EMPTY (or to os.devnull) -> banking DISABLED —
-  the watcher items set ``PERF_LOG_PATH=`` so its own labeled
-  append-and-commit is the only writer;
+* ``PERF_LOG_PATH`` set EMPTY (or to os.devnull) -> banking DISABLED;
 * an OSError never raises: the contract line must still print, the
   failure is recorded on the entry as ``bank_error``.
 """

@@ -87,7 +87,7 @@ def main():
 
     from ai_rtc_agent_tpu.utils.contract import sigterm_to_exception
 
-    sigterm_to_exception("watcher timeout")
+    sigterm_to_exception("timeout")
     out = {"phase": "build" if args.build else "reload",
            "ok": False, "backend": "unknown"}
     try:
@@ -102,7 +102,7 @@ def main():
             out["build_s"] = round(time.monotonic() - t0, 1)
             out["fps"] = round(measure_fps(eng, cfg, args.frames), 2)
             out["donation_in_place"] = check_donation(eng, cfg)
-            out["ok"] = bool(ok)  # watcher commit criterion (tpu_watch.sh)
+            out["ok"] = bool(ok)
         else:
             # fast path: no jit wrapper at all — state built, engine adopted
             eng, cfg = build_engine(args.model_id, jit_compile=False)
@@ -114,7 +114,7 @@ def main():
             if ok:
                 out["fps"] = round(measure_fps(eng, cfg, args.frames), 2)
                 out["donation_in_place"] = check_donation(eng, cfg)
-            out["ok"] = bool(ok)  # watcher commit criterion (tpu_watch.sh)
+            out["ok"] = bool(ok)
     except BaseException as e:  # noqa: BLE001 — contract line on any failure
         out["error"] = f"{type(e).__name__}: {e}"
     finally:

@@ -180,9 +180,8 @@ def run() -> dict:
         "value": round(amortization, 2),
         "unit": "x",
         "vs_baseline": round(amortization, 2),
-        # the REAL backend: the cpu env default is a setdefault, so the
-        # watcher's JAX_PLATFORMS=tpu items must not mislabel (and the
-        # watch_filter banks only backend=="tpu" lines)
+        # the REAL backend: the cpu env default is a setdefault, so a
+        # JAX_PLATFORMS=tpu run must not be mislabelled
         "backend": jax.default_backend(),
         "live": True,
         "label": f"batchsched_{SESSIONS}s_{FRAMES}f",

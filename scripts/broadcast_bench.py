@@ -70,12 +70,9 @@ MTU = int(os.getenv("BROADCAST_BENCH_MTU") or 1200)
 PAIRS = int(os.getenv("BROADCAST_BENCH_PAIRS") or 5)
 
 # --probe-backend: import jax and stamp the REAL backend instead of the
-# "cpu" default — the tpu_watch.sh rows pass it so watch_filter.py's
-# backend refusal admits the line exactly when the box is a live TPU
-# (the measurement itself stays host-side either way; what the TPU box
-# changes is the codec tier: libavcodec H.264 vs NullCodec).
-# --metric=<name>: emit only that contract line (run_item banks the LAST
-# line, so each watcher row selects its one metric).
+# "cpu" default (the measurement itself stays host-side either way; what a
+# TPU host changes is the codec tier: libavcodec H.264 vs NullCodec).
+# --metric=<name>: emit only that contract line.
 PROBE_BACKEND = "--probe-backend" in sys.argv
 ONLY_METRIC = next(
     (a.split("=", 1)[1] for a in sys.argv if a.startswith("--metric=")),

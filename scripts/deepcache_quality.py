@@ -3,7 +3,7 @@
 Runs the same moving-scene comparison as tests/test_deepcache_quality.py
 but against an arbitrary model id (real weights when available) and also
 times the stream, so one run yields the full quality/speed trade-off
-table.  Prints ONE JSON line (watch_filter-compatible: carries backend).
+table.  Prints ONE JSON line (it carries the backend it ran on).
 
 Usage:
     python scripts/deepcache_quality.py --model-id tiny-test --frames 24

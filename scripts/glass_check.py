@@ -4,9 +4,10 @@
 VERDICT r2 next-round #9: run the full wire path — H.264 bytes -> UDP ->
 depacketize -> decode -> jitted diffusion step -> encode -> UDP -> H.264
 bytes — against the flagship model and persist the codec-inclusive
-/metrics stages (decode/encode/glass p50) as ONE JSON line.  The TPU
-watcher (scripts/tpu_watch.sh) commits it to PERF_LOG.jsonl; the
-BASELINE.md target is p50 glass-to-glass < 100 ms.
+/metrics stages (decode/encode/glass p50) as ONE JSON line.  The
+BASELINE.md target is p50 glass-to-glass < 100 ms.  Agent and client share
+this one process (and so the chip); chip_smoke.py drives the same path with
+the agent as its own process.
 
 Frames are paced at --fps (default 30) like a live camera; the client
 keeps draining returned packets so encoder/decoder pipelines stay busy.
@@ -114,15 +115,15 @@ def main():
               "model_id": args.model_id}
     from ai_rtc_agent_tpu.utils.contract import sigterm_to_exception
 
-    sigterm_to_exception("watcher timeout")
+    sigterm_to_exception("timeout")
     try:
         from ai_rtc_agent_tpu.media import native
 
         if not native.h264_available():
             raise RuntimeError("libavcodec unavailable — no codec-inclusive path")
-        import jax
+        from ai_rtc_agent_tpu.utils.device import require_device
 
-        result["backend"] = jax.default_backend()
+        result["backend"] = require_device()["platform"]
         asyncio.run(
             run(args.model_id, args.frames, args.fps, args.min_return_frac,
                 result)

@@ -36,7 +36,7 @@ def main():
     result = {"ok": False, "check": "golden_capture", "model_id": args.model_id}
     from ai_rtc_agent_tpu.utils.contract import sigterm_to_exception
 
-    sigterm_to_exception("watcher timeout")
+    sigterm_to_exception("timeout")
     try:
         cap = golden.capture(args.model_id)
         os.makedirs(os.path.dirname(out), exist_ok=True)

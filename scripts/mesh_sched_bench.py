@@ -17,8 +17,8 @@ real chips and the ratio approaches N; on this CPU tier the "devices"
 are XLA's 8-virtual-device simulation sharing the host's cores, so the
 honest CPU number mostly prices the sharded dispatch/assembly machinery
 (partitioned executable, per-shard staging, global-array assembly) —
-the fence catches that machinery regressing, the TPU watcher row
-(``meshsched_dp8`` in tpu_watch.sh) is the accelerator truth.  Never
+the fence catches that machinery regressing; the accelerator truth is a
+run on the four-chip host, which has not been made.  Never
 bank the CPU number on the accelerator trajectory: the ``backend``
 field + perf_compare's hardware-tier predicate keep the two apart.
 

@@ -91,7 +91,12 @@ def test_build_engines_peers_flag(tmp_path, monkeypatch):
     from ai_rtc_agent_tpu.assets import build_engines
     from ai_rtc_agent_tpu.models import registry
     from ai_rtc_agent_tpu.parallel.multipeer import MultiPeerEngine
+    from ai_rtc_agent_tpu.utils import device
 
+    # main() is a process entry point and places XLA's persistent compile
+    # cache; called in-process it would engage that cache for the rest of
+    # the suite, which conftest.py keeps cache-free
+    monkeypatch.setattr(device, "configure_compile_cache", lambda: "off")
     build_engines.main([
         "--model-id", "tiny-test", "--cache-dir", str(tmp_path),
         "--peers", "2",

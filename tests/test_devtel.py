@@ -314,6 +314,53 @@ def test_scheduler_prewarm_clean_then_forced_retrace_breaches(
         s.close()
 
 
+def test_rehearsed_scheduler_compiles_nothing_while_serving(bundle, cfg):
+    """BatchScheduler.rehearse (the agent's last warm-up act): after it,
+    two sessions joining, frames through both bucket sizes, a prompt, a
+    t-index and a guidance update compile NOTHING in the serving phase —
+    the small eager per-slot programs were all compiled at boot (on a v5e
+    each costs 70-300 ms, and the slow ones were serve-time breaches).  The
+    gauges the rehearsal moved are back at zero."""
+    from ai_rtc_agent_tpu.stream.scheduler import BatchScheduler
+
+    p = devtel.activate(DevTelPlane())
+    s = BatchScheduler(
+        bundle.stream_models, bundle.params, cfg, bundle.encode_prompt,
+        max_sessions=2, window_ms=10_000.0, prewarm=True,
+    )
+    try:
+        s.rehearse()
+        assert s.steps_total == 0 and s.snapshot()["batchsched_sessions"] == 0
+        assert "batchsched_occupancy_max" not in s.snapshot()
+        assert s.window_s == 10.0 and s.free_slots == 2
+        assert p.serving_compiles == 0 and p.warmup_compiles == p.compiles_total
+
+        p.serving()
+        rng = np.random.default_rng(1)
+        frame = lambda: rng.integers(  # noqa: E731
+            0, 255, (cfg.height, cfg.width, 3), np.uint8
+        )
+        a = s.claim("a")
+        assert a(frame()).shape == (cfg.height, cfg.width, 3)  # inline k=1
+        b = s.claim("b")
+        ha, hb = a.submit(frame()), b.submit(frame())  # completes a k=2 batch
+        a.fetch(ha), b.fetch(hb)
+        s.update_prompt("another prompt")
+        b.update_t_index_list(list(cfg.t_index_list))
+        a.update_guidance(guidance_scale=1.4, delta=0.8)
+        a.restart()
+        ha, hb = a.submit(frame()), b.submit(frame())
+        a.fetch(ha), b.fetch(hb)
+        assert s.snapshot()["batchsched_occupancy_hist"] == {"1": 1, "2": 2}
+        assert p.serving_compiles == 0, [
+            c for c in p.compiles if c["phase"] == "serving"
+        ]
+        a.release(), b.release()
+    finally:
+        s.close()
+        devtel.deactivate(p)
+
+
 # -- agent wiring: the three alert surfaces ----------------------------------
 
 def test_agent_retrace_breach_rides_all_three_surfaces(monkeypatch):

@@ -71,13 +71,10 @@ def test_session_axis_rules_and_knobs(monkeypatch):
 
 
 def test_collectives_in_shard_map(rng):
-    from functools import partial
-    from jax.experimental.shard_map import shard_map
-
     m = M.make_mesh(dp=8)
     x = jnp.arange(8.0)
 
-    f = shard_map(
+    f = jax.shard_map(
         lambda v: jax.lax.psum(v, axis_name="dp"),
         mesh=m,
         in_specs=P("dp"),
@@ -87,17 +84,16 @@ def test_collectives_in_shard_map(rng):
     np.testing.assert_allclose(out, np.full(8, x.sum()))
 
     def ring_shift(v):
-        n = jax.lax.psum(1, "dp")  # portable axis size on jax 0.4.x
+        n = jax.lax.axis_size("dp")
         return jax.lax.ppermute(
             v, axis_name="dp", perm=[(i, (i + 1) % n) for i in range(n)]
         )
 
-    g = shard_map(
+    g = jax.shard_map(
         ring_shift,
         mesh=m,
         in_specs=P("dp"),
         out_specs=P("dp"),
-        check_rep=False,
     )
     np.testing.assert_allclose(np.asarray(g(x)), np.roll(np.arange(8.0), 1))
 
