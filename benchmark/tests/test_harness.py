@@ -1,0 +1,158 @@
+"""The harness end to end on the CPU at the tiny size, and its refusals."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+from .conftest import REPO, make_root, tiny_spec
+
+CONTRACT_KEYS = ["correct", "attempted", "failed", "metrics", "device"]
+
+
+def test_without_a_chip_it_refuses_to_report():
+    """The real command on this CPU-only machine: non-zero, no result line."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, "-m", "benchmark.run", "--workload", "turbo512.solo60",
+         "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=300,
+    )
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""
+    assert "TPU" in p.stderr
+
+
+@pytest.mark.parametrize("workload", ["tiny64.duo20", "tinyturbo64.duo20"])
+def test_with_a_device_stub_one_well_formed_last_line(run_cell, workload):
+    code, line, err = run_cell(workload, seed=2**31 + 5)
+    assert code == 0, err
+    assert list(line)[: len(CONTRACT_KEYS)] == CONTRACT_KEYS
+    assert list(line)[-1] == "compared"
+    assert line["correct"] is True, line["compared"]
+    assert line["attempted"] > 20 and line["failed"] == 0
+    spec = tiny_spec()
+    assert list(line["metrics"]) == [m["name"] for m in spec["end_to_end"]]
+    for m in spec["end_to_end"]:
+        got = line["metrics"][m["name"]]
+        assert got["unit"] == m["unit"] and got["value"] > 0
+    assert set(line["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
+    # each number compared stands beside its limit, in the line and as the
+    # last lines of standard error
+    assert set(line["compared"]) == {"session_bias_rel_max", "frame_pooled_rms_max"}
+    last = [l for l in err.splitlines() if l.strip()][-2:]
+    assert last[0].startswith("compared session_bias_rel_max") and "limit" in last[0]
+    assert last[1].startswith("compared frame_pooled_rms_max") and "limit" in last[1]
+    # tiny64 carries state: the window's first 8 frames and its last 4, per session
+    assert line["readings"]["frames_compared"] >= 18
+
+
+def test_a_named_piece_the_disk_lacks_is_an_error_that_names_it(tmp_path, run_cell):
+    root = make_root(tmp_path)
+    os.remove(os.path.join(root, "benchmark", "traffic", "duo20.json"))
+    code, line, err = run_cell("tiny64.duo20", root=root)
+    assert code != 0 and line is None
+    assert "traffic mix 'duo20'" in err and "traffic/duo20.json" in err
+
+    root = make_root(tmp_path / "b")
+    os.remove(os.path.join(root, "benchmark", "configs", "tiny64.json"))
+    code, line, err = run_cell("tiny64.duo20", root=root)
+    assert code != 0 and line is None
+    assert "configuration 'tiny64'" in err and "configs/tiny64.json" in err
+
+    root = make_root(tmp_path / "c")
+    os.remove(os.path.join(root, "benchmark", "layer_metrics", "step_mfu.py"))
+    code, line, err = run_cell("tiny64.duo20", root=root, trace=1)
+    assert code != 0 and line is None
+    assert "per-layer metric 'step_mfu'" in err and "layer_metrics/step_mfu.py" in err
+
+    code, line, err = run_cell("tiny64.nosuch", root=root)
+    assert code != 0 and "tiny64.nosuch" in err
+
+    root = make_root(tmp_path / "d")
+    os.remove(os.path.join(root, "benchmark", "reference", "sd_stream.py"))
+    code, line, err = run_cell("tiny64.duo20", root=root)
+    assert code != 0 and line is None
+    assert "reference 'sd_stream' of configuration 'tiny64'" in err
+    assert "reference/sd_stream.py" in err
+
+    root = make_root(tmp_path / "e")
+    os.remove(os.path.join(root, "benchmark", "flops", "sd_stream.py"))
+    code, line, err = run_cell("tiny64.duo20", root=root)
+    assert code != 0 and line is None
+    assert "flops module 'sd_stream' of configuration 'tiny64'" in err
+
+
+def test_new_pieces_are_added_by_files_and_entries_alone(tmp_path):
+    """A later PR's configuration, traffic mix and per-layer metric: new
+    files and new BENCHMARK.json entries, no edit to a file that is there."""
+    from benchmark.harness import Benchmark
+
+    spec = tiny_spec()
+    spec["configs"].append(
+        {"name": "dummy", "source": "tests", "file": "benchmark/configs/dummy.json",
+         "reduced": [], "why": "added"}
+    )
+    spec["workloads"].append(
+        {"name": "dummy.trickle", "config": "dummy", "traffic": "trickle",
+         "chips": 1, "why": "added"}
+    )
+    spec["per_layer"].append(
+        {"name": "dummy_count", "unit": "frames", "better": "higher",
+         "source": "program_counter", "layer": "load generator (benchmark)",
+         "moves": "stylized_fps", "workloads": ["dummy.trickle"]}
+    )
+    root = make_root(tmp_path, spec)
+    home = os.path.join(root, "benchmark")
+    with open(os.path.join(home, "configs", "tiny64.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(home, "configs", "dummy.json"), "w") as f:
+        json.dump(dict(cfg, what="a copy", reference="dummy_ref", flops="dummy_ops"), f)
+    # a new model family brings its own reference and its own count of
+    # operations (here: the old ones under new names), found by name
+    with open(os.path.join(home, "reference", "dummy_ref.py"), "w") as f:
+        f.write("from .sd_stream import Reference, weight_shapes  # noqa: F401\n")
+    with open(os.path.join(home, "flops", "dummy_ops.py"), "w") as f:
+        f.write("def frame_flops(cfg):\n    return 7\n")
+    with open(os.path.join(home, "traffic", "trickle.json"), "w") as f:
+        json.dump({"sessions": 1, "slots": 1, "source_fps": 5,
+                   "pipeline_depth": 2, "warmup_frames": 10}, f)
+    with open(os.path.join(home, "layer_metrics", "dummy_count.py"), "w") as f:
+        f.write("def read(ctx):\n    return float(ctx.traffic['source_fps'])\n")
+
+    bench = Benchmark(root)
+    cell = bench.cell("dummy.trickle")
+    assert bench.config(cell)["name"] == "dummy"
+    assert bench.flops(bench.config(cell)).frame_flops(None) == 7
+    assert bench.reference(bench.config(cell)).Reference.__name__ == "Reference"
+    assert bench.traffic(cell)["source_fps"] == 5
+    names = [m["name"] for m in bench.per_layer(cell)]
+    assert names[-1] == "dummy_count" and len(names) == len(spec["per_layer"])
+    # the old cells do not see the new metric, which lists its own cells
+    assert "dummy_count" not in [m["name"] for m in bench.per_layer(bench.cell("tiny64.duo20"))]
+
+    class Ctx:
+        traffic = bench.traffic(cell)
+
+    assert bench.reader(bench.per_layer(cell)[-1])(Ctx) == 5.0
+
+
+def test_the_real_benchmark_json_finds_all_its_pieces():
+    from benchmark.harness import Benchmark
+
+    bench = Benchmark(REPO)
+    assert bench.spec["command"] == ["python3", "-m", "benchmark.run"]
+    for cell in bench.spec["workloads"]:
+        assert cell["chips"] == 1
+        cfg, traffic = bench.config(cell), bench.traffic(cell)
+        assert traffic["slots"] == traffic["sessions"]
+        assert set(cfg["check"]["limits"]) == {"session_bias_rel_max"}
+        assert {m["name"] for m in bench.end_to_end(cell)} == {
+            "stylized_fps", "frame_latency_p50_ms", "frame_latency_p95_ms", "setup_s"}
+        assert bench.flops(cfg).frame_flops(cfg) > 1e12
+        assert callable(bench.reference(cfg).weight_shapes)
+        for m in bench.per_layer(cell):
+            assert callable(bench.reader(m))
+            assert m["moves"] in {e["name"] for e in bench.end_to_end(cell)}
