@@ -289,24 +289,32 @@ def _resnet(p, x, temb, groups: int = 32):
 def _transformer(p, x, context, cfg: UNetConfig, heads: int, attn_impl: str):
     n, h, w, c = x.shape
     residual = x
-    z = group_norm(p["norm"], x, cfg.norm_groups)
-    if cfg.use_linear_projection:
-        z = z.reshape(n, h * w, c)
-        z = linear(p["proj_in"], z)
-    else:
-        z = conv2d(p["proj_in"], z)
-        z = z.reshape(n, h * w, c)
+    with jax.named_scope("proj"):
+        z = group_norm(p["norm"], x, cfg.norm_groups)
+        if cfg.use_linear_projection:
+            z = z.reshape(n, h * w, c)
+            z = linear(p["proj_in"], z)
+        else:
+            z = conv2d(p["proj_in"], z)
+            z = z.reshape(n, h * w, c)
     for blk in p["blocks"]:
-        z = z + attention(blk["attn1"], layer_norm(blk["norm1"], z), None, heads, attn_impl=attn_impl)
-        z = z + attention(blk["attn2"], layer_norm(blk["norm2"], z), context, heads, attn_impl=attn_impl)
-        z = z + geglu_ff(blk["ff"], layer_norm(blk["norm3"], z))
-    if cfg.use_linear_projection:
-        z = linear(p["proj_out"], z)
-        z = z.reshape(n, h, w, c)
-    else:
-        z = z.reshape(n, h, w, c)
-        z = conv2d(p["proj_out"], z)
-    return z + residual
+        # the scopes name the model part, never the Mosaic kernel inside it
+        # (its pallas_call carries its own name): the trace readers find a
+        # kernel by that substring of an op's name
+        with jax.named_scope("self_attn"):
+            z = z + attention(blk["attn1"], layer_norm(blk["norm1"], z), None, heads, attn_impl=attn_impl)
+        with jax.named_scope("cross_attn"):
+            z = z + attention(blk["attn2"], layer_norm(blk["norm2"], z), context, heads, attn_impl=attn_impl)
+        with jax.named_scope("ff"):
+            z = z + geglu_ff(blk["ff"], layer_norm(blk["norm3"], z))
+    with jax.named_scope("proj"):
+        if cfg.use_linear_projection:
+            z = linear(p["proj_out"], z)
+            z = z.reshape(n, h, w, c)
+        else:
+            z = z.reshape(n, h, w, c)
+            z = conv2d(p["proj_out"], z)
+        return z + residual
 
 
 def _upsample2x(x):
@@ -370,8 +378,31 @@ def apply_unet(
                     wiring invariant the tests pin.
     """
     nb = len(cfg.block_out_channels)
-    temb = time_cond_embedding(p, cfg, timesteps, added_cond, dtype=x.dtype)
+    with jax.named_scope("time_embed"):
+        temb = time_cond_embedding(p, cfg, timesteps, added_cond, dtype=x.dtype)
     context = context.astype(x.dtype)
+
+    def resnet(j, rn, h):
+        with jax.named_scope(f"resnet_{j}"):
+            return _resnet(rn, h, temb, cfg.norm_groups)
+
+    def transformer(j, tp, h, heads):
+        with jax.named_scope(f"transformer_{j}"):
+            return _transformer(tp, h, context, cfg, heads, attn_impl)
+
+    def conv_in(x):
+        with jax.named_scope("conv_in"):
+            return conv2d(p["conv_in"], x)
+
+    def conv_out(h):
+        with jax.named_scope("conv_out"):
+            h = group_norm(p["conv_norm_out"], h, cfg.norm_groups)
+            return conv2d(p["conv_out"], silu(h))
+
+    def up_resnet(j, rn, h, skip):
+        with jax.named_scope(f"resnet_{j}"):  # the skip concat is its input
+            h = jnp.concatenate([h, skip], axis=-1)
+            return _resnet(rn, h, temb, cfg.norm_groups)
 
     if deep_cache == "use":
         if down_residuals is not None or mid_residual is not None:
@@ -381,17 +412,17 @@ def apply_unet(
             )
         if cached_h is None:
             raise ValueError("deep_cache='use' requires cached_h")
-        h = conv2d(p["conv_in"], x)
+        h = conv_in(x)
         skips = [h]
         blk0 = p["down_blocks"][0]
-        for j, rn in enumerate(blk0["resnets"]):
-            h = _resnet(rn, h, temb, cfg.norm_groups)
-            if blk0["attentions"]:
-                h = _transformer(
-                    blk0["attentions"][j], h, context, cfg,
-                    cfg.num_heads_per_block[0], attn_impl,
-                )
-            skips.append(h)
+        with jax.named_scope("down_0"):
+            for j, rn in enumerate(blk0["resnets"]):
+                h = resnet(j, rn, h)
+                if blk0["attentions"]:
+                    h = transformer(
+                        j, blk0["attentions"][j], h, cfg.num_heads_per_block[0]
+                    )
+                skips.append(h)
         blk = p["up_blocks"][-1]
         if len(blk["resnets"]) != len(skips):
             raise ValueError(
@@ -399,31 +430,30 @@ def apply_unet(
                 f"{len(blk['resnets'])} skips, shallow pass made {len(skips)}"
             )
         h = cached_h.astype(x.dtype)
-        for j, rn in enumerate(blk["resnets"]):
-            h = jnp.concatenate([h, skips.pop()], axis=-1)
-            h = _resnet(rn, h, temb, cfg.norm_groups)
-            if blk["attentions"]:
-                h = _transformer(
-                    blk["attentions"][j], h, context, cfg,
-                    cfg.num_heads_per_block[0], attn_impl,
-                )
-        h = group_norm(p["conv_norm_out"], h, cfg.norm_groups)
-        h = conv2d(p["conv_out"], silu(h))
-        return h
+        with jax.named_scope(f"up_{len(p['up_blocks']) - 1}"):
+            for j, rn in enumerate(blk["resnets"]):
+                h = up_resnet(j, rn, h, skips.pop())
+                if blk["attentions"]:
+                    h = transformer(
+                        j, blk["attentions"][j], h, cfg.num_heads_per_block[0]
+                    )
+        return conv_out(h)
 
-    h = conv2d(p["conv_in"], x)
+    h = conv_in(x)
     skips = [h]
     for i, blk in enumerate(p["down_blocks"]):
-        for j, rn in enumerate(blk["resnets"]):
-            h = _resnet(rn, h, temb, cfg.norm_groups)
-            if blk["attentions"]:
-                h = _transformer(
-                    blk["attentions"][j], h, context, cfg, cfg.num_heads_per_block[i], attn_impl
-                )
-            skips.append(h)
-        if blk["downsample"] is not None:
-            h = conv2d(blk["downsample"], h, stride=2, padding=1)
-            skips.append(h)
+        with jax.named_scope(f"down_{i}"):
+            for j, rn in enumerate(blk["resnets"]):
+                h = resnet(j, rn, h)
+                if blk["attentions"]:
+                    h = transformer(
+                        j, blk["attentions"][j], h, cfg.num_heads_per_block[i]
+                    )
+                skips.append(h)
+            if blk["downsample"] is not None:
+                with jax.named_scope("downsample"):
+                    h = conv2d(blk["downsample"], h, stride=2, padding=1)
+                skips.append(h)
 
     if down_residuals is not None:
         if len(down_residuals) != len(skips):
@@ -433,11 +463,10 @@ def apply_unet(
         skips = [s + r.astype(s.dtype) for s, r in zip(skips, down_residuals)]
 
     mb = p["mid_block"]
-    h = _resnet(mb["resnet1"], h, temb, cfg.norm_groups)
-    h = _transformer(
-        mb["attention"], h, context, cfg, cfg.num_heads_per_block[-1], attn_impl
-    )
-    h = _resnet(mb["resnet2"], h, temb, cfg.norm_groups)
+    with jax.named_scope("mid"):
+        h = resnet(0, mb["resnet1"], h)
+        h = transformer(0, mb["attention"], h, cfg.num_heads_per_block[-1])
+        h = resnet(1, mb["resnet2"], h)
     if mid_residual is not None:
         h = h + mid_residual.astype(h.dtype)
 
@@ -446,19 +475,19 @@ def apply_unet(
         i = nb - 1 - k
         if k == len(p["up_blocks"]) - 1 and deep_cache == "capture":
             deep_h = h  # the feature the "use" pass splices back in
-        for j, rn in enumerate(blk["resnets"]):
-            h = jnp.concatenate([h, skips.pop()], axis=-1)
-            h = _resnet(rn, h, temb, cfg.norm_groups)
-            if blk["attentions"]:
-                h = _transformer(
-                    blk["attentions"][j], h, context, cfg, cfg.num_heads_per_block[i], attn_impl
-                )
-        if blk["upsample"] is not None:
-            h = _upsample2x(h)
-            h = conv2d(blk["upsample"], h)
+        with jax.named_scope(f"up_{k}"):
+            for j, rn in enumerate(blk["resnets"]):
+                h = up_resnet(j, rn, h, skips.pop())
+                if blk["attentions"]:
+                    h = transformer(
+                        j, blk["attentions"][j], h, cfg.num_heads_per_block[i]
+                    )
+            if blk["upsample"] is not None:
+                with jax.named_scope("upsample"):
+                    h = _upsample2x(h)
+                    h = conv2d(blk["upsample"], h)
 
-    h = group_norm(p["conv_norm_out"], h, cfg.norm_groups)
-    h = conv2d(p["conv_out"], silu(h))
+    h = conv_out(h)
     if deep_cache == "capture":
         return h, deep_h
     return h

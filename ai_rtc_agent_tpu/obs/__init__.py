@@ -1,7 +1,9 @@
 """Observability subsystem: per-frame tracing + black-box flight recorder.
 
 * obs/trace.py — :class:`FrameTrace` span timelines threaded through every
-  hop of the media path (decode → … → send), zero-cost when off.
+  hop of the media path (decode → … → send), zero-cost when off; and
+  :func:`hop`, the one span helper that also writes each hop into JAX's
+  profiler trace as ``rtc:<hop>``, beside the device's ops.
 * obs/recorder.py — :class:`FlightRecorder`: bounded per-session rings of
   completed timelines + an always-on structured event log, snapshotted
   automatically on StreamDegraded/FAILED and on demand via
@@ -24,4 +26,5 @@ from .trace import (  # noqa: F401
     SessionTracer,
     TraceController,
     get_trace,
+    hop,
 )

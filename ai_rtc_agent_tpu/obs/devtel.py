@@ -7,8 +7,7 @@ freeze that used to surface only as an unexplained SLO burn.  This
 module makes the device side first-class, with the SLO plane's
 always-on/zero-cost-off discipline (``DEVTEL_ENABLE=0`` removes it —
 the jax monitoring listener is never registered and every ``note_*``
-hot-path hook is one module-global read + None test, banked as
-``devtel_off_overhead_ratio`` by scripts/trace_overhead_bench.py):
+hot-path hook is one module-global read + None test):
 
 * **Compile watchdog** — every XLA compile is recorded via
   ``jax.monitoring``'s ``backend_compile_duration`` event with its

@@ -21,9 +21,11 @@ Event Format that Perfetto and ``chrome://tracing`` load directly:
 (header, then events, then frame timelines).
 
 ``start_jax_bridge``/``stop_jax_bridge`` are the opt-in hook that opens a
-``jax.profiler`` trace over the same window as the host-side capture, so
-a TPU timeline (XLA ops, transfers) and the frame timeline can be lined
-up over one incident.  jax is imported lazily and every failure degrades
+``jax.profiler`` trace over the same window as the host-side capture.
+That trace holds the device's ops (under the model's named scopes) and
+the program's own ``rtc:`` host spans (obs/trace.py ``hop``) on one clock;
+the frame timeline rendered here is a second file on the host's
+monotonic clock, lined up with it by wall time only.  jax is imported lazily and every failure degrades
 to a reported string — observability must never take the media path down.
 """
 

@@ -180,7 +180,9 @@ def _slo_families(slo: SloPlane) -> list:
     budget = _Family("slo_stage_budget_ms", "gauge")
     over = _Family("slo_stage_over_budget_total", "counter")
     for stage in STAGES:
-        h = slo.global_hist[stage]
+        h = slo.global_hist.get(stage)
+        if h is None:
+            continue  # a hop below a stage: no budget, no histogram
         for le, acc in h.cumulative():
             hist.samples.append(
                 labeled(

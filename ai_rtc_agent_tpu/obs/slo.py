@@ -33,8 +33,7 @@ Feed path: :class:`~.trace.SessionTracer` mints a timeline whenever the
 SLO plane is enabled (even with tracing off — the completed-timeline
 ring is only retained while tracing proper is on) and hands every sealed
 timeline to :meth:`SloPlane.observe`.  ``SLO_ENABLE=0`` restores the
-exact PR-5 hot path; scripts/trace_overhead_bench.py banks that off-mode
-residue as a guarded contract number (``slo_off_overhead_ratio``).
+exact PR-5 hot path.
 
 Label-cardinality rule (machine-checked: analysis/metric_cardinality.py):
 exported label values come ONLY from the closed STAGES enum — per-session
@@ -49,7 +48,6 @@ import logging
 import threading
 
 from ..utils import env
-from .trace import STAGES
 
 # fixed bucket upper bounds, milliseconds — chosen to straddle every
 # stage's regime (µs-scale packetize/protect up to multi-second compile
@@ -69,7 +67,10 @@ def stage_budgets_ms() -> dict:
     table complete in both directions).  Defaults bracket the 30 fps
     steady-state numbers with headroom; engine_step/batch_join budgets
     assume a warmed engine (compile stalls are the supervisor's problem,
-    not a latency SLO's)."""
+    not a latency SLO's).  The keys are the budgeted stages: the hops
+    below a stage that ``STAGES`` also names (``coerce``, ``launch`` …) are
+    spans of the profiler trace and scheduler counters, never FrameTrace
+    spans, and get no budget and no histogram."""
     return {
         "decode": env.get_float("SLO_DECODE_BUDGET_MS", 15.0),
         "ingest": env.get_float("SLO_INGEST_BUDGET_MS", 50.0),
@@ -202,9 +203,9 @@ class SessionSlo:
         self.plane = plane
         self.stages = {
             s: _StageSloState(
-                StageHistogram(plane.budgets_ms[s]), plane.slow_ticks
+                StageHistogram(budget_ms), plane.slow_ticks
             )
-            for s in STAGES
+            for s, budget_ms in plane.budgets_ms.items()
         }
 
     def tick(self):
@@ -290,7 +291,7 @@ class SloPlane:
         self.down_ticks = max(1, env.get_int("SLO_DOWN_TICKS", 6))
         self.budgets_ms = stage_budgets_ms()
         self.global_hist = {
-            s: StageHistogram(self.budgets_ms[s]) for s in STAGES
+            s: StageHistogram(b) for s, b in self.budgets_ms.items()
         }
         self.sessions: dict = {}
         self.frames_observed = 0
@@ -397,8 +398,7 @@ class SloPlane:
             "slo_frames_observed": self.frames_observed,
         }
         stages = {}
-        for name in STAGES:
-            h = self.global_hist[name]
+        for name, h in self.global_hist.items():
             if h.count == 0:
                 continue
             stages[name] = {

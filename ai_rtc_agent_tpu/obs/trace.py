@@ -23,10 +23,9 @@ Design rules, enforced by construction:
 * **zero-cost when off** — the hot path's entire residue is one attribute
   read (``controller.enabled``) at the mint site and one
   ``getattr(frame, "trace", None)`` per downstream hop
-  (:func:`get_trace`); no allocation, no lock, no clock read happens
-  until tracing is actually enabled.  scripts/trace_overhead_bench.py
-  banks the measured off-mode overhead into PERF_LOG.jsonl as a guarded
-  contract number.
+  (:func:`get_trace`); no ``FrameTrace`` allocation and no lock happens
+  until tracing is actually enabled.  What it costs on the chip is what
+  the benchmark's runs read (``benchmark/``, PERF.md).
 * **allocation-light when on** — a trace is one ``__slots__`` object and
   two lists; span stamps are tuple appends; no dicts on the per-span
   path.
@@ -40,6 +39,18 @@ Design rules, enforced by construction:
 * **all spans close on all paths** — the span-pairing checker
   (analysis/span_pairing.py) verifies every ``trace.begin(name)`` in
   package code has a matching ``end``/context-manager exit.
+
+The same hop sites also write to **JAX's profiler trace** (:func:`hop`):
+each is a ``jax.profiler.TraceAnnotation`` named ``rtc:<hop>``, so a
+profiler session (``POST /debug/trace {"jax_profiler_dir": ...}``, the
+benchmark's traced run) holds the host's hops and the device's ops on ONE
+clock — and the model names its own parts there too (``jax.named_scope``
+in ``models/`` and ``stream/engine.py``).  With no profiler session open
+an annotation is a flag test; the hop's two clock reads feed whoever
+asked: the ``FrameTrace`` where one rides the frame, and the batch
+scheduler's always-on per-hop counters (``BatchScheduler.snapshot()``
+``batchsched_hop_*``), which is how bare-``ndarray`` tiers — no per-frame
+timeline — are still spanned and counted.
 
 Knobs (docs/environment.md "Tracing & flight recorder"): ``TRACE_ENABLE``,
 ``TRACE_RING_FRAMES``, ``TRACE_MAX_CAPTURE_S``.
@@ -68,6 +79,24 @@ STAGES = (
     "packetize",    # AU -> RTP packets
     "protect",      # SRTP protect_frame
     "send",         # socket flush
+    # -- hops below a stage (stream/scheduler.py, server/tracks.py): spans
+    # of the profiler trace and keys of the scheduler's hop counters, never
+    # FrameTrace spans, so they carry no SLO budget (obs/slo.py budgets
+    # the stages above)
+    "pull_wait",          # the track's wait for its source (counter only:
+                          # no span is held across an await)
+    "coerce",             # duck-typed frame -> [H,W,3] uint8 (child of submit)
+    "stage_h2d",          # stage_frame: the H2D copy is started (child of submit)
+    "enqueue",            # scheduler lock + queue push; holds an inline dispatch
+    "enqueue_lock_wait",  # the wait for the scheduler's lock inside enqueue
+                          # (counter only)
+    "dispatch",           # _step_batch_locked, caller's or dispatcher thread
+    "assemble",           # bucket layout + the frame batch (child of dispatch)
+    "launch",             # the jitted bucket call returns (child of dispatch)
+    "readback_start",     # row slices + copy_to_host_async (child of dispatch)
+    "window_wait",        # dispatcher parked on the window or the in-flight cap
+    "await_row",          # future wait + the blocking row readback (child of fetch)
+    "finish_output",      # safety check + pts wrap (child of fetch)
 )
 
 # terminal markers — how a frame left the pipeline
@@ -78,6 +107,52 @@ TERMINAL_DROPPED = "dropped"
 TERMINALS = (
     TERMINAL_SENT, TERMINAL_SHED, TERMINAL_PASSTHROUGH, TERMINAL_DROPPED,
 )
+
+
+_HOP_PREFIX = "rtc:"
+_annotation = None  # jax.profiler.TraceAnnotation, imported on first use:
+                    # the fleet router imports this module and stays jax-free
+
+
+class hop:
+    """``with hop("dispatch", k=2, cause="window") as h:`` — THE span
+    helper of the program's host side, and the only place that builds a
+    profiler annotation.  Enters a ``jax.profiler.TraceAnnotation`` named
+    ``rtc:<name>`` carrying ``ids`` (a flag test while no profiler session
+    is open), reads the clock once on each side, and on exit stamps the
+    span on ``frame_trace`` under the same name when one is given.  The two
+    stamps stay on the object (``t0``, ``t1``, ``seconds``) for whoever
+    else asked: a FrameTrace span under another name, the pending frame's
+    counter slots.  One thread, never across an ``await``: an annotation
+    belongs to the thread that entered it."""
+
+    __slots__ = ("name", "frame_trace", "t0", "t1", "_ann")
+
+    def __init__(self, name: str, frame_trace=None, **ids):
+        global _annotation
+        if _annotation is None:
+            from jax.profiler import TraceAnnotation
+
+            _annotation = TraceAnnotation
+        self.name = name
+        self.frame_trace = frame_trace
+        self._ann = _annotation(_HOP_PREFIX + name, **ids)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1 = time.monotonic()
+        self._ann.__exit__(*exc)
+        if self.frame_trace is not None:
+            self.frame_trace.add_span(self.name, self.t0, self.t1)
+        return False
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
 
 
 def safe_list(dq) -> list:

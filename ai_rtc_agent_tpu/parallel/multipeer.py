@@ -64,18 +64,22 @@ def make_bucket_step(vstep, capacity: int, scatter_output: bool = True):
     buckets)."""
 
     def bucket(params, states, frames_k, idx):
-        sub = jax.tree.map(lambda a: jnp.take(a, idx, axis=0), states)
+        # the jitted function keeps the name ``bucket``: the trace readers
+        # find the step's program by it (``jit_bucket``)
+        with jax.named_scope("gather"):
+            sub = jax.tree.map(lambda a: jnp.take(a, idx, axis=0), states)
         new_sub, out = vstep(params, sub, frames_k)
-        new_states = jax.tree.map(
-            lambda full, ns: full.at[idx].set(ns), states, new_sub
-        )
-        if not scatter_output:
-            return new_states, out
-        # scatter into a full-capacity output so callers keep indexing by
-        # slot id (rows not in idx are zeros, discarded)
-        full_out = jnp.zeros(
-            (capacity,) + out.shape[1:], out.dtype
-        ).at[idx].set(out)
+        with jax.named_scope("scatter"):
+            new_states = jax.tree.map(
+                lambda full, ns: full.at[idx].set(ns), states, new_sub
+            )
+            if not scatter_output:
+                return new_states, out
+            # scatter into a full-capacity output so callers keep indexing
+            # by slot id (rows not in idx are zeros, discarded)
+            full_out = jnp.zeros(
+                (capacity,) + out.shape[1:], out.dtype
+            ).at[idx].set(out)
         return new_states, full_out
 
     return bucket

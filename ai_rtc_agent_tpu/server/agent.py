@@ -1794,8 +1794,11 @@ async def debug_trace(request):
 
     Captures are bounded by TRACE_MAX_CAPTURE_S — a forgotten start can
     never leave per-frame allocation on forever.  The optional
-    jax.profiler bridge opens a TPU trace over the same window so the
-    device timeline and the host frame timeline line up."""
+    jax.profiler bridge opens a TPU trace over the same window: that one
+    trace holds the device's ops under the model's named scopes and the
+    program's ``rtc:`` host spans (obs/trace.py ``hop``) on one clock.
+    The per-frame FrameTrace timeline stays a second file on the host's
+    monotonic clock."""
     flight = request.app.get("flight")
     if flight is None:
         return web.Response(status=404, text="flight recorder disabled")

@@ -63,6 +63,10 @@ class VideoStreamTrack:
         )
         if not hasattr(pipeline, "submit"):
             self.pipeline_depth = 1
+        # the batch scheduler's session counts how long this track waited
+        # for its source (hop ``pull_wait``); wrappers around the session
+        # pass the attribute through, other pipelines have no such counter
+        self._note_pull_wait = getattr(pipeline, "note_pull_wait", None)
         # in-flight bound: the submit loops below never hold more than
         # `pipeline_depth` entries (single-frame path) / batches (fbs path)
         self._pending: deque = deque(maxlen=self.pipeline_depth)
@@ -108,7 +112,12 @@ class VideoStreamTrack:
         edge) — the half-deadline target keeps freshness p99 comfortably
         inside it.  A stale frame with nothing behind it is still
         delivered — a late frame beats a frozen stream."""
+        # a counter, not a span: an annotation belongs to its thread, and
+        # tasks interleave on the event loop's thread across this await
+        t_pull = time.monotonic()
         frame = await self.track.recv()
+        if self._note_pull_wait is not None:
+            self._note_pull_wait(time.monotonic() - t_pull)
         tracer = self.tracer
         trace = tracer.attach(frame) if tracer is not None else None
         ov = self.overload

@@ -226,19 +226,23 @@ def make_step_fn(models: StreamModels, cfg: StreamConfig,
         def run_unet(x, t, ctx, a, cond):
             """-> (model_out, deep_h_or_None)."""
             if cond is not None:  # ControlNet path (unet_variant=="full")
-                dres, mres = models.controlnet(
-                    params, x, t, ctx, cond.astype(dt), a, state["cnet_scale"]
-                )
-                return models.unet(
-                    params, x, t, ctx, a, down_residuals=dres, mid_residual=mres
-                ), None
-            if unet_variant == "capture":
-                return models.unet_capture(params, x, t, ctx, a)
-            if unet_variant == "cached":
-                return models.unet_cached(
-                    params, x, t, ctx, a, state["unet_cache"]
-                ), None
-            return models.unet(params, x, t, ctx, a), None
+                with jax.named_scope("controlnet"):
+                    dres, mres = models.controlnet(
+                        params, x, t, ctx, cond.astype(dt), a, state["cnet_scale"]
+                    )
+                with jax.named_scope("unet"):
+                    return models.unet(
+                        params, x, t, ctx, a,
+                        down_residuals=dres, mid_residual=mres,
+                    ), None
+            with jax.named_scope("unet"):
+                if unet_variant == "capture":
+                    return models.unet_capture(params, x, t, ctx, a)
+                if unet_variant == "cached":
+                    return models.unet_cached(
+                        params, x, t, ctx, a, state["unet_cache"]
+                    ), None
+                return models.unet(params, x, t, ctx, a), None
 
         t = coeffs.timesteps
         added = None
@@ -273,8 +277,9 @@ def make_step_fn(models: StreamModels, cfg: StreamConfig,
                 else None
             )
             out, new_cache = run_unet(x2, t2, ctx2, added2, cond2)
-            eps_u, eps_c = jnp.split(out, 2, axis=0)
-            eps = R.combine_full(eps_u, eps_c, state["guidance"])
+            with jax.named_scope("epilogue"):
+                eps_u, eps_c = jnp.split(out, 2, axis=0)
+                eps = R.combine_full(eps_u, eps_c, state["guidance"])
             new_stock = stock
         else:
             eps_c, new_cache = run_unet(x_t, t, cond, added, cond_img)
@@ -284,19 +289,28 @@ def make_step_fn(models: StreamModels, cfg: StreamConfig,
                 eps = eps_c
                 new_stock = stock
             else:  # self | initialize
-                eps = R.combine_residual(
-                    eps_c, stock.astype(dt), state["guidance"], state["delta"]
-                )
-                if cfg.cfg_type == "self":
-                    new_stock = R.update_stock_noise(
-                        stock.astype(dt), eps_c, coeffs.alpha, coeffs.sigma
+                with jax.named_scope("epilogue"):
+                    eps = R.combine_residual(
+                        eps_c, stock.astype(dt), state["guidance"], state["delta"]
                     )
-                else:
-                    new_stock = stock
+                    if cfg.cfg_type == "self":
+                        new_stock = R.update_stock_noise(
+                            stock.astype(dt), eps_c, coeffs.alpha, coeffs.sigma
+                        )
+                    else:
+                        new_stock = stock
         return eps, new_stock, new_cache
 
     def step(params, state, frame_u8):
-        """frame_u8: [fbs,H,W,3] (or [H,W,3] when fbs==1) uint8 RGB."""
+        """frame_u8: [fbs,H,W,3] (or [H,W,3] when fbs==1) uint8 RGB.
+
+        Every model part runs under a ``jax.named_scope`` (``preprocess``,
+        ``vae_encode``, ``add_noise``, ``unet`` and its blocks,
+        ``epilogue``, ``vae_decode``, ``postprocess``): metadata only —
+        the compiled program and its numbers do not change — so that a
+        profiler trace can say which part a microsecond of device time
+        belongs to.  No scope is named after a Mosaic kernel: the trace
+        readers find a kernel by its name inside an op's name."""
         coeffs = _as_step_coeffs(state["coeffs"])
 
         # ---- per-session style adapters (adapters/): graft the slot's
@@ -315,12 +329,15 @@ def make_step_fn(models: StreamModels, cfg: StreamConfig,
 
         # ---- encode the incoming frame(s) to the noisiest stage ----
         if cfg.mode == "img2img":
-            img = I.preprocess_uint8(frame_u8, dtype=dt)  # [fbs,H,W,3]
-            z0 = models.vae_encode(params, img)  # [fbs,h,w,4]
+            with jax.named_scope("preprocess"):
+                img = I.preprocess_uint8(frame_u8, dtype=dt)  # [fbs,H,W,3]
+            with jax.named_scope("vae_encode"):
+                z0 = models.vae_encode(params, img)  # [fbs,h,w,4]
             if cfg.do_add_noise:
-                a0 = coeffs.alpha[:fbs].reshape(-1, 1, 1, 1).astype(dt)
-                s0 = coeffs.sigma[:fbs].reshape(-1, 1, 1, 1).astype(dt)
-                x_new = a0 * z0 + s0 * state["noise"][:fbs].astype(dt)
+                with jax.named_scope("add_noise"):
+                    a0 = coeffs.alpha[:fbs].reshape(-1, 1, 1, 1).astype(dt)
+                    s0 = coeffs.sigma[:fbs].reshape(-1, 1, 1, 1).astype(dt)
+                    x_new = a0 * z0 + s0 * state["noise"][:fbs].astype(dt)
             else:
                 x_new = z0
         else:  # txt2img: fresh noise enters the ring
@@ -330,8 +347,9 @@ def make_step_fn(models: StreamModels, cfg: StreamConfig,
         cond_full = None
         new_cnet_ring = None
         if cfg.use_controlnet:
-            src = I.preprocess_uint8(frame_u8, dtype=dt)
-            cond_new = _annotate(src, cfg, params)  # [fbs,H,W,3]
+            with jax.named_scope("annotate"):
+                src = I.preprocess_uint8(frame_u8, dtype=dt)
+                cond_new = _annotate(src, cfg, params)  # [fbs,H,W,3]
             # state["cnet_cond"] is [B-fbs,H,W,3] (possibly empty), aligned
             # with x_buf; rotation mirrors the latent ring exactly
             cond_full = jnp.concatenate(
@@ -351,68 +369,72 @@ def make_step_fn(models: StreamModels, cfg: StreamConfig,
                     params, x_t, state, coeffs, state["stock"], cond_full,
                     return_raw=True,
                 )
-                kc = coeffs
-                if cfg.scheduler == "turbo":
-                    # turbo step is pred_x0 == LCM blend with c_skip=0, c_out=1
-                    kc = L.StepCoeffs(
-                        coeffs.timesteps, coeffs.alpha, coeffs.sigma,
-                        jnp.zeros_like(coeffs.c_skip),
-                        jnp.ones_like(coeffs.c_out),
-                        coeffs.next_alpha, coeffs.next_sigma,
-                    )
-                # align noise with "next stage": entry b renoises with the
-                # noise of slot b+fbs; exit entries get next_sigma=0
-                noise_next = (
-                    jnp.concatenate(
-                        [state["noise"][fbs:], jnp.zeros_like(state["noise"][:fbs])],
-                        axis=0,
-                    )
-                    if B > fbs
-                    else jnp.zeros_like(state["noise"])
-                )
                 from ..ops.pallas.fused_scheduler import fused_stream_epilogue
 
-                denoised, advanced, new_stock = fused_stream_epilogue(
-                    x_t,
-                    eps_c,
-                    state["stock"].astype(dt),
-                    noise_next.astype(dt),
-                    kc,
-                    state["guidance"],
-                    state["delta"],
-                    cfg_type=cfg.cfg_type,
-                )
-                out_latent = denoised[B - fbs :]
-                new_buf = advanced[: B - fbs] if B > fbs else state["x_buf"]
+                # the wrapper around the kernel rides the same scope as the
+                # plain path below; the kernel keeps its own name inside it
+                with jax.named_scope("epilogue"):
+                    kc = coeffs
+                    if cfg.scheduler == "turbo":
+                        # turbo step is pred_x0 == LCM blend with c_skip=0, c_out=1
+                        kc = L.StepCoeffs(
+                            coeffs.timesteps, coeffs.alpha, coeffs.sigma,
+                            jnp.zeros_like(coeffs.c_skip),
+                            jnp.ones_like(coeffs.c_out),
+                            coeffs.next_alpha, coeffs.next_sigma,
+                        )
+                    # align noise with "next stage": entry b renoises with the
+                    # noise of slot b+fbs; exit entries get next_sigma=0
+                    noise_next = (
+                        jnp.concatenate(
+                            [state["noise"][fbs:], jnp.zeros_like(state["noise"][:fbs])],
+                            axis=0,
+                        )
+                        if B > fbs
+                        else jnp.zeros_like(state["noise"])
+                    )
+                    denoised, advanced, new_stock = fused_stream_epilogue(
+                        x_t,
+                        eps_c,
+                        state["stock"].astype(dt),
+                        noise_next.astype(dt),
+                        kc,
+                        state["guidance"],
+                        state["delta"],
+                        cfg_type=cfg.cfg_type,
+                    )
+                    out_latent = denoised[B - fbs :]
+                    new_buf = advanced[: B - fbs] if B > fbs else state["x_buf"]
             else:
                 eps, new_stock, new_cache = unet_with_guidance(
                     params, x_t, state, coeffs, state["stock"], cond_full
                 )
-                if cfg.scheduler == "turbo":
-                    denoised = L.turbo_denoise(x_t, eps, coeffs, cfg.prediction_type)
-                else:
-                    denoised = L.lcm_denoise(x_t, eps, coeffs, cfg.prediction_type)
+                with jax.named_scope("epilogue"):
+                    if cfg.scheduler == "turbo":
+                        denoised = L.turbo_denoise(x_t, eps, coeffs, cfg.prediction_type)
+                    else:
+                        denoised = L.lcm_denoise(x_t, eps, coeffs, cfg.prediction_type)
 
-                # ---- rotate the ring: advance every entry one stage ----
-                out_latent = denoised[B - fbs :]
-                if B > fbs:
-                    stage_noise = state["noise"][fbs:].astype(dt)
-                    advanced = L.renoise_next(
-                        denoised[: B - fbs],
-                        stage_noise,
-                        L.StepCoeffs(
-                            *[
-                                getattr(coeffs, f)[: B - fbs]
-                                for f in (
-                                    "timesteps", "alpha", "sigma", "c_skip", "c_out",
-                                    "next_alpha", "next_sigma",
-                                )
-                            ]
-                        ),
-                    )
-                    new_buf = advanced
-                else:
-                    new_buf = state["x_buf"]
+                    # ---- rotate the ring: advance every entry one stage ----
+                    out_latent = denoised[B - fbs :]
+                    if B > fbs:
+                        stage_noise = state["noise"][fbs:].astype(dt)
+                        advanced = L.renoise_next(
+                            denoised[: B - fbs],
+                            stage_noise,
+                            L.StepCoeffs(
+                                *[
+                                    getattr(coeffs, f)[: B - fbs]
+                                    for f in (
+                                        "timesteps", "alpha", "sigma", "c_skip", "c_out",
+                                        "next_alpha", "next_sigma",
+                                    )
+                                ]
+                            ),
+                        )
+                        new_buf = advanced
+                    else:
+                        new_buf = state["x_buf"]
         else:
             # sequential (non-stream) mode: all stages for this frame now —
             # n UNet passes of batch fbs; parity with the reference's
@@ -442,17 +464,20 @@ def make_step_fn(models: StreamModels, cfg: StreamConfig,
                         axis=0,
                     )
                 )
-                if cfg.scheduler == "turbo":
-                    d = L.turbo_denoise(x, eps, sub, cfg.prediction_type)
-                else:
-                    d = L.lcm_denoise(x, eps, sub, cfg.prediction_type)
-                x = L.renoise_next(d, state["noise"][sl].astype(dt), sub)
+                with jax.named_scope("epilogue"):
+                    if cfg.scheduler == "turbo":
+                        d = L.turbo_denoise(x, eps, sub, cfg.prediction_type)
+                    else:
+                        d = L.lcm_denoise(x, eps, sub, cfg.prediction_type)
+                    x = L.renoise_next(d, state["noise"][sl].astype(dt), sub)
             out_latent = x
             new_buf = state["x_buf"]
 
         # ---- decode + postprocess in-graph ----
-        img_out = models.vae_decode(params, out_latent)
-        out_u8 = I.postprocess_uint8(img_out.astype(jnp.float32))
+        with jax.named_scope("vae_decode"):
+            img_out = models.vae_decode(params, out_latent)
+        with jax.named_scope("postprocess"):
+            out_u8 = I.postprocess_uint8(img_out.astype(jnp.float32))
 
         new_state = dict(state)
         new_state["x_buf"] = new_buf

@@ -21,13 +21,12 @@ Differences, all deliberate and TPU-motivated:
 from __future__ import annotations
 
 import logging
-import time
 from typing import Sequence
 
 import numpy as np
 
 from ..models import registry
-from ..obs.trace import get_trace
+from ..obs.trace import get_trace, hop
 from ..utils import env
 from .engine import StreamConfig, StreamEngine
 
@@ -220,20 +219,14 @@ class StreamDiffusionPipeline:
 
     def __call__(self, frame):
         trace = get_trace(frame)  # None (one getattr) unless tracing is on
-        if trace is None:
+        with hop("submit", trace):
             pre = self.preprocess(frame)
+        with hop("engine_step", trace):  # sync path: the whole device step
             out = self.predict(pre)
-            if hasattr(frame, "pts") and not env.hw_encode():
-                return self.postprocess(out, frame)
-            return out
-        with trace.span("submit"):
-            pre = self.preprocess(frame)
-        with trace.span("engine_step"):  # sync path: the whole device step
-            out = self.predict(pre)
-        if self.engine.last_submit_was_skip:
+        if trace is not None and self.engine.last_submit_was_skip:
             trace.mark("similar_skip")
         if hasattr(frame, "pts") and not env.hw_encode():
-            with trace.span("postprocess"):
+            with hop("postprocess", trace):
                 return self.postprocess(out, frame)
         return out
 
@@ -244,13 +237,10 @@ class StreamDiffusionPipeline:
         handle for :meth:`fetch`.  Lets the caller keep several frames in
         flight so device compute, dispatch and readback overlap."""
         trace = get_trace(frame)
-        if trace is None:
-            pre = self.preprocess(frame)
-            return self.engine.submit(pre)
-        with trace.span("submit"):  # host preprocess + async device dispatch
+        with hop("submit", trace):  # host preprocess + async device dispatch
             pre = self.preprocess(frame)
             handle = self.engine.submit(pre)
-        if self.engine.last_submit_was_skip:
+        if trace is not None and self.engine.last_submit_was_skip:
             trace.mark("similar_skip")
         return handle
 
@@ -285,29 +275,25 @@ class StreamDiffusionPipeline:
     def fetch(self, handle, src_frame=None):
         """Resolve a submit() handle; attaches pts metadata like __call__."""
         trace = get_trace(src_frame) if src_frame is not None else None
-        if trace is not None:
-            t0 = time.monotonic()
-        out = self.engine.fetch(handle)
-        if trace is not None:
-            # resolve-end stamped BEFORE the safety checker: fetch is the
-            # blocking readback hop, and a CLIP forward riding its span
-            # would inflate exactly the histogram the SLO fetch budget
-            # fences (the scheduler's fetch stamps the same way)
-            t1 = time.monotonic()
+        # resolve-end stamped BEFORE the safety checker: fetch is the
+        # blocking readback hop, and a CLIP forward riding its span would
+        # inflate exactly the histogram the SLO fetch budget fences (the
+        # scheduler's fetch stamps the same way)
+        with hop("fetch", trace) as resolve:
+            out = self.engine.fetch(handle)
         if self.safety_checker is not None:
             out = self.safety_checker(out)
         if trace is not None:
-            # fetch = the blocking host-side resolve; engine_step = the
-            # frame's device residency, submit-end -> resolve-end (the
-            # host-observable bound on the async step — stamped OUTSIDE
-            # jit, the trace-purity checker holds that line)
-            trace.add_span("fetch", t0, t1)
+            # engine_step = the frame's device residency, submit-end ->
+            # resolve-end (the host-observable bound on the async step —
+            # stamped OUTSIDE jit, the trace-purity checker holds that line)
             sub_end = trace.span_end("submit")
-            trace.add_span("engine_step", sub_end if sub_end is not None else t0, t1)
+            trace.add_span(
+                "engine_step",
+                sub_end if sub_end is not None else resolve.t0, resolve.t1,
+            )
         if src_frame is not None and hasattr(src_frame, "pts") and not env.hw_encode():
-            if trace is None:
-                return self.postprocess(out, src_frame)
-            with trace.span("postprocess"):
+            with hop("postprocess", trace):
                 return self.postprocess(out, src_frame)
         return out
 
@@ -324,9 +310,7 @@ def finish_output(out, src_frame=None, safety_checker=None, trace=None):
     if src_frame is not None and hasattr(src_frame, "pts") and not env.hw_encode():
         from ..media.frames import wrap_processed
 
-        if trace is None:
-            return wrap_processed(out, src_frame)
-        with trace.span("postprocess"):
+        with hop("postprocess", trace):
             return wrap_processed(out, src_frame)
     return out
 

@@ -97,7 +97,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import devtel
-from ..obs.trace import get_trace, safe_list
+from ..obs.trace import get_trace, hop, safe_list
 from ..ops.pallas import mosaic_kernel_counts
 from ..parallel.multipeer import CapacityError, make_bucket_step
 from ..resilience import faults as _faults
@@ -131,6 +131,19 @@ __all__ = [
 # adapter_targets when a bank is bound.
 SESSION_SNAPSHOT_SCHEMA = 2
 
+# the hops the scheduler counts (``batchsched_hop_*`` in snapshot()): names
+# from the obs/trace.py STAGES taxonomy, the closed key set of the counters
+COUNTED_HOPS = (
+    "pull_wait", "coerce", "stage_h2d", "enqueue_lock_wait", "dispatch",
+    "launch", "await_row", "finish_output",
+)
+# why a step was dispatched when it was (``batchsched_dispatch_cause_total``):
+# solo = the one-live-session path in _enqueue; inline_full = this submit
+# completed the batch; window = the dispatcher went with who showed up;
+# backpressure = the dispatcher, every live session ready, held back only
+# by the in-flight cap until a batch resolved
+DISPATCH_CAUSES = ("solo", "inline_full", "window", "backpressure")
+
 
 class SnapshotMismatch(ValueError):
     """A session snapshot does not fit this scheduler — wrong schema
@@ -154,10 +167,13 @@ class _DispatchedBatch:
 
     __slots__ = (
         "rows", "host", "rlocks", "entries", "t_dispatch", "occupancy",
-        "resolved", "feed",
+        "resolved", "feed", "cause", "inflight", "starved", "dispatch_s",
+        "launch_s",
     )
 
-    def __init__(self, rows, entries, t_dispatch, occupancy, feed=True):
+    def __init__(self, rows, entries, t_dispatch, occupancy, feed=True,
+                 cause="solo", inflight=0, starved=False, dispatch_s=0.0,
+                 launch_s=0.0):
         self.rows = rows  # per-entry device buffers (async D2H in flight)
         self.host = [None] * len(rows)  # memoized per-row host copies
         self.rlocks = [threading.Lock() for _ in rows]
@@ -166,19 +182,32 @@ class _DispatchedBatch:
         self.occupancy = occupancy
         self.resolved = False  # first-row-resolved: accounting + in-flight
         self.feed = feed
+        # the dispatch's own stamps, folded into the scheduler's counters
+        # by the first resolver (_note_step): why it was dispatched (one of
+        # DISPATCH_CAUSES), the batches then in flight, whether it found
+        # the device drained, and the host seconds of _step_batch_locked
+        # and of the jitted call inside it
+        self.cause = cause
+        self.inflight = inflight
+        self.starved = starved
+        self.dispatch_s = dispatch_s
+        self.launch_s = launch_s
 
 
 class _PendingFrame:
     """One enqueued frame: the waiter future plus the stamps the
     observability spans need (enqueue -> dispatch = batch_join; dispatch
-    -> resolve = engine_step)."""
+    -> resolve = engine_step) and the submit-side hop stamps that fetch
+    folds into the scheduler's counters (None = the hop did not run)."""
 
     __slots__ = (
         "frame", "frame_dev", "future", "trace", "t_enq", "t_dispatch",
-        "occupancy", "skipped", "readback",
+        "occupancy", "skipped", "readback", "seq", "pull_wait_s",
+        "coerce_s", "stage_s", "lock_wait_s",
     )
 
-    def __init__(self, frame, trace=None):
+    def __init__(self, frame, trace=None, seq=0, pull_wait_s=None,
+                 coerce_s=None):
         self.frame = frame  # host pixels (shed-passthrough + similarity)
         self.frame_dev = None  # staged device copy (stage_frame at submit)
         self.future: Future = Future()
@@ -187,6 +216,13 @@ class _PendingFrame:
         self.t_dispatch: float | None = None
         self.occupancy = 0
         self.skipped = False
+        # the session's own count of submitted frames: with the slot, the
+        # identifier every profiler span of this frame carries
+        self.seq = seq
+        self.pull_wait_s: float | None = pull_wait_s
+        self.coerce_s: float | None = coerce_s
+        self.stage_s: float | None = None
+        self.lock_wait_s: float | None = None
         # (batch, row) of the _DispatchedBatch this frame rode — the
         # submitter resolves it directly at fetch, bypassing the future
         self.readback: tuple | None = None
@@ -234,6 +270,7 @@ class ScheduledSession:
         )
         self._last_pending: _PendingFrame | None = None
         self._had_output = False
+        self._pull_wait_s: float | None = None  # note_pull_wait -> next submit
         self.frames_submitted = 0
         self.frames_skipped_similar = 0
 
@@ -267,6 +304,13 @@ class ScheduledSession:
         overload plane's /metrics queue registry by the agent)."""
         return self._owner._queues[self.slot]
 
+    def note_pull_wait(self, seconds: float):
+        """How long the track waited for its source before the frame it
+        submits next (server/tracks.py ``_pull_fresh``; the supervisor's
+        wrappers pass the attribute through).  A counter only
+        (``pull_wait``): no span is held across an ``await``."""
+        self._pull_wait_s = seconds
+
     def submit(self, frame):
         """Coerce + enqueue one frame into the coalescing window; returns
         a handle for :meth:`fetch`.  A similarity skip never enters the
@@ -275,18 +319,19 @@ class ScheduledSession:
         from .pipeline import coerce_frame
 
         trace = get_trace(frame)
-        if trace is None:
-            arr = coerce_frame(frame, self._owner.height, self._owner.width)
-            return self._submit_arr(arr, trace)
-        with trace.span("submit"):
-            arr = coerce_frame(frame, self._owner.height, self._owner.width)
-            handle = self._submit_arr(arr, trace)
-        if handle.skipped:
+        self.frames_submitted += 1
+        slot, seq = self.slot, self.frames_submitted
+        with hop("submit", trace, slot=slot, seq=seq):
+            with hop("coerce", slot=slot, seq=seq) as coerce:
+                arr = coerce_frame(frame, self._owner.height, self._owner.width)
+            handle = self._submit_arr(arr, trace, seq, coerce.seconds)
+        if trace is not None and handle.skipped:
             trace.mark("similar_skip")
         return handle
 
-    def _submit_arr(self, arr: np.ndarray, trace) -> _PendingFrame:
-        self.frames_submitted += 1
+    def _submit_arr(self, arr: np.ndarray, trace, seq: int,
+                    coerce_s: float) -> _PendingFrame:
+        pull_wait_s, self._pull_wait_s = self._pull_wait_s, None
         if (
             self._sim is not None
             and self._sim.should_skip(
@@ -299,7 +344,7 @@ class ScheduledSession:
             # the most recent submit resolves with, so resolution order
             # stays correct even while that step is still in flight
             self.frames_skipped_similar += 1
-            p = _PendingFrame(arr, trace)
+            p = _PendingFrame(arr, trace, seq, pull_wait_s, coerce_s)
             p.skipped = True
             last = self._last_pending
 
@@ -317,7 +362,7 @@ class ScheduledSession:
 
             last.future.add_done_callback(_copy)
             return p
-        p = _PendingFrame(arr, trace)
+        p = _PendingFrame(arr, trace, seq, pull_wait_s, coerce_s)
         # stage the H2D copy NOW, on the caller's thread, before any
         # scheduler lock: concurrent sessions' transfers overlap each
         # other and in-flight compute instead of serializing behind the
@@ -328,9 +373,11 @@ class ScheduledSession:
         # reshape op (per-op dispatch is real money at small step sizes).
         # On a dp mesh the copy lands on the SLOT'S OWN SHARD — never
         # device 0 followed by a cross-device reshuffle at dispatch
-        p.frame_dev = stage_frame(
-            arr[None], device=self._owner._slot_device(self.slot)
-        )
+        with hop("stage_h2d", slot=self.slot, seq=seq) as stage:
+            p.frame_dev = stage_frame(
+                arr[None], device=self._owner._slot_device(self.slot)
+            )
+        p.stage_s = stage.seconds
         self._owner._enqueue(self.slot, p)
         if self._sim is not None:
             # dup-chain anchor — only the similarity filter ever reads it
@@ -341,42 +388,50 @@ class ScheduledSession:
         """Resolve a submit handle to the session's output frame.
         ShedFrame markers (window shed under pressure) pass through raw so
         the resilience wrapper accounts them as passthrough."""
-        trace = handle.trace
-        if trace is None and src_frame is not None:
-            trace = get_trace(src_frame)
-        t0 = time.monotonic()
-        fi = None
+        with hop("fetch", slot=self.slot, seq=handle.seq):
+            return self._fetch(handle, src_frame)
+
+    def _await_row(self, handle: _PendingFrame, t0: float):
+        """Block until this frame's row is on the host -> (out, t1, fi);
+        a slot released with the frame still queued raises CancelledError."""
         if handle.readback is not None:
             # fast path: resolve THIS session's row right here (the
             # dedicated-engine flow — submit dispatched, fetch blocks on
             # its own per-slot readback, zero thread handoffs)
             batch, row, fi = handle.readback
-            out, t1 = self._owner._resolve_row(batch, row, t0)
-        else:
+            return (*self._owner._resolve_row(batch, row, t0), fi)
+        out = handle.future.result(timeout=self._owner.fetch_timeout)
+        if (
+            isinstance(out, tuple)
+            and len(out) == 3
+            and isinstance(out[0], _DispatchedBatch)
+        ):
+            # this frame was waiting in the window when a dispatch
+            # (inline or dispatcher) claimed it — the marker routes us
+            # to our own per-slot row of that batch
+            return (*self._owner._resolve_row(out[0], out[1], t0), out[2])
+        return out, time.monotonic(), None
+
+    def _fetch(self, handle: _PendingFrame, src_frame):
+        trace = handle.trace
+        if trace is None and src_frame is not None:
+            trace = get_trace(src_frame)
+        owner = self._owner
+        with hop("await_row", slot=self.slot, seq=handle.seq) as wait:
+            t0 = wait.t0
             try:
-                out = handle.future.result(timeout=self._owner.fetch_timeout)
+                out, t1, fi = self._await_row(handle, t0)
             except CancelledError:
                 # teardown race: the slot was released with this frame
                 # queued — deliver passthrough, never crash the (dying)
                 # track
                 return ShedFrame(handle.frame)
-            if (
-                isinstance(out, tuple)
-                and len(out) == 3
-                and isinstance(out[0], _DispatchedBatch)
-            ):
-                # this frame was waiting in the window when a dispatch
-                # (inline or dispatcher) claimed it — the marker routes us
-                # to our own per-slot row of that batch
-                fi = out[2]
-                out, t1 = self._owner._resolve_row(out[0], out[1], t0)
-            else:
-                t1 = time.monotonic()
         if fi is not None and not isinstance(out, ShedFrame):
             # fbs>1: the memoized row is the session's [fbs, H, W, 3]
             # group — this handle owns exactly one consecutive frame of it
             out = out[fi]
         if isinstance(out, ShedFrame):
+            owner._fold_frame(handle, wait.seconds, None)
             return out
         self._had_output = True
         if trace is not None:
@@ -396,10 +451,13 @@ class ScheduledSession:
             trace.add_span("fetch", t0, t1)
         from .pipeline import finish_output
 
-        return finish_output(
-            out, src_frame,
-            safety_checker=self._owner.safety_checker, trace=trace,
-        )
+        with hop("finish_output", slot=self.slot, seq=handle.seq) as finish:
+            result = finish_output(
+                out, src_frame,
+                safety_checker=owner.safety_checker, trace=trace,
+            )
+        owner._fold_frame(handle, wait.seconds, finish.seconds)
+        return result
 
     def __call__(self, frame):
         return self.fetch(self.submit(frame), frame)
@@ -718,8 +776,11 @@ class BatchScheduler:
         # only, percentiles computed per snapshot over <=512 floats)
         self._occ: deque = deque(maxlen=512)
         self._waits: deque = deque(maxlen=512)
-        self._occ_hist: dict = {}
-        self.steps_total = 0
+        self._reset_counters_locked()
+        # bucket steps launched by this process, rehearsal included, never
+        # reset: the n-th ``rtc:launch`` span is the n-th ``jit_bucket``
+        # event of the chip's in-order program stream (dispatch lock held)
+        self._dispatch_seq = 0
         self._aot_adopted = False
         # -- engine fault domain (resilience/engine_guard.py) ---------------
         # duck-typed attach (attach_guard) — no construction-order coupling
@@ -1610,6 +1671,19 @@ class BatchScheduler:
                     k, self.max_sessions, v, self.dp,
                 )
 
+    def compiled_text(self) -> dict:
+        """{bucket label: HLO text of its compiled executable}, for the
+        buckets prewarm_buckets compiled (an AOT-adopted or lazily jitted
+        bucket has no compiled object to read).  A profiler trace names a
+        device op by its HLO instruction only; the instruction's
+        ``metadata={op_name=...}`` in this text is where its
+        ``jax.named_scope`` path is (benchmark/scope_reduce.py)."""
+        return {
+            self._bucket_label(k, v): step.as_text()
+            for (k, v), step in self._bucket_steps.items()
+            if hasattr(step, "as_text")
+        }
+
     def rehearse(self):
         """Walk throw-away sessions through everything a real one will do —
         claim, one frame through every bucket size, a prompt / t-index /
@@ -1660,10 +1734,7 @@ class BatchScheduler:
             self._snap_rows, self._last_snap_t = {}, 0.0
             self._tick = 0
         with self._stats_lock:
-            self.steps_total = 0
-            self._occ.clear()
-            self._waits.clear()
-            self._occ_hist = {}
+            self._reset_counters_locked()
         logger.info(
             "batchsched rehearsed %d session(s) through buckets %s",
             len(sessions), self._bucket_sizes,
@@ -1859,7 +1930,8 @@ class BatchScheduler:
             # only shed at its deadline — recv never hangs on a quarantine
             self._evict(pending, "engine-quarantined")
             return
-        with self._has_work:
+        with hop("enqueue", slot=slot, seq=pending.seq) as enqueue, self._has_work:
+            pending.lock_wait_s = time.monotonic() - enqueue.t0
             room = (
                 self._batches_in_flight(pending.t_enq) < self.PIPELINE_DEPTH
             )
@@ -1873,7 +1945,9 @@ class BatchScheduler:
                 # — dispatch THIS frame without touching the window queue
                 # at all (the pass-through-cheap promise: a lock and a
                 # gather/scatter, not a queue round-trip + thread handoff)
-                self._dispatch_entries_locked([(slot, [pending])], pending)
+                self._dispatch_entries_locked(
+                    [(slot, [pending])], pending, "solo"
+                )
                 return
             self._queues[slot].push(pending, stamp=pending.t_enq)
             if room and len(self._waiting_slots()) >= self.active.count(
@@ -1911,7 +1985,25 @@ class BatchScheduler:
                 entries.append((s, plist))
         if not entries:
             return
-        self._dispatch_entries_locked(entries, submitter)
+        self._dispatch_entries_locked(entries, submitter, "inline_full")
+
+    def _device_drained_locked(self) -> bool:
+        """Has every batch dispatched before now left the device?  True
+        when each earlier batch is resolved or its rows are all
+        ``is_ready()`` (a non-blocking question, no transfer): the step
+        about to be dispatched then finds the chip idle — a bubble the
+        host let open, counted with no profiler attached
+        (``batchsched_dispatch_starved_total``)."""
+        for b in self._batches:
+            if b.resolved:
+                continue
+            for r in b.rows:
+                try:
+                    if r is not None and not r.is_ready():
+                        return False
+                except (AttributeError, RuntimeError):
+                    pass  # not a device buffer any more: nothing in flight
+        return True
 
     def _step_batch_locked(self, entries):
         """The ONE dispatch sequence both paths share (dispatcher loop and
@@ -1920,33 +2012,36 @@ class BatchScheduler:
         dp mesh), stamp, step, slice per-slot rows on device — each FROM
         ITS OWN SHARD when sharded — and kick each row's async readback.
         Caller holds the lock; a raising step is the caller's to deliver
-        to the waiters.  -> (rows, t_disp, occ, feed): ``feed`` False on
-        a bucket variant's first use (a lazy compile may ride it — not a
-        capacity signal)."""
+        to the waiters.  Runs inside the caller's ``rtc:dispatch`` span
+        and opens its children ``assemble``, ``launch`` and
+        ``readback_start``.  -> (rows, t_disp, feed, launch seconds):
+        ``feed`` False on a bucket variant's first use (a lazy compile may
+        ride it — not a capacity signal)."""
         idx = [s for s, _ in entries]
         k = self._bucket_for(len(idx))
-        pad, positions = self._layout_pad(idx, k)
-        # frames were staged to device ROW-SHAPED at submit time
-        # (stage_frame, outside any lock, onto the slot's own shard): a
-        # solo bucket consumes the staged buffer with ZERO extra device
-        # ops, a wider bucket pays one concatenate/stack per shard —
-        # never an H2D copy under the dispatch lock
-        by_slot = {}
-        for s, plist in entries:
-            bufs = [
-                stage_frame(p.frame[None], device=self._slot_device(s))
-                if p.frame_dev is None
-                else p.frame_dev
-                for p in plist
-            ]
-            if self.fbs == 1:
-                by_slot[s] = bufs[0]
-            else:
-                # a (defensive) short group pads by repeating its last
-                # frame — identical compute, the absent handles were shed
-                bufs = (bufs + [bufs[-1]] * self.fbs)[: self.fbs]
-                by_slot[s] = jnp.concatenate(bufs, axis=0)
-        frames_k = self._assemble_frames(pad, by_slot, k)
+        with hop("assemble", k=k):
+            pad, positions = self._layout_pad(idx, k)
+            # frames were staged to device ROW-SHAPED at submit time
+            # (stage_frame, outside any lock, onto the slot's own shard): a
+            # solo bucket consumes the staged buffer with ZERO extra device
+            # ops, a wider bucket pays one concatenate/stack per shard —
+            # never an H2D copy under the dispatch lock
+            by_slot = {}
+            for s, plist in entries:
+                bufs = [
+                    stage_frame(p.frame[None], device=self._slot_device(s))
+                    if p.frame_dev is None
+                    else p.frame_dev
+                    for p in plist
+                ]
+                if self.fbs == 1:
+                    by_slot[s] = bufs[0]
+                else:
+                    # a (defensive) short group pads by repeating its last
+                    # frame — identical compute, the absent handles were shed
+                    bufs = (bufs + [bufs[-1]] * self.fbs)[: self.fbs]
+                    by_slot[s] = jnp.concatenate(bufs, axis=0)
+            frames_k = self._assemble_frames(pad, by_slot, k)
         t_disp = time.monotonic()
         occ = len(entries)
         for _, plist in entries:
@@ -1991,17 +2086,19 @@ class BatchScheduler:
             with devtel.compile_scope(self._bucket_label(k, variant)):
                 return step(*step_args)
 
-        guard = self._guard
-        if guard is None:
-            self.states, out = _device_step()
-        else:
-            # deadline-bounded dispatch (resilience/engine_guard.py): a
-            # wedged or lost device trips the guard and raises — states
-            # are assigned only on success, so an abandoned worker's late
-            # result can never race the rebuild's fresh stack.  Cold
-            # bucket variants get the long compile deadline (the
-            # warm-step rule's analog).
-            self.states, out = guard.dispatch(_device_step, cold=not feed)
+        self._dispatch_seq += 1
+        with hop("launch", step=self._dispatch_seq, k=k) as launch:
+            guard = self._guard
+            if guard is None:
+                self.states, out = _device_step()
+            else:
+                # deadline-bounded dispatch (resilience/engine_guard.py): a
+                # wedged or lost device trips the guard and raises — states
+                # are assigned only on success, so an abandoned worker's late
+                # result can never race the rebuild's fresh stack.  Cold
+                # bucket variants get the long compile deadline (the
+                # warm-step rule's analog).
+                self.states, out = guard.dispatch(_device_step, cold=not feed)
         self._warmed_buckets.add((k, variant))
         # per-slot readback plane: slice each rider's row ON DEVICE and
         # start its D2H copy now — a fetch resolves only its own buffer,
@@ -2011,20 +2108,21 @@ class BatchScheduler:
         # frame).  A single-device solo batch skips the slice (its whole
         # output IS the row — _resolve_row squeezes leading singleton
         # axes on the host for free)
-        if self.dp > 1:
-            rows = self._rows_from_sharded(out, positions, k)
-        else:
-            rows = (
-                [out]
-                if len(entries) == 1
-                else [out[i] for i in positions]
-            )
-        for r in rows:
-            try:
-                r.copy_to_host_async()
-            except (AttributeError, RuntimeError):
-                pass
-        return rows, t_disp, occ, feed
+        with hop("readback_start", k=k):
+            if self.dp > 1:
+                rows = self._rows_from_sharded(out, positions, k)
+            else:
+                rows = (
+                    [out]
+                    if len(entries) == 1
+                    else [out[i] for i in positions]
+                )
+            for r in rows:
+                try:
+                    r.copy_to_host_async()
+                except (AttributeError, RuntimeError):
+                    pass
+        return rows, t_disp, feed, launch.seconds
 
     def _layout_pad(self, idx, k: int):
         """Bucket layout: which slot fills each of the k rows, and which
@@ -2187,9 +2285,11 @@ class BatchScheduler:
             )
 
     def _dispatch_entries_locked(
-        self, entries, submitter: "_PendingFrame | None"
+        self, entries, submitter: "_PendingFrame | None", cause: str
     ):
         """Dispatch + hand every rider its per-slot readback.
+        ``cause``: why now, one of DISPATCH_CAUSES (the dispatch span and
+        ``batchsched_dispatch_cause_total`` carry it).
         ``submitter``: the EXACT pending whose submit is running this
         dispatch inline (None = dispatcher thread — every future gets the
         marker; there is no caller to re-raise into).  Identity matters:
@@ -2198,8 +2298,17 @@ class BatchScheduler:
         just submitted — that frame's waiter may already be blocked on
         its future, so only the submitted pending itself may skip the
         future machinery (code-review r1)."""
+        occ = len(entries)
         try:
-            rows, t_disp, occ, feed = self._step_batch_locked(entries)
+            with hop(
+                "dispatch", k=self._bucket_for(occ), riders=occ, cause=cause,
+                frames=",".join(
+                    f"{s}:{p.seq}" for s, plist in entries for p in plist
+                ),
+            ) as dispatch:
+                inflight = self._batches_in_flight(dispatch.t0)
+                starved = self._device_drained_locked()
+                rows, t_disp, feed, launch_s = self._step_batch_locked(entries)
         except Exception as e:
             # a dispatch failing must unblock EVERY rider's future (the
             # other sessions' fetches would otherwise hang out the full
@@ -2218,7 +2327,10 @@ class BatchScheduler:
             if submitter is None:
                 return
             raise
-        batch = _DispatchedBatch(rows, entries, t_disp, occ, feed=feed)
+        batch = _DispatchedBatch(
+            rows, entries, t_disp, occ, feed, cause, inflight, starved,
+            dispatch.seconds, launch_s,
+        )
         self._maybe_bank_rows_locked()
         if any(b.resolved for b in self._batches):
             # drop resolved batches WHEREVER they sit — the ring exists
@@ -2259,6 +2371,7 @@ class BatchScheduler:
         one session's readback never serializes another's.  The first
         resolver (any row) does the per-batch accounting."""
         out = batch.host[row]
+        d2h = 0
         if out is None:
             with batch.rlocks[row]:
                 out = batch.host[row]
@@ -2291,12 +2404,14 @@ class BatchScheduler:
                     # fetches never re-transfer, so this meter is the
                     # fetch-isolation story as a live counter
                     devtel.note_d2h(arr.nbytes)
+                    d2h = arr.nbytes
                     batch.host[row] = arr
                     batch.rows[row] = None  # release the device buffer
                     out = arr
         t1 = time.monotonic()
         first = False
         with self._stats_lock:
+            self._d2h_bytes += d2h  # what devtel was told, kept here too
             if not batch.resolved:
                 batch.resolved = True
                 first = True
@@ -2310,12 +2425,7 @@ class BatchScheduler:
             # the device is the bottleneck (fetch arrives before compute
             # finishes) and near-zero when the box is idle — both correct
             # directions for a capacity signal.
-            self._note_step(
-                min(t1 - batch.t_dispatch, t1 - t0),
-                batch.occupancy,
-                batch.entries,
-                feed=batch.feed,
-            )
+            self._note_step(min(t1 - batch.t_dispatch, t1 - t0), batch)
             if self._throttled:
                 # an in-flight slot just freed and the dispatcher is
                 # parked on the backpressure cap — wake it (a racing
@@ -2355,6 +2465,11 @@ class BatchScheduler:
         dispatches while batch N's readbacks drain on the fetchers."""
         while True:
             with self._has_work:
+                # someone was missing when the dispatcher last looked, so
+                # the dispatch that follows is the window's ("window");
+                # otherwise every live session was ready and only the
+                # in-flight cap held the step back ("backpressure")
+                missed = False
                 while not self._stop:
                     waiting = self._waiting_slots()
                     g = self._guard
@@ -2372,6 +2487,7 @@ class BatchScheduler:
                         self._has_work.wait(timeout=0.1)
                         continue
                     if not waiting:
+                        missed = False
                         self._has_work.wait(timeout=0.5)
                         continue
                     if (
@@ -2383,18 +2499,18 @@ class BatchScheduler:
                         # timeout is a safety net for abandoned batches
                         # (they age out at 60s) and the set/check race
                         self._throttled = True
-                        self._has_work.wait(timeout=0.05)
+                        with hop("window_wait", on="inflight_cap"):
+                            self._has_work.wait(timeout=0.05)
                         self._throttled = False
                         continue
                     live = self.active.count(True)
-                    if (
-                        len(waiting) >= live
-                        or live <= 1
-                        or self.window_s <= 0.0
-                    ):
+                    if len(waiting) >= live or live <= 1:
                         # every live session has work (or there's nobody
                         # to wait for): dispatch NOW — the single-session
                         # fast path never pays the window
+                        break
+                    missed = True
+                    if self.window_s <= 0.0:
                         break
                     oldest = self._oldest_enqueue(waiting)
                     remain = (
@@ -2404,7 +2520,8 @@ class BatchScheduler:
                     )
                     if remain <= 0.0:
                         break  # window expired: go with who showed up
-                    self._has_work.wait(timeout=remain)
+                    with hop("window_wait", on="window"):
+                        self._has_work.wait(timeout=remain)
                 if self._stop:
                     break
                 entries = []
@@ -2413,7 +2530,9 @@ class BatchScheduler:
                     if plist is not None:
                         entries.append((s, plist))
                 if entries:
-                    self._dispatch_entries_locked(entries, None)
+                    self._dispatch_entries_locked(
+                        entries, None, "window" if missed else "backpressure"
+                    )
         # drain on stop
         for q in self._queues:
             while True:
@@ -2422,9 +2541,58 @@ class BatchScheduler:
                     break
                 got[0].future.cancel()
 
-    def _note_step(self, dt_s: float, occupancy: int, entries, feed=True):
+    def _reset_counters_locked(self):
+        """Zero everything snapshot() counts (construction, and the end of
+        rehearse(): set-up's steps are not serving's).  Fixed key sets,
+        so snapshot() copies them without the lock."""
+        self.steps_total = 0
+        self._occ.clear()
+        self._waits.clear()
+        self._occ_hist: dict = {}
+        self._hop_ms = dict.fromkeys(COUNTED_HOPS, 0.0)
+        self._hop_max_ms = dict.fromkeys(COUNTED_HOPS, 0.0)
+        self._hop_n = dict.fromkeys(COUNTED_HOPS, 0)
+        self._cause_n = dict.fromkeys(DISPATCH_CAUSES, 0)
+        # index = batches dispatched and not yet resolved at a dispatch
+        self._inflight_n = [0] * (self._batches.maxlen + 1)
+        self._starved_n = 0
+        self._h2d_bytes = 0
+        self._d2h_bytes = 0
+
+    def _count_hop_locked(self, name: str, seconds: float):
+        ms = 1e3 * seconds
+        self._hop_ms[name] += ms
+        self._hop_n[name] += 1
+        if ms > self._hop_max_ms[name]:
+            self._hop_max_ms[name] = ms
+
+    def _fold_frame(self, p: _PendingFrame, await_s: float, finish_s):
+        """Fold one fetched frame's hop stamps into the counters: the one
+        short critical section a frame that the counters add (the batch's
+        own stamps ride _note_step, which the first resolver already
+        runs)."""
+        with self._stats_lock:
+            for name, seconds in (
+                ("pull_wait", p.pull_wait_s), ("coerce", p.coerce_s),
+                ("stage_h2d", p.stage_s),
+                ("enqueue_lock_wait", p.lock_wait_s),
+                ("await_row", await_s), ("finish_output", finish_s),
+            ):
+                if seconds is not None:
+                    self._count_hop_locked(name, seconds)
+            if p.stage_s is not None:
+                self._h2d_bytes += p.frame.nbytes  # as devtel.note_h2d
+
+    def _note_step(self, dt_s: float, batch: _DispatchedBatch):
+        occupancy, entries, feed = batch.occupancy, batch.entries, batch.feed
         with self._stats_lock:  # dispatcher + inline-fetch callers
             self.steps_total += 1
+            self._count_hop_locked("dispatch", batch.dispatch_s)
+            self._count_hop_locked("launch", batch.launch_s)
+            self._cause_n[batch.cause] += 1
+            self._inflight_n[min(batch.inflight, len(self._inflight_n) - 1)] += 1
+            if batch.starved:
+                self._starved_n += 1
             self._occ.append(occupancy)
             # copy-on-new-key: snapshot() iterates this dict WITHOUT the
             # stats lock (it must never block on a dispatch) — replacing
@@ -2481,6 +2649,24 @@ class BatchScheduler:
             "batchsched_occupancy_hist": {
                 str(k): v for k, v in sorted(self._occ_hist.items())
             },
+            # cumulative since the rehearsal, never reset while serving: a
+            # later snapshot minus an earlier one is that window's.  Host
+            # milliseconds and counts by hop (COUNTED_HOPS), the longest
+            # single one beside them
+            "batchsched_hop_ms_total": {
+                h: round(v, 4) for h, v in self._hop_ms.items()
+            },
+            "batchsched_hop_count": dict(self._hop_n),
+            "batchsched_hop_ms_max": {
+                h: round(v, 4) for h, v in self._hop_max_ms.items()
+            },
+            "batchsched_dispatch_cause_total": dict(self._cause_n),
+            "batchsched_dispatch_inflight_hist": {
+                str(i): n for i, n in enumerate(list(self._inflight_n)) if n
+            },
+            "batchsched_dispatch_starved_total": self._starved_n,
+            "batchsched_h2d_bytes_total": self._h2d_bytes,
+            "batchsched_d2h_bytes_total": self._d2h_bytes,
         }
         if self._adapter_rank:
             # style-adapter plane (adapters/): live sessions riding a

@@ -64,8 +64,7 @@ def build_engine(config: str, fbs: int = 1, unet_cache: int = 0):
     from ai_rtc_agent_tpu.models import registry
     from ai_rtc_agent_tpu.stream.engine import StreamEngine
 
-    # shared with scripts/profile_step.py, whose tiny64 plumbing check runs
-    # on a CPU asked for by name (float32 there); main() below never does
+    # float32 only on a CPU asked for by name; main() below never is
     dtype = "bfloat16" if jax.default_backend() != "cpu" else "float32"
     controlnet = None
     if config == "turbo512":

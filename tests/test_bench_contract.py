@@ -150,78 +150,6 @@ def test_broadcast_bench_contract(tmp_path):
     assert banked == set(by_metric)
 
 
-def test_trace_overhead_bench_contract(tmp_path):
-    """Tracing-overhead microbench smoke (ISSUE 5): runs in seconds on
-    CPU, emits exactly one contract line, BANKS it into PERF_LOG_PATH,
-    and the zero-cost-when-off promise holds as a guarded ratio.  The
-    fence is deliberately loose for contended CI boxes — what it catches
-    is a regression that puts allocation/locking/clock reads back on the
-    trace-off hot path (that is a multi-x blowup, not a few percent)."""
-    log = tmp_path / "PERF_LOG.jsonl"
-    env = dict(os.environ)
-    env.pop("PYTHONPATH", None)
-    env.update(
-        {
-            "PERF_LOG_PATH": str(log),
-            "TRACE_BENCH_FRAMES": "400",
-            "JAX_PLATFORMS": "cpu",
-        }
-    )
-    r = subprocess.run(
-        [sys.executable, "scripts/trace_overhead_bench.py"],
-        env=env, capture_output=True, text=True, timeout=120, cwd=REPO,
-    )
-    assert r.returncode == 0, r.stderr[-800:]
-    lines = [ln for ln in r.stdout.strip().splitlines() if ln.startswith("{")]
-    # three contract lines: the trace/SLO quartet + the devtel leg
-    # (ISSUE 10) + the fleet journey leg (ISSUE 13)
-    assert len(lines) == 3, r.stdout
-    by_metric = {json.loads(ln)["metric"]: json.loads(ln) for ln in lines}
-    d = by_metric["trace_off_overhead_ratio"]
-    for k in ("metric", "value", "unit", "vs_baseline"):
-        assert k in d, d
-    assert "error" not in d, d
-    assert 0 < d["value"] <= 1.5, d  # off-mode must stay within noise
-    # tracing ON costs more than OFF (the bench actually traced), and the
-    # absolute off-mode residue stays in single-digit µs per frame
-    assert d["trace_on_us_per_frame"] >= d["trace_off_us_per_frame"], d
-    assert d["off_overhead_us_per_frame"] < 25.0, d
-    # the SLO plane's off-mode contract (ISSUE 8 acceptance: ≤5% over the
-    # trace-off ratio on an uncontended box; this CI fence is loose the
-    # same way the trace one is — what it catches is allocation/locking
-    # landing back on the SLO_ENABLE=0 hot path, a multi-x blowup)
-    assert 0 < d["slo_off_overhead_ratio"] <= 1.5, d
-    assert d["slo_off_overhead_us_per_frame"] < 25.0, d
-    # slo-on actually aggregated (the bench fed real timelines)
-    assert d["slo_frames_observed"] > 0, d
-    assert d["fingerprint"]["jax_backend"] == "unprobed"
-    # the devtel plane's off-mode contract (ISSUE 10 acceptance: ≤1.05 on
-    # an uncontended box; this CI fence is loose the same way — it
-    # catches allocation/locking landing back on the DEVTEL_ENABLE=0
-    # hook path, a multi-x blowup, not a few percent)
-    dt = by_metric["devtel_off_overhead_ratio"]
-    assert "error" not in dt, dt
-    assert 0 < dt["value"] <= 1.5, dt
-    assert dt["devtel_off_overhead_us_per_frame"] < 25.0, dt
-    # the on-leg actually counted every hook (2 per frame x frames x reps)
-    assert dt["devtel_transfers_counted"] > 0, dt
-    # the fleet journey plane's off-mode contract (ISSUE 13: the
-    # JOURNEY_ENABLE=0 note() residue is one attribute read — same loose
-    # CI fence, same multi-x failure mode it exists to catch)
-    jt = by_metric["journey_off_overhead_ratio"]
-    assert "error" not in jt, jt
-    assert 0 < jt["value"] <= 1.5, jt
-    assert jt["journey_off_overhead_us_per_frame"] < 25.0, jt
-    # the on-leg actually recorded into the bounded ring
-    assert jt["journey_events_counted"] > 0, jt
-    # banked: all THREE entries landed in the log
-    banked = [json.loads(x) for x in log.read_text().splitlines()]
-    assert {b["metric"] for b in banked[-3:]} == {
-        "trace_off_overhead_ratio", "devtel_off_overhead_ratio",
-        "journey_off_overhead_ratio",
-    }
-
-
 def test_unet_cache_prefix_validated():
     """advisor r3: 'foo:3' must not parse as a valid UNET_CACHE spelling."""
     import pytest

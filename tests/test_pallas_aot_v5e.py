@@ -86,3 +86,57 @@ def test_fused_epilogue_compiles_for_v5e(v5e, batch, cfg_type, vmap_k):
     assert mosaic_kernel_counts(compiled.as_text()) == {
         "fused_stream_epilogue": 1
     }
+
+
+def test_scoped_bucket_step_compiles_for_v5e_with_the_same_kernels(monkeypatch):
+    """ISSUE 25: the model's ``jax.named_scope``s are metadata.  The tiny
+    bucket step compiled for the chip holds the same Mosaic kernels with the
+    scopes as without, every kernel's custom call still carries the
+    kernel's name (the trace readers find it by that), and the scopes are
+    in the compiled text, where ``benchmark/scope_reduce.py`` reads them."""
+    import contextlib
+
+    from jax.experimental import topologies
+
+    import ai_rtc_agent_tpu.ops.pallas.attention as A
+    import ai_rtc_agent_tpu.ops.pallas.fused_scheduler as F
+    from ai_rtc_agent_tpu.models import registry
+    from ai_rtc_agent_tpu.stream.scheduler import BatchScheduler
+
+    # the program asks the backend whether to interpret its kernels; this
+    # process sees a CPU, the compile below is for the chip
+    monkeypatch.setattr(A, "interpret_default", lambda: False)
+    monkeypatch.setattr(F, "interpret_default", lambda: False)
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
+    chip = SingleDeviceSharding(topo.devices[0])
+
+    def compiled_text():
+        bundle = registry.load_model_bundle("tiny-test", attn_impl="pallas")
+        cfg = registry.default_stream_config(
+            "tiny-test", attn_impl="pallas", use_fused_epilogue=True,
+            dtype="bfloat16", height=32, width=32,
+        )
+        s = BatchScheduler(
+            bundle.stream_models, registry.cast_params(bundle.params, cfg.dtype),
+            cfg, bundle.encode_prompt, max_sessions=1, prewarm=False,
+        )
+        try:
+            specs = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+                s._bucket_specs(1),
+            )
+            return s._bucket_step(1, "full").lower(*specs).compile().as_text()
+        finally:
+            s.close()
+
+    scoped = compiled_text()
+    kernels = mosaic_kernel_counts(scoped)
+    assert kernels["fused_stream_epilogue"] == 1 and kernels["flash_attention"] >= 4
+    assert "unnamed" not in kernels
+    for scope in ("vmap(unet)/down_0/resnet_0/", "transformer_0/self_attn/",
+                  "vmap(vae_decode)/", "jit(bucket)/gather/"):
+        assert scope in scoped, scope
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    plain = compiled_text()
+    assert mosaic_kernel_counts(plain) == kernels
+    assert "self_attn" not in plain
