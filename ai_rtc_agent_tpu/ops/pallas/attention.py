@@ -2,10 +2,28 @@
 
 Replaces the xformers/TensorRT fused attention of the reference stack
 (reference lib/wrapper.py:710-711 'xformers' acceleration) with a TPU
-blockwise-softmax kernel: Q tiles stream over K/V tiles held in VMEM with
-running max/denominator, so the [Lq, Lk] score matrix never materializes in
-HBM.  Matters at SDXL@1024 (16k latent tokens: dense scores would be
-16k x 16k x heads).
+blockwise-softmax kernel: a Q tile meets K/V, which sit whole in VMEM for
+one head, block by block with a running max/denominator, so the [Lq, Lk]
+score matrix never materializes in HBM.
+
+What the MXU is handed: ``q``, ``k`` and ``v`` in the dtype they arrive in
+(bf16 in every served graph) and ``p`` cast to ``v``'s dtype, both
+contractions accumulating in float32.  The scores, the softmax statistics
+and the output accumulator are float32 (the v5e has no bf16 VPU or EUP), and
+the softmax scale multiplies the float32 scores, not ``q``.  A float32 caller
+gets float32 operands: the dtype decides, nothing else.
+
+What sets the kernel's pace on a v5e (PERF.md section 6, PR 26) is not the
+operands' dtype -- Mosaic serves a default-precision float32 contraction in
+one bf16 pass -- but the K loop: as a rolled ``fori_loop`` of 256-key blocks
+each iteration waits out its own matmul -> max -> exp -> sum -> matmul chain
+(0.69 ms a 4096-token SD2.1 call).  So the loop is unrolled, which lets the
+scheduler overlap one block's softmax with the next block's QK^T, keys that
+fit one block are a single pass with no running rescale, and the blocks come
+from the call's shapes (``_choose_blocks``): 0.24 ms for the same call.
+Measured on the chip at every shape the SD2.1 (head dim 64, B=1) and SD1.5
+(head dims 40/80/160, B=4) graphs reach: Lq 4096/1024/256/64, self and
+77-key cross.
 
 Non-causal (diffusion attention has no mask).  Interpret mode on an
 explicitly requested CPU so the hermetic suite exercises the same code path.
@@ -22,37 +40,88 @@ from jax.experimental import pallas as pl
 
 from . import interpret_default
 
-DEFAULT_BLOCK_Q = 256
-DEFAULT_BLOCK_K = 256
+# one pass over the keys up to here; longer K is met in blocks
+_MAX_BLOCK_K = 1024
+# f32 score-tile elements ([block_q, block_k], lanes padded to 128) a program
+# may hold with 2-byte operands: 4 MB in a single pass, half of that when the
+# unrolled K loop keeps several tiles alive.  Twice these did not fit the
+# v5e's 16 MB of scoped VMEM at head dim 40
+_TILE_SINGLE_PASS = 1 << 20
+_TILE_LOOP = 1 << 19
+# unrolled up to here (4 steps at 4096 keys); a longer loop stays rolled
+_MAX_UNROLL = 8
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _choose_blocks(lq: int, lk: int, head_dim: int, itemsize: int):
+    """(block_q, block_k) from the call's shapes.
+
+    ``block_k``: all of K up to ``_MAX_BLOCK_K`` (the 77-key cross-attention
+    and every tier below 4096 tokens), else the largest power-of-two block
+    that divides ``lk`` (where none does, the caller's ragged-tail fall-back
+    takes the call).
+    ``block_q``: as many queries as keep the float32 score tile and the
+    [block_q, head_dim] tiles inside the budget, which scales with the
+    operand's size.  On the chip (PERF.md section 6, PR 26): 512/1024 at
+    4096 tokens, 1024/1024 at 1024, the whole of Lq against 77 keys."""
+    if lk <= _MAX_BLOCK_K:
+        block_k, tile = lk, _TILE_SINGLE_PASS
+    else:
+        block_k = next((b for b in (1024, 512, 256, 128) if lk % b == 0), 128)
+        tile = _TILE_LOOP
+    tile = tile * 2 // itemsize
+    widest = max(_round_up(block_k, 128), _round_up(head_dim, 128))
+    return min(lq, tile // widest), block_k
 
 
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, scale: float):
-    """One (batch*head, q-block) program: stream K/V blocks."""
-    q = q_ref[...].astype(jnp.float32) * scale  # [bq, d]
-    lk = k_ref.shape[0]
-    bq, d = q.shape
+    """One (batch*head, q-block) program over that head's K/V."""
+    q = q_ref[...]  # [bq, d], the input's dtype
+    steps = k_ref.shape[0] // block_k
+
+    def scores(k):  # -> [bq, bk] f32
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        return s * scale
+
+    def weigh(p, v):  # -> [bq, d] f32
+        return jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    if steps == 1:  # every key in one block: a plain softmax, no rescale
+        s = scores(k_ref[...])
+        p = jnp.exp(s - s.max(axis=-1, keepdims=True))
+        o = weigh(p, v_ref[...]) / p.sum(axis=-1, keepdims=True)
+        o_ref[...] = o.astype(o_ref.dtype)
+        return
 
     def body(i, carry):
         o, m, l = carry
-        k = k_ref[pl.ds(i * block_k, block_k), :].astype(jnp.float32)  # [bk, d]
-        v = v_ref[pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [bq, bk]
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        rows = pl.ds(pl.multiple_of(i * block_k, block_k), block_k)
+        s = scores(k_ref[rows, :])
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
-        l_new = l * corr + p.sum(axis=-1)
-        o_new = o * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        return o_new, m_new, l_new
+        l_new = l * corr + p.sum(axis=-1, keepdims=True)
+        return o * corr + weigh(p, v_ref[rows, :]), m_new, l_new
 
-    o0 = jnp.zeros((bq, d), jnp.float32)
-    m0 = jnp.full((bq,), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((bq,), jnp.float32)
-    o, m, l = jax.lax.fori_loop(0, lk // block_k, body, (o0, m0, l0))
-    o_ref[...] = (o / l[:, None]).astype(o_ref.dtype)
+    bq, d = q.shape
+    o, _, l = jax.lax.fori_loop(
+        0, steps, body,
+        (
+            jnp.zeros((bq, d), jnp.float32),
+            jnp.full((bq, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((bq, 1), jnp.float32),
+        ),
+        unroll=steps <= _MAX_UNROLL,
+    )
+    o_ref[...] = (o / l).astype(o_ref.dtype)
 
 
 def flash_attention(
@@ -60,13 +129,17 @@ def flash_attention(
     k,
     v,
     mask=None,
-    block_q: int = DEFAULT_BLOCK_Q,
-    block_k: int = DEFAULT_BLOCK_K,
+    block_q: int | None = None,
+    block_k: int | None = None,
     interpret: bool | None = None,
 ):
     """q: [B, Lq, H, D], k/v: [B, Lk, H, D] -> [B, Lq, H, D].
 
-    ``mask`` unsupported (diffusion attention is unmasked); raises if given.
+    ``block_q`` / ``block_k`` default to what ``_choose_blocks`` makes of the
+    shapes.  Lq is padded up to a multiple of ``block_q``; an Lk that
+    ``block_k`` does not divide goes to plain XLA attention (no served graph
+    reaches it).  ``mask`` unsupported (diffusion attention is unmasked);
+    raises if given.
     """
     if mask is not None:
         raise NotImplementedError("flash_attention is non-causal/unmasked")
@@ -74,34 +147,22 @@ def flash_attention(
         interpret = interpret_default()
     b, lq, h, d = q.shape
     lk = k.shape[1]
-    block_q = min(block_q, lq)
-    block_k = min(block_k, lk)
-
-    # pad sequence lengths to block multiples; padded K rows get -inf scores
-    # naturally excluded because we pad K with zeros AND track true lk via
-    # masking — simpler: require divisibility, pad otherwise
-    pad_q = (-lq) % block_q
-    pad_k = (-lk) % block_k
-    if pad_k:
-        # zero-pad K/V and rely on exp(s - m) weighting: zero K rows give
-        # s=0 which is WRONG, so mask by appending -inf scores via a pad of
-        # K that we explicitly exclude: simplest correct route is to fall
-        # back to XLA attention for ragged tails.
+    auto_q, auto_k = _choose_blocks(lq, lk, d, q.dtype.itemsize)
+    block_q = min(block_q or auto_q, lq)
+    block_k = min(block_k or auto_k, lk)
+    if lk % block_k:
         return _xla_attention(q, k, v)
-    if pad_q:
-        q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
-        lq_p = lq + pad_q
-    else:
-        lq_p = lq
+    lq_p = _round_up(lq, block_q)
+    if lq_p != lq:
+        q = jnp.pad(q, ((0, 0), (0, lq_p - lq), (0, 0), (0, 0)))
 
-    scale = 1.0 / math.sqrt(d)
     # layout: fold batch*heads into grid dim 0; tiles [block, d]
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, lq_p, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, lk, d)
     vt = v.transpose(0, 2, 1, 3).reshape(b * h, lk, d)
 
     out = pl.pallas_call(
-        partial(_attn_kernel, block_k=block_k, scale=scale),
+        partial(_attn_kernel, block_k=block_k, scale=1.0 / math.sqrt(d)),
         grid=(b * h, lq_p // block_q),
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda g, i: (g, i, 0)),

@@ -8,11 +8,12 @@ never interpret mode: on a TPU the kernel goes through Mosaic and the
 reference through XLA at ``highest`` matmul precision, so the two share no
 code below the jnp call.
 
-Shapes ([B, L, heads, head_dim]; cross-attention has 77 keys, and
-``block_k = min(256, 77)`` leaves no ragged tail, so it runs in the kernel):
+Shapes ([B, L, heads, head_dim]; cross-attention has 77 keys, which the
+kernel takes whole as one block, so it runs in the kernel):
 
 * SD2.1 (sd-turbo, 512x512): L 4096/1024/256/64, heads 5/10/20/20, d 64
-* SD1.5 4-stage stream batch (B=4): heads 8, d 40/80/160
+* SD1.5 4-stage stream batch (B=4): L 4096/1024/256/64, heads 8,
+  d 40/80/160/160, self and cross at every tier
 * SDXL (1024x1024): 4096 x 10 heads, 1024 x 20 heads, 77 keys of context
 * one self-attention shape under ``vmap`` k=2 (the scheduler's bucket step)
 * the fused epilogue at B=1 ``none`` (64x64 and 128x128 latents), at B=4
@@ -53,7 +54,11 @@ ATTN_CASES = {
     "sd15_b4_self_4096x8x40": ((4, 4096, 8, 40), (4, 4096, 8, 40), 0),
     "sd15_b4_self_1024x8x80": ((4, 1024, 8, 80), (4, 1024, 8, 80), 0),
     "sd15_b4_self_256x8x160": ((4, 256, 8, 160), (4, 256, 8, 160), 0),
+    "sd15_b4_self_64x8x160": ((4, 64, 8, 160), (4, 64, 8, 160), 0),
     "sd15_b4_cross_4096x8x40_k77": ((4, 4096, 8, 40), (4, 77, 8, 40), 0),
+    "sd15_b4_cross_1024x8x80_k77": ((4, 1024, 8, 80), (4, 77, 8, 80), 0),
+    "sd15_b4_cross_256x8x160_k77": ((4, 256, 8, 160), (4, 77, 8, 160), 0),
+    "sd15_b4_cross_64x8x160_k77": ((4, 64, 8, 160), (4, 77, 8, 160), 0),
     "sdxl_self_4096x10x64": ((1, 4096, 10, 64), (1, 4096, 10, 64), 0),
     "sdxl_self_1024x20x64": ((1, 1024, 20, 64), (1, 1024, 20, 64), 0),
     "sdxl_cross_1024x20x64_k77": ((1, 1024, 20, 64), (1, 77, 20, 64), 0),
@@ -63,6 +68,9 @@ ATTN_CASES_TINY = {
     "tiny_self_64x2x16": ((1, 64, 2, 16), (1, 64, 2, 16), 0),
     "tiny_cross_64x2x16_k7": ((1, 64, 2, 16), (1, 7, 2, 16), 0),
     "tiny_b4_self_64x2x40": ((4, 64, 2, 40), (4, 64, 2, 40), 0),
+    "tiny_b4_self_16x2x160": ((4, 16, 2, 160), (4, 16, 2, 160), 0),
+    "tiny_b4_cross_64x2x80_k7": ((4, 64, 2, 80), (4, 7, 2, 80), 0),
+    "tiny_b4_cross_16x2x160_k7": ((4, 16, 2, 160), (4, 7, 2, 160), 0),
     "tiny_self_64x2x16_vmap2": ((1, 64, 2, 16), (1, 64, 2, 16), 2),
 }
 # name -> (B, latent h=w, cfg_type, vmap k)

@@ -33,20 +33,29 @@ def v5e():
     )
 
 
-@pytest.mark.parametrize(
-    "q_shape,kv_shape,vmap_k",
-    [
-        ((1, 4096, 5, 64), (1, 4096, 5, 64), 0),  # SD2.1 top self-attention
-        ((4, 4096, 8, 40), (4, 4096, 8, 40), 0),  # SD1.5 stream batch, d=40
-        ((1, 4096, 5, 64), (1, 77, 5, 64), 0),  # cross-attention, block_k=77
-        ((1, 4096, 5, 64), (1, 4096, 5, 64), 2),  # the scheduler's k=2 bucket
-    ],
-)
+def _served_attention_shapes():
+    """Every distinct (B, lq, lk, heads, head_dim) the two benchmark
+    configurations reach: four tiers x self / 77-key cross x SD2.1 (sd-turbo:
+    B=1, head dim 64) / SD1.5 (4-stage stream batch: B=4, 8 heads), each
+    under ``vmap`` k=1 as the bucket step runs it, and one at k=2."""
+    tiers = [
+        # (B, heads, head_dim) per tier, tokens 4096 / 1024 / 256 / 64
+        ((1, 5, 64), (1, 10, 64), (1, 20, 64), (1, 20, 64)),
+        ((4, 8, 40), (4, 8, 80), (4, 8, 160), (4, 8, 160)),
+    ]
+    cases = []
+    for config in tiers:
+        for tokens, (b, h, d) in zip((4096, 1024, 256, 64), config):
+            for lk in (tokens, 77):
+                cases.append(((b, tokens, h, d), (b, lk, h, d), 1))
+    cases.append(((1, 4096, 5, 64), (1, 4096, 5, 64), 2))
+    return cases
+
+
+@pytest.mark.parametrize("q_shape,kv_shape,vmap_k", _served_attention_shapes())
 def test_flash_attention_compiles_for_v5e(v5e, q_shape, kv_shape, vmap_k):
-    fn = lambda q, k, v: flash_attention(q, k, v, interpret=False)  # noqa: E731
-    if vmap_k:
-        fn = jax.vmap(fn)
-        q_shape, kv_shape = (vmap_k,) + q_shape, (vmap_k,) + kv_shape
+    fn = jax.vmap(lambda q, k, v: flash_attention(q, k, v, interpret=False))
+    q_shape, kv_shape = (vmap_k,) + q_shape, (vmap_k,) + kv_shape
     compiled = jax.jit(fn).lower(
         v5e(q_shape, jnp.bfloat16),
         v5e(kv_shape, jnp.bfloat16),

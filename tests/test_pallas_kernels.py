@@ -60,6 +60,68 @@ def test_flash_attention_matches_dense(rng):
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
 
+# scripts/tpu_numerics_check.py's tolerance for bf16 in and out on the chip
+ATTN_ATOL = 2e-2
+
+
+@pytest.mark.parametrize("blocks", [None, (32, 32)], ids=["auto", "32x32"])
+@pytest.mark.parametrize("batch", [1, 4])
+@pytest.mark.parametrize("lk", [64, 7], ids=["self", "cross7"])
+@pytest.mark.parametrize("head_dim", [16, 40, 64, 80, 160])
+def test_flash_attention_bf16_matches_f32_dense(head_dim, lk, batch, blocks):
+    """bf16 in and out, as the served graphs call it: operands reach the
+    contractions in bf16, statistics and accumulator in f32.  Against the
+    dense reference on the same values in f32, at the chip script's
+    tolerance, with the blocks the shapes choose and with an explicit pair
+    (which loops over two key blocks of 32 in self-attention)."""
+    keys = jax.random.split(jax.random.PRNGKey(head_dim + lk + batch), 3)
+    q = jax.random.normal(keys[0], (batch, 64, 2, head_dim), jnp.bfloat16)
+    k = jax.random.normal(keys[1], (batch, lk, 2, head_dim), jnp.bfloat16)
+    v = jax.random.normal(keys[2], (batch, lk, 2, head_dim), jnp.bfloat16)
+    kw = {} if blocks is None else {"block_q": blocks[0], "block_k": blocks[1]}
+    got = PA.flash_attention(q, k, v, interpret=True, **kw)
+    assert got.dtype == jnp.bfloat16 and got.shape == q.shape
+    want = PA._xla_attention(*(a.astype(jnp.float32) for a in (q, k, v)))
+    diff = np.max(np.abs(np.asarray(got.astype(jnp.float32)) - np.asarray(want)))
+    assert diff < ATTN_ATOL, diff
+
+
+@pytest.mark.parametrize("lk", [4096, 77], ids=["loop", "single_pass"])
+def test_flash_attention_f32_caller_keeps_f32(rng, lk):
+    """The dtype decides, nothing else: float32 inputs meet the dense
+    reference at float32 tolerance through the shape-chosen blocks, in the
+    unrolled K loop (4096 keys in four blocks) and in the single pass."""
+    q = jnp.asarray(rng.standard_normal((1, 256, 1, 16)).astype(np.float32))
+    k = jnp.asarray(rng.standard_normal((1, lk, 1, 16)).astype(np.float32))
+    v = jnp.asarray(rng.standard_normal((1, lk, 1, 16)).astype(np.float32))
+    got = PA.flash_attention(q, k, v, interpret=True)
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(PA._xla_attention(q, k, v)), rtol=2e-4, atol=2e-5
+    )
+
+
+@pytest.mark.parametrize(
+    "lq,lk,head_dim,itemsize,want",
+    [
+        # what the chip sweep of PR 26 picked, per served shape
+        (4096, 4096, 64, 2, (512, 1024)),
+        (4096, 4096, 40, 2, (512, 1024)),
+        (1024, 1024, 80, 2, (1024, 1024)),
+        (256, 256, 160, 2, (256, 256)),
+        (64, 64, 160, 2, (64, 64)),
+        (4096, 77, 64, 2, (4096, 77)),
+        (64, 77, 160, 2, (64, 77)),
+        # float32 operands: half the queries a tile
+        (4096, 4096, 64, 4, (256, 1024)),
+        # 3 x 512 keys: the largest power of two that divides them
+        (4096, 1536, 64, 2, (1024, 512)),
+    ],
+)
+def test_choose_blocks(lq, lk, head_dim, itemsize, want):
+    assert PA._choose_blocks(lq, lk, head_dim, itemsize) == want
+
+
 def test_flash_attention_ragged_falls_back(rng):
     B, Lq, Lk, H, D = 1, 10, 7, 2, 8  # not divisible by blocks
     q = jnp.asarray(rng.standard_normal((B, Lq, H, D)).astype(np.float32))
