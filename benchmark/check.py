@@ -15,7 +15,8 @@ before them, after which its state is the followed one's to a thousandth
 
 Runs once the window has closed, the peak memory has been read and the
 program is freed.  The reference makes its own weights from the seed
-(``weights.py``) and takes nothing from the program.
+(``weights.py``), holds them once (``reference_for``) and takes nothing from
+the program.
 
 The number compared, ``session_bias_rel_max``: per session, the signed
 error (served minus reference, uint8 levels, the reference unrounded) is
@@ -34,7 +35,6 @@ numbers in ``readings`` are printed for the record and judged by nothing.
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -43,16 +43,17 @@ from .weights import make_weights
 
 
 def reference_for(cfg: dict, seed: int, ref_module):
-    """The configuration's reference (``harness.Benchmark.reference``) on
-    float32 copies of the weights the seed gives."""
+    """The configuration's reference (``harness.Benchmark.reference``) on the
+    weights the seed gives, held once and in the dtype they are served in:
+    the reference widens each leaf to float32 where it reads it
+    (``reference/nn.py``), which is exact, so it computes on the numbers a
+    float32 copy of the tree would hold without the copy's 4 bytes a
+    parameter beside the tree's 2."""
     s = cfg["stream"]
-    served = make_weights(
+    return ref_module.Reference(cfg, make_weights(
         ref_module.weight_shapes(cfg), seed, jnp.dtype(s["dtype"]),
         cfg.get("weights", {}).get("rules", ()),
-    )
-    return ref_module.Reference(
-        cfg, jax.tree.map(lambda a: a.astype(jnp.float32), served)
-    )
+    ))
 
 
 POOL = 8  # pixels a side: one latent cell

@@ -14,6 +14,14 @@ files and entries and edits nothing here.
                  ``flops``) with ``frame_flops(cfg)`` and what a kernel's
                  roofline reader asks of it
 
+A second model family came in that way (``tests/data/configs/tinyxl64.json``
+with ``reference/sdxl_stream.py`` and ``flops/sdxl_stream.py``: two text
+towers, an addition embedding).  What a configuration file may state beside
+its sizes, each with a default that the first family's files rely on:
+``program_text_subtrees`` (the top-level subtrees of the weight tree that the
+program's ``encode_prompt`` reads: ``["clip"]``), ``program_stream_overrides``
+(none) and ``weights.rules`` (none).
+
 A name the disk lacks is an error that says which.
 """
 

@@ -76,12 +76,31 @@ def build_scheduler(cfg: dict, ours, seed: int, slots: int, quant: str | None = 
     jax.block_until_ready(served)
     stage("weights from the seed")
     # encode_prompt closes over the dict load_model_bundle filled; in the
-    # agent that dict keeps the float32 text tower while the step gets the
-    # cast tree.  Same here: float32 copies of the served values.
+    # agent that dict keeps the float32 text towers while the step gets the
+    # cast tree.  Same here: float32 copies of the served values, of every
+    # subtree the configuration file says the text side reads.
+    texts = cfg.get("program_text_subtrees", ["clip"])
+    absent = [t for t in texts if t not in served]
+    if absent:
+        raise WrongGraph(
+            f"{cfg['name']}: program_text_subtrees names {absent}, the weight "
+            f"tree has {sorted(served)}"
+        )
     bundle.params.clear()
-    bundle.params["clip"] = jax.tree.map(
-        lambda a: a.astype(jnp.float32), served["clip"]
-    )
+    for t in texts:
+        bundle.params[t] = jax.tree.map(lambda a: a.astype(jnp.float32), served[t])
+
+    def encode_prompt(prompt: str):
+        # the scheduler encodes its first prompt while it is built, so a
+        # subtree the file does not list is named here, in set-up
+        try:
+            return bundle.encode_prompt(prompt)
+        except KeyError as e:
+            raise WrongGraph(
+                f"{cfg['name']}: the program's encode_prompt reads the subtree "
+                f"{e.args[0]!r}, which the configuration file's "
+                f"program_text_subtrees ({texts}) does not list"
+            ) from None
 
     overrides = {
         k: tuple(v) if isinstance(v, list) else v
@@ -115,7 +134,7 @@ def build_scheduler(cfg: dict, ours, seed: int, slots: int, quant: str | None = 
         os.environ.pop("QUANT_MIN_SIZE", None)
     stage("cast_params")
     sched = BatchScheduler(
-        bundle.stream_models, params, stream_cfg, bundle.encode_prompt,
+        bundle.stream_models, params, stream_cfg, encode_prompt,
         max_sessions=slots, guidance_scale=s["guidance_scale"], delta=s["delta"],
         prewarm=True, dp=1,
     )

@@ -1,8 +1,9 @@
 """The three networks of a Stable-Diffusion stream step, as plain float32
 forward passes driven by the sizes in a configuration file
 (``benchmark/configs/<name>.json``): the conditional UNet
-(diffusers ``UNet2DConditionModel``: SD1.5 / SD2.1 geometry), the CLIP text
-tower (``CLIPTextModel``) and the tiny autoencoder TAESD
+(diffusers ``UNet2DConditionModel``: SD1.5 / SD2.1 / SDXL geometry), the CLIP
+text tower (``CLIPTextModel``, ``CLIPTextModelWithProjection``) and the tiny
+autoencoder TAESD
 (``AutoencoderTiny``).  Published descriptions followed; departures the
 program makes and the reference therefore shares are listed in the
 configuration file under ``assumed``.
@@ -57,13 +58,17 @@ def _transformer(p, x, ctx, u, heads):
     return z + x
 
 
-def unet(p, x, timesteps, ctx, u: dict):
-    """x [B,h,w,4], timesteps [B], ctx [B,L,cross] -> eps [B,h,w,4]."""
+def unet(p, x, timesteps, ctx, u: dict, added=None):
+    """x [B,h,w,4], timesteps [B], ctx [B,L,cross] -> eps [B,h,w,4].
+    ``added`` [B,temb]: an addition embedding (``addition_embed_type``),
+    summed onto the time embedding before the first block reads it."""
     groups = u["norm_num_groups"]
     heads = _heads(u)
     te = p["time_embedding"]
     temb = nn.sinusoid(timesteps, u["block_out_channels"][0])
     temb = nn.dense(te["linear_2"], nn.silu(nn.dense(te["linear_1"], temb)))
+    if added is not None:
+        temb = temb + added
 
     h = nn.conv(p["conv_in"], x)
     skips = [h]
@@ -97,14 +102,13 @@ def unet(p, x, timesteps, ctx, u: dict):
 
 # -- CLIP text tower --------------------------------------------------------
 
-def clip_text(p, token_ids, t: dict):
-    """token_ids [B,L] -> hidden states [B,L,width] fed to cross attention:
-    the last layer's, final-normed, when ``clip_skip`` is 0; the raw output
-    of layer ``-1-clip_skip`` otherwise."""
+def clip_layers(p, token_ids, t: dict) -> list:
+    """token_ids [B,L] -> the hidden states [B,L,width] after the embeddings
+    and after each layer, none of them final-normed."""
     act = {"quick_gelu": nn.quick_gelu, "gelu": nn.gelu}[t["hidden_act"]]
     heads = t["num_attention_heads"]
     b, l = token_ids.shape
-    x = p["token_embedding"][token_ids] + p["position_embedding"][:l]
+    x = nn.f32(p["token_embedding"][token_ids]) + nn.f32(p["position_embedding"][:l])
     causal = jnp.where(jnp.tril(jnp.ones((l, l), bool)), 0.0, -1e9)[None, None]
     hiddens = [x]
     for layer in p["layers"]:
@@ -118,9 +122,33 @@ def clip_text(p, token_ids, t: dict):
         y = nn.layer_norm(layer["ln2"], x)
         x = x + nn.dense(layer["fc2"], act(nn.dense(layer["fc1"], y)))
         hiddens.append(x)
+    return hiddens
+
+
+def _clip_hidden(p, hiddens: list, t: dict):
     if t["clip_skip"] == 0:
-        return nn.layer_norm(p["final_norm"], x)
+        return nn.layer_norm(p["final_norm"], hiddens[-1])
     return hiddens[-1 - t["clip_skip"]]
+
+
+def clip_text(p, token_ids, t: dict):
+    """token_ids [B,L] -> hidden states [B,L,width] fed to cross attention:
+    the last layer's, final-normed, when ``clip_skip`` is 0; the raw output
+    of layer ``-1-clip_skip`` otherwise."""
+    return _clip_hidden(p, clip_layers(p, token_ids, t), t)
+
+
+def clip_text_projected(p, token_ids, t: dict):
+    """``CLIPTextModelWithProjection``: -> (hidden states as ``clip_text``
+    gives them, the text embedding [B,projection_dim]).  The embedding is the
+    last layer's final-normed state at the end-of-text token (the highest id
+    of the CLIP vocabulary, so the first argmax of a row), through the
+    bias-free ``text_projection``."""
+    hiddens = clip_layers(p, token_ids, t)
+    final = nn.layer_norm(p["final_norm"], hiddens[-1])
+    eot = jnp.argmax(token_ids, axis=-1)
+    pooled = final[jnp.arange(final.shape[0]), eot]
+    return _clip_hidden(p, hiddens, t), nn.dense(p["text_projection"], pooled)
 
 
 # -- TAESD ------------------------------------------------------------------

@@ -2,11 +2,14 @@
 
 Straightforward ``jax.numpy``, every contraction at ``highest`` precision
 (on a TPU a float32 matmul otherwise runs in bfloat16 passes).  No kernels,
-no batching tricks, no state.  Imports nothing of the program: the only
-thing shared with it is the layout of the weight tree (nested dicts of
-``kernel`` [in..., out] / ``bias`` / ``scale`` leaves, NHWC activations,
-HWIO convolution kernels), which ``benchmark/weights.py`` fills from the
-seed for both sides.
+no batching tricks, no state.  The weight tree is held in the dtype it was
+served in and each leaf is widened to float32 where it is used (``f32``):
+bfloat16 -> float32 is exact, so every operand is the number a float32 copy
+of the tree would hold, and the tree is held once, at 2 bytes a parameter.
+Imports nothing of the program: the only thing shared with it is the layout
+of the weight tree (nested dicts of ``kernel`` [in..., out] / ``bias`` /
+``scale`` leaves, NHWC activations, HWIO convolution kernels), which
+``benchmark/weights.py`` fills from the seed for both sides.
 """
 
 from __future__ import annotations
@@ -19,9 +22,14 @@ import jax.numpy as jnp
 HI = jax.lax.Precision.HIGHEST
 
 
+def f32(leaf):
+    """A weight leaf as float32, at the place that reads it."""
+    return leaf.astype(jnp.float32)
+
+
 def dense(p, x):
-    y = jnp.matmul(x, p["kernel"], precision=HI)
-    return y + p["bias"] if "bias" in p else y
+    y = jnp.matmul(x, f32(p["kernel"]), precision=HI)
+    return y + f32(p["bias"]) if "bias" in p else y
 
 
 def conv(p, x, stride: int = 1):
@@ -29,10 +37,10 @@ def conv(p, x, stride: int = 1):
     k = p["kernel"].shape[0]
     pad = k // 2
     y = jax.lax.conv_general_dilated(
-        x, p["kernel"], (stride, stride), ((pad, pad), (pad, pad)),
+        x, f32(p["kernel"]), (stride, stride), ((pad, pad), (pad, pad)),
         dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=HI,
     )
-    return y + p["bias"] if "bias" in p else y
+    return y + f32(p["bias"]) if "bias" in p else y
 
 
 def group_norm(p, x, groups: int, eps: float = 1e-5):
@@ -41,13 +49,13 @@ def group_norm(p, x, groups: int, eps: float = 1e-5):
     mean = g.mean(axis=(1, 3), keepdims=True)
     var = ((g - mean) ** 2).mean(axis=(1, 3), keepdims=True)
     g = (g - mean) / jnp.sqrt(var + eps)
-    return g.reshape(n, h, w, c) * p["scale"] + p["bias"]
+    return g.reshape(n, h, w, c) * f32(p["scale"]) + f32(p["bias"])
 
 
 def layer_norm(p, x, eps: float = 1e-5):
     mean = x.mean(-1, keepdims=True)
     var = ((x - mean) ** 2).mean(-1, keepdims=True)
-    return (x - mean) / jnp.sqrt(var + eps) * p["scale"] + p["bias"]
+    return (x - mean) / jnp.sqrt(var + eps) * f32(p["scale"]) + f32(p["bias"])
 
 
 def silu(x):

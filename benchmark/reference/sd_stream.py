@@ -77,9 +77,10 @@ def _col(v):
 
 
 class Reference:
-    """The reference for one configuration: float32 weights (the tree
-    ``{"unet","clip","taesd"}``) and one jitted step shared by its
-    sessions.  ``cfg``: the parsed configuration file."""
+    """The reference for one configuration: the weight tree
+    ``{"unet","clip","taesd"}`` in the dtype it is served in (each leaf is
+    widened to float32 where it is read, ``nn.f32``) and one jitted step
+    shared by its sessions.  ``cfg``: the parsed configuration file."""
 
     def __init__(self, cfg: dict, weights: dict):
         self.cfg, self.s, self.w = cfg, cfg["stream"], weights
@@ -115,6 +116,9 @@ class Reference:
         return Session(self, prompt, seed)
 
     def encode_prompt(self, prompt: str):
+        """What a session's prompt conditions its steps on: here the text
+        tower's hidden states [1,L,width]; a family's own, whatever its
+        ``_conditioning`` reads."""
         t = self.cfg["text_encoder"]
         ids = hash_tokens(prompt, t["vocab_size"], t["max_position_embeddings"])
         with jax.default_matmul_precision("highest"):
@@ -122,17 +126,30 @@ class Reference:
                 self.w["clip"], jnp.asarray([ids], jnp.int32), t
             )
 
+    def _conditioning(self, w, cond):
+        """-> (cross-attention context [1,L,cross], addition embedding
+        [1,temb] or None) of a session, from what ``encode_prompt`` gave."""
+        return cond, None
+
+    def _eps(self, w, x_t, cond):
+        """The UNet's noise prediction for the stream batch ``x_t`` [B,h,w,4],
+        every row under the session's one prompt."""
+        def rows(a):
+            return jnp.broadcast_to(a, x_t.shape[:1] + a.shape[1:])
+
+        ctx, added = self._conditioning(w, cond)
+        return models.unet(
+            w["unet"], x_t, jnp.asarray(self.k["t"], jnp.int32), rows(ctx),
+            self.cfg["unet"], added=None if added is None else rows(added),
+        )
+
     def _step_fn(self, w, cond, noise, ring, stock, frame_u8):
         s, k = self.s, self.k
-        B = noise.shape[0]
         img = frame_u8.astype(jnp.float32)[None] / 255.0
         z0 = models.taesd_encode(w["taesd"]["encoder"], img)
         x_new = k["alpha"][0] * z0 + k["sigma"][0] * noise[:1]
         x_t = jnp.concatenate([x_new, ring], axis=0)
-        ctx = jnp.broadcast_to(cond, (B,) + cond.shape[1:])
-        eps_c = models.unet(
-            w["unet"], x_t, jnp.asarray(k["t"], jnp.int32), ctx, self.cfg["unet"]
-        )
+        eps_c = self._eps(w, x_t, cond)
         if s["cfg_type"] == "none":
             eps, new_stock = eps_c, stock
         elif s["cfg_type"] == "self":

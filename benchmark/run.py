@@ -180,7 +180,14 @@ def main(argv=None) -> int:
         cfg, check.reference_for(cfg, args.seed, ref_module), result,
         frame_shift=1 if args.control == "wrong_frame" else 0,
     )
-    logger.info("output check took %.1f s", time.monotonic() - t0)
+    # the process's peak never falls: this is the check's own peak where the
+    # check went above the window's, and the window's again where it did not
+    device["check_memory_peak_bytes"] = serve.memory_peak()
+    logger.info(
+        "output check took %.1f s; peak memory after it %.3f GB (the window's %.3f)",
+        time.monotonic() - t0, device["check_memory_peak_bytes"] / 1e9,
+        device["memory_peak_bytes"] / 1e9,
+    )
     correct, compared = check.judge(verdict["numbers"], limits)
     out["correct"] = correct
     out["readings"] = verdict["numbers"]

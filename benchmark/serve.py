@@ -216,7 +216,8 @@ class WindowResult:
         return {int(k): v - before.get(k, 0) for k, v in after.items()}
 
 
-def _memory_peak() -> int:
+def memory_peak() -> int:
+    """The process's peak on its fullest chip so far: it never falls."""
     peak = 0
     for d in jax.local_devices():
         stats = d.memory_stats() or {}
@@ -328,7 +329,7 @@ async def _drive(sched, stream_cfg, traffic: dict, seed: int, seconds: float,
         await asyncio.gather(*tasks)
         counters_close = sched.snapshot()
         compiles = sum(1 for t in _compile_times[compiles_before:] if t < t_close)
-        peak = _memory_peak()
+        peak = memory_peak()
 
         # frames in flight at the close: wait for each of them
         depth = traffic["pipeline_depth"]

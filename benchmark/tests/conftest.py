@@ -18,22 +18,24 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 STUB_DEVICE = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+TINY_CONFIGS = ("tiny64", "tinyturbo64", "tinyxl64")
 
 
 def tiny_spec() -> dict:
-    """The real BENCHMARK.json with the tiny configurations and the test
-    traffic in place of the cells (same metrics, same keys)."""
+    """The real BENCHMARK.json with the tiny configurations (two of the
+    one-tower family, one of the two-tower family) and the test traffic in
+    place of the cells (same metrics, same keys)."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         spec = json.load(f)
     spec["configs"] = [
         {"name": n, "source": "tests", "file": f"benchmark/configs/{n}.json",
          "reduced": [], "why": "tiny test family"}
-        for n in ("tiny64", "tinyturbo64")
+        for n in TINY_CONFIGS
     ]
     spec["workloads"] = [
         {"name": f"{n}.duo20", "config": n, "traffic": "duo20", "chips": 1,
          "why": "two sessions, CPU test"}
-        for n in ("tiny64", "tinyturbo64")
+        for n in TINY_CONFIGS
     ]
     return spec
 

@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 
 
-@pytest.mark.parametrize("workload", ["tiny64.duo20", "tinyturbo64.duo20"])
+@pytest.mark.parametrize("workload", ["tiny64.duo20", "tinyturbo64.duo20", "tinyxl64.duo20"])
 def test_the_control_in_lower_precision_comes_out_not_correct(run_cell, workload):
     code, line, err = run_cell(workload, seed=41, control="w8")
     assert code == 0, err

@@ -2,12 +2,13 @@
 
 import json
 import os
+import shutil
 import subprocess
 import sys
 
 import pytest
 
-from .conftest import REPO, make_root, tiny_spec
+from .conftest import HERE, REPO, make_root, tiny_spec
 
 CONTRACT_KEYS = ["correct", "attempted", "failed", "metrics", "device"]
 
@@ -25,7 +26,7 @@ def test_without_a_chip_it_refuses_to_report():
     assert "TPU" in p.stderr
 
 
-@pytest.mark.parametrize("workload", ["tiny64.duo20", "tinyturbo64.duo20"])
+@pytest.mark.parametrize("workload", ["tiny64.duo20", "tinyturbo64.duo20", "tinyxl64.duo20"])
 def test_with_a_device_stub_one_well_formed_last_line(run_cell, workload):
     code, line, err = run_cell(workload, seed=2**31 + 5)
     assert code == 0, err
@@ -38,7 +39,8 @@ def test_with_a_device_stub_one_well_formed_last_line(run_cell, workload):
     for m in spec["end_to_end"]:
         got = line["metrics"][m["name"]]
         assert got["unit"] == m["unit"] and got["value"] > 0
-    assert set(line["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
+    assert set(line["device"]) == {
+        "platform", "kind", "count", "memory_peak_bytes", "check_memory_peak_bytes"}
     # each number compared stands beside its limit, in the line and as the
     # last lines of standard error
     assert set(line["compared"]) == {"session_bias_rel_max", "frame_pooled_rms_max"}
@@ -86,36 +88,47 @@ def test_a_named_piece_the_disk_lacks_is_an_error_that_names_it(tmp_path, run_ce
 
 
 def test_new_pieces_are_added_by_files_and_entries_alone(tmp_path):
-    """A later PR's configuration, traffic mix and per-layer metric: new
-    files and new BENCHMARK.json entries, no edit to a file that is there."""
-    from benchmark.harness import Benchmark
+    """A later PR's model family, traffic mix and per-layer metric: new files
+    and new BENCHMARK.json entries, no edit to a file that is there.  The
+    family is the second one the repo has: its configuration file, its
+    reference module and its operations module, copied into a root that
+    holds the first family alone."""
+    from benchmark.harness import Benchmark, MissingPiece
 
+    family = [("configs", "tinyxl64.json"), ("reference", "sdxl_stream.py"),
+              ("flops", "sdxl_stream.py")]
     spec = tiny_spec()
+    spec["configs"] = [c for c in spec["configs"] if c["name"] != "tinyxl64"]
+    spec["workloads"] = [w for w in spec["workloads"] if w["config"] != "tinyxl64"]
+    root = make_root(tmp_path, spec)
+    home = os.path.join(root, "benchmark")
+    for kind, f in family:
+        os.remove(os.path.join(home, kind, f))
+    with pytest.raises(MissingPiece, match="tinyxl64.trickle"):
+        Benchmark(root).cell("tinyxl64.trickle")
+
+    # what the later PR brings: entries ...
     spec["configs"].append(
-        {"name": "dummy", "source": "tests", "file": "benchmark/configs/dummy.json",
-         "reduced": [], "why": "added"}
+        {"name": "tinyxl64", "source": "tests", "file": "benchmark/configs/tinyxl64.json",
+         "reduced": [], "why": "two text towers, text_time addition embedding"}
     )
     spec["workloads"].append(
-        {"name": "dummy.trickle", "config": "dummy", "traffic": "trickle",
+        {"name": "tinyxl64.trickle", "config": "tinyxl64", "traffic": "trickle",
          "chips": 1, "why": "added"}
     )
     spec["per_layer"].append(
         {"name": "dummy_count", "unit": "frames", "better": "higher",
          "source": "program_counter", "layer": "load generator (benchmark)",
-         "moves": "stylized_fps", "workloads": ["dummy.trickle"]}
+         "moves": "stylized_fps", "workloads": ["tinyxl64.trickle"]}
     )
-    root = make_root(tmp_path, spec)
-    home = os.path.join(root, "benchmark")
-    with open(os.path.join(home, "configs", "tiny64.json")) as f:
-        cfg = json.load(f)
-    with open(os.path.join(home, "configs", "dummy.json"), "w") as f:
-        json.dump(dict(cfg, what="a copy", reference="dummy_ref", flops="dummy_ops"), f)
-    # a new model family brings its own reference and its own count of
-    # operations (here: the old ones under new names), found by name
-    with open(os.path.join(home, "reference", "dummy_ref.py"), "w") as f:
-        f.write("from .sd_stream import Reference, weight_shapes  # noqa: F401\n")
-    with open(os.path.join(home, "flops", "dummy_ops.py"), "w") as f:
-        f.write("def frame_flops(cfg):\n    return 7\n")
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(spec, f)
+    # ... and files: the family's three, a traffic mix, a metric's reader
+    src = {"configs": os.path.join(HERE, "data", "configs"),
+           "reference": os.path.join(REPO, "benchmark", "reference"),
+           "flops": os.path.join(REPO, "benchmark", "flops")}
+    for kind, f in family:
+        shutil.copy(os.path.join(src[kind], f), os.path.join(home, kind, f))
     with open(os.path.join(home, "traffic", "trickle.json"), "w") as f:
         json.dump({"sessions": 1, "slots": 1, "source_fps": 5,
                    "pipeline_depth": 2, "warmup_frames": 10}, f)
@@ -123,10 +136,15 @@ def test_new_pieces_are_added_by_files_and_entries_alone(tmp_path):
         f.write("def read(ctx):\n    return float(ctx.traffic['source_fps'])\n")
 
     bench = Benchmark(root)
-    cell = bench.cell("dummy.trickle")
-    assert bench.config(cell)["name"] == "dummy"
-    assert bench.flops(bench.config(cell)).frame_flops(None) == 7
-    assert bench.reference(bench.config(cell)).Reference.__name__ == "Reference"
+    cell = bench.cell("tinyxl64.trickle")
+    cfg = bench.config(cell)
+    assert cfg["name"] == "tinyxl64" and cfg["program_text_subtrees"] == ["clip", "clip2"]
+    ref_module, flops = bench.reference(cfg), bench.flops(cfg)
+    assert ref_module.__file__ == os.path.join(home, "reference", "sdxl_stream.py")
+    assert set(ref_module.weight_shapes(cfg)) == {"unet", "clip", "clip2", "taesd"}
+    assert "add_embedding" in ref_module.weight_shapes(cfg)["unet"]
+    assert flops.frame_flops(cfg) > flops.sd_stream.frame_flops(cfg)
+    assert len(flops.attention_calls(cfg)) == 2 * (2 + 2 + 4)  # depth 2: down, mid, up x 2
     assert bench.traffic(cell)["source_fps"] == 5
     names = [m["name"] for m in bench.per_layer(cell)]
     assert names[-1] == "dummy_count" and len(names) == len(spec["per_layer"])
