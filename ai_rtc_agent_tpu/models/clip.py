@@ -55,6 +55,13 @@ class CLIPTextConfig:
         return CLIPTextConfig(width=1024, layers=24, heads=16, activation="gelu", clip_skip=1)
 
     @staticmethod
+    def sdxl_l() -> "CLIPTextConfig":
+        """SDXL's first tower: the SD1.5 ViT-L, read at the penultimate
+        layer (``StableDiffusionXLPipeline.encode_prompt`` takes
+        ``hidden_states[-2]`` of both towers)."""
+        return CLIPTextConfig(clip_skip=1)
+
+    @staticmethod
     def sdxl_g() -> "CLIPTextConfig":
         return CLIPTextConfig(
             width=1280,

@@ -103,9 +103,11 @@ def default_stream_config(model_id: str, **overrides) -> StreamConfig:
             ),
         )
     elif fam == "sdxl":
+        # SDXL-Turbo's model card publishes 512x512; base SDXL is 1024x1024
+        side = 512 if "turbo" in m else 1024
         base = dict(
-            height=1024,
-            width=1024,
+            height=side,
+            width=side,
             t_index_list=(0,),
             num_inference_steps=1,
             timestep_spacing="trailing",
@@ -176,7 +178,7 @@ def _model_configs(fam: str):
     if fam == "sd21":
         return U.UNetConfig.sd21(), C.CLIPTextConfig.sd21(), T.TAESDConfig()
     if fam == "sdxl":
-        return U.UNetConfig.sdxl(), C.CLIPTextConfig.sd15(), T.TAESDConfig()
+        return U.UNetConfig.sdxl(), C.CLIPTextConfig.sdxl_l(), T.TAESDConfig()
     if fam == "tiny":
         return (
             U.UNetConfig.tiny(),

@@ -336,12 +336,15 @@ def time_cond_embedding(p, cfg: UNetConfig, timesteps, added_cond=None, dtype=jn
         time_ids = added_cond["time_ids"]  # [B, num_time_ids]
         pooled = added_cond["text_embeds"]  # [B, pooled_dim]
         b = time_ids.shape[0]
-        tid = timestep_embedding(
-            time_ids.reshape(-1), cfg.addition_time_embed_dim, dtype=dtype
-        ).reshape(b, -1)
-        add = jnp.concatenate([pooled.astype(dtype), tid], axis=-1)
-        ae = p["add_embedding"]
-        temb = temb + linear(ae["linear_2"], silu(linear(ae["linear_1"], add)))
+        with jax.named_scope("add_embedding"):
+            tid = timestep_embedding(
+                time_ids.reshape(-1), cfg.addition_time_embed_dim, dtype=dtype
+            ).reshape(b, -1)
+            add = jnp.concatenate([pooled.astype(dtype), tid], axis=-1)
+            ae = p["add_embedding"]
+            temb = temb + linear(
+                ae["linear_2"], silu(linear(ae["linear_1"], add))
+            )
     return temb
 
 

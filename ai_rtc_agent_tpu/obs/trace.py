@@ -97,6 +97,8 @@ STAGES = (
     "window_wait",        # dispatcher parked on the window or the in-flight cap
     "await_row",          # future wait + the blocking row readback (child of fetch)
     "finish_output",      # safety check + pts wrap (child of fetch)
+    "encode_prompt",      # the text towers: a claim's prompt, a /config or
+                          # datachannel prompt write (never on a frame's path)
 )
 
 # terminal markers — how a frame left the pipeline

@@ -135,7 +135,7 @@ SESSION_SNAPSHOT_SCHEMA = 2
 # from the obs/trace.py STAGES taxonomy, the closed key set of the counters
 COUNTED_HOPS = (
     "pull_wait", "coerce", "stage_h2d", "enqueue_lock_wait", "dispatch",
-    "launch", "await_row", "finish_output",
+    "launch", "await_row", "finish_output", "encode_prompt",
 )
 # why a step was dispatched when it was (``batchsched_dispatch_cause_total``):
 # solo = the one-live-session path in _enqueue; inline_full = this submit
@@ -666,8 +666,9 @@ class BatchScheduler:
         # wires this to the overload plane's step EWMA as dt/occupancy
         self.on_step = None
         self.params = params
+        self._encode_prompt = encode_prompt
         self._template = StreamEngine(
-            models, params, cfg, encode_prompt,
+            models, params, cfg, self._encode_towers,
             schedule=schedule, jit_compile=False,
         )
         # DeepCache (UNET_CACHE) rides the scheduler as a GLOBAL cadence
@@ -714,28 +715,6 @@ class BatchScheduler:
         # HLO}; filled by prewarm_buckets (an AOT-adopted or lazily
         # compiled bucket has no compiled object to read, and no entry)
         self.mosaic_kernels: dict = {}
-        # ONE template prepare, tiled: inactive rows are placeholders —
-        # claim() installs a freshly prepared state before any frame runs
-        self._template.prepare(
-            self.prompt, guidance_scale=self.guidance_scale,
-            delta=self.delta, seed=0,
-        )
-        tmpl_state = self._template.state
-        if self._adapter_rank:
-            # the factor bank stacks WITH the latents: every slot is born
-            # on the zero rows (a bitwise no-op through layers.linear), so
-            # the bank changes shapes exactly once — at bind — and every
-            # adapter install afterwards is a control-plane write
-            tmpl_state = dict(tmpl_state)
-            tmpl_state["adapters"] = self._zero_rows
-        self.states = jax.tree.map(
-            lambda x: jnp.stack([x] * S), tmpl_state
-        )
-        if self.dp > 1:
-            # materialize the session-axis shards NOW: every later install
-            # (.at[slot].set of an uncommitted fresh row) preserves the
-            # sharding, so donation round-trips without resharding copies
-            self.states = jax.device_put(self.states, self._row_sh)
         self.active = [False] * S
         self._sessions: dict = {}  # slot -> ScheduledSession
         self._queues = [
@@ -777,6 +756,29 @@ class BatchScheduler:
         self._occ: deque = deque(maxlen=512)
         self._waits: deque = deque(maxlen=512)
         self._reset_counters_locked()
+        # ONE template prepare, tiled: inactive rows are placeholders —
+        # claim() installs a freshly prepared state before any frame runs
+        # (after the counters exist: the prepare encodes the default prompt)
+        self._template.prepare(
+            self.prompt, guidance_scale=self.guidance_scale,
+            delta=self.delta, seed=0,
+        )
+        tmpl_state = self._template.state
+        if self._adapter_rank:
+            # the factor bank stacks WITH the latents: every slot is born
+            # on the zero rows (a bitwise no-op through layers.linear), so
+            # the bank changes shapes exactly once — at bind — and every
+            # adapter install afterwards is a control-plane write
+            tmpl_state = dict(tmpl_state)
+            tmpl_state["adapters"] = self._zero_rows
+        self.states = jax.tree.map(
+            lambda x: jnp.stack([x] * S), tmpl_state
+        )
+        if self.dp > 1:
+            # materialize the session-axis shards NOW: every later install
+            # (.at[slot].set of an uncommitted fresh row) preserves the
+            # sharding, so donation round-trips without resharding copies
+            self.states = jax.device_put(self.states, self._row_sh)
         # bucket steps launched by this process, rehearsal included, never
         # reset: the n-th ``rtc:launch`` span is the n-th ``jit_bucket``
         # event of the chip's in-order program stream (dispatch lock held)
@@ -1295,9 +1297,20 @@ class BatchScheduler:
             self._tick = 0
             self._uncaptured.add(slot)
 
+    def _encode_towers(self, prompt: str):
+        """The bundle's ``encode_prompt`` as the template engine calls it,
+        from ``prepare`` (a claim) and from ``_encode`` (a prompt write):
+        the text towers, spanned and counted, without the heavy lock's
+        wait."""
+        with hop("encode_prompt") as towers:
+            res = self._encode_prompt(prompt)
+        with self._stats_lock:
+            self._count_hop_locked("encode_prompt", towers.seconds)
+        return res
+
     def _encode(self, prompt: str):
         with self._heavy_lock, devtel.expected_scope("sched-prompt-encode"):
-            res = self._template.encode_prompt(prompt)
+            res = self._encode_towers(prompt)
             return res if len(res) == 3 else (*res, {})
 
     def _apply_prompt(self, slot: int, encoded):

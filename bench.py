@@ -72,7 +72,10 @@ def build_engine(config: str, fbs: int = 1, unet_cache: int = 0):
     elif config == "lcm4x512":
         model_id, overrides = "lykon/dreamshaper-8", dict(dtype=dtype)
     elif config == "sdxl1024":
-        model_id, overrides = "stabilityai/sdxl-turbo", dict(dtype=dtype)
+        # the id's own default is 512x512 (its model card); this is
+        # BASELINE configs[2], which asks for 1024x1024
+        model_id = "stabilityai/sdxl-turbo"
+        overrides = dict(dtype=dtype, height=1024, width=1024)
     elif config == "controlnet512":
         # BASELINE configs[3]: ControlNet-canny conditioned stream (SD1.5+LCM)
         model_id = "lykon/dreamshaper-8"

@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from ai_rtc_agent_tpu.models import clip as C
 from ai_rtc_agent_tpu.models import controlnet as CN
@@ -153,10 +154,94 @@ def test_default_stream_config_families():
     assert sd21b.prediction_type == "epsilon" and sd21b.height == 512
 
     xl = registry.default_stream_config("stabilityai/sdxl-turbo")
-    assert xl.height == 1024 and xl.use_added_cond
+    assert (xl.height, xl.width) == (512, 512) and xl.use_added_cond
+    xl_base = registry.default_stream_config("stabilityai/sdxl-base-1.0")
+    assert (xl_base.height, xl_base.width) == (1024, 1024) and xl_base.use_added_cond
 
     sd15 = registry.default_stream_config("lykon/dreamshaper-8")
     assert sd15.scheduler == "lcm" and sd15.cfg_type == "self"
+
+
+def test_sdxl_turbo_is_served_as_published():
+    """The model card's stream (512x512, one step, no guidance) and the
+    pipeline's text path: ``StableDiffusionXLPipeline.encode_prompt`` takes
+    ``hidden_states[-2]`` of BOTH towers (no final norm); the text embedding
+    is the second tower's projection."""
+    from ai_rtc_agent_tpu.models import registry
+
+    cfg = registry.default_stream_config("stabilityai/sdxl-turbo")
+    assert (cfg.height, cfg.width) == (512, 512)
+    assert cfg.t_index_list == (0,) and cfg.num_inference_steps == 1
+    assert cfg.scheduler == "turbo" and cfg.timestep_spacing == "trailing"
+    assert cfg.cfg_type == "none" and cfg.use_added_cond
+    unet_cfg, tower1, _ = registry._model_configs("sdxl")
+    tower2 = C.CLIPTextConfig.sdxl_g()
+    assert (tower1.width, tower1.layers, tower1.clip_skip) == (768, 12, 1)
+    assert (tower2.width, tower2.layers, tower2.clip_skip) == (1280, 32, 1)
+    assert tower2.use_text_projection and not tower1.use_text_projection
+    assert unet_cfg.cross_attention_dim == tower1.width + tower2.width == 2048
+
+
+@pytest.mark.parametrize("clip_skip", [0, 1])
+def test_clip_text_agrees_with_the_plain_reference(clip_skip):
+    """``apply_clip_text`` against ``benchmark/reference/models.py`` on one
+    seeded tree at a tiny width: the hidden states cross attention is fed
+    (last layer final-normed at ``clip_skip`` 0, the layer before it raw at
+    1) and the projected end-of-text embedding.  Tolerance 2e-5 absolute on
+    values of order one: both sides are float32 on the CPU and differ in
+    the order of their sums only; the two ``clip_skip`` readings are 0.1
+    and more apart, so taking the wrong layer fails it."""
+    from benchmark.reference import models as ref_models
+
+    cfg = C.CLIPTextConfig(
+        vocab_size=256, max_length=16, width=32, layers=3, heads=4,
+        clip_skip=clip_skip, use_text_projection=True, projection_dim=24,
+    )
+    params = C.init_clip_text(jax.random.PRNGKey(4), cfg)
+    # init's norms are exactly (1, 0): move them, or a dropped norm hides
+    params = jax.tree.map(
+        lambda a: a + 0.1 * jax.random.normal(jax.random.PRNGKey(a.size), a.shape),
+        params,
+    )
+    ids = jnp.asarray([[254, 7, 99, 3, 255] + [255] * 11, [254, 1, 2, 255] + [255] * 12])
+    t = {"hidden_act": cfg.activation, "num_attention_heads": cfg.heads,
+         "clip_skip": clip_skip}
+    ours = C.apply_clip_text(params, ids, cfg)
+    with jax.default_matmul_precision("highest"):
+        hidden, text = ref_models.clip_text_projected(params, ids, t)
+        other = ref_models.clip_text(params, ids, dict(t, clip_skip=1 - clip_skip))
+    np.testing.assert_allclose(ours["hidden"], hidden, atol=2e-5, rtol=0)
+    np.testing.assert_allclose(ours["projected"], text, atol=2e-5, rtol=0)
+    assert np.abs(np.asarray(ours["hidden"]) - np.asarray(other)).max() > 0.1
+
+
+def test_sdxl_turbo_tree_is_the_benchmark_files():
+    """The program's tree for ``stabilityai/sdxl-turbo``, as shapes, against
+    what ``benchmark/configs/sdxlturbo512.json`` lays out: the same leaves,
+    3,387,629,067 parameters, so the file and the program cannot drift
+    apart.  Nothing is materialised (``eval_shape``)."""
+    import json
+    import math
+    import os
+
+    from ai_rtc_agent_tpu.models import registry
+    from benchmark.reference import sdxl_stream
+    from benchmark.weights import same_layout
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "sdxlturbo512.json")) as f:
+        cfg = json.load(f)
+    assert cfg["program_model_id"] == "stabilityai/sdxl-turbo"
+    shapes = jax.eval_shape(
+        lambda: registry.load_model_bundle(cfg["program_model_id"]).params
+    )
+    assert same_layout(sdxl_stream.weight_shapes(cfg), shapes) is None
+    assert sorted(shapes) == sorted(cfg["program_text_subtrees"] + ["unet", "taesd"])
+    n = sum(math.prod(a.shape) for a in jax.tree.leaves(shapes))
+    assert n == 3_387_629_067
+    stream = registry.default_stream_config(cfg["program_model_id"])
+    assert (stream.height, stream.width) == (cfg["stream"]["height"], cfg["stream"]["width"])
+    assert cfg["text_encoder"]["clip_skip"] == registry._model_configs("sdxl")[1].clip_skip == 1
 
 
 def test_v_prediction_stream_end_to_end(rng):

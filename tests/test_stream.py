@@ -1,5 +1,8 @@
 """Stream engine tests on the tiny model family (CPU, hermetic)."""
 
+import json
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -293,11 +296,153 @@ def test_concurrent_submits_from_two_threads():
         assert o.shape == (cfg.height, cfg.width, 3) and o.dtype == np.uint8
 
 
-def test_tinyxl_added_cond_stream_and_prompt_swap():
+# -- the two-tower (SDXL-style) family ----------------------------------------
+
+_TINYXL_FILE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "benchmark", "tests", "data", "configs", "tinyxl64.json",
+)
+_PROMPTS = ("a street at night, neon style", "a watercolor garden at noon")
+# uint8 levels between a served frame and the reference's unrounded one:
+# half a level is the rounding to uint8, the rest float32 summation order
+# (both sides float32 on the CPU, the reference at ``highest``; read 5e-5).
+# A dropped part moves single pixels by 30-180 levels (CHANGES.md, PR 28)
+_PARITY_LEVELS = 0.5 + 0.01
+
+
+@pytest.fixture(scope="module")
+def tinyxl():
+    """-> (bundle, configuration file), built once for this file: the
+    program's ``tinyxl-test`` bundle on seeded weights (``benchmark/
+    weights.py`` under the tiny configuration's rules, which keep the
+    decoded image off the clip: a flat or saturated frame shows no
+    difference), and the parsed file the plain reference reads."""
+    from benchmark.reference import sdxl_stream
+    from benchmark.weights import make_weights
+
+    with open(_TINYXL_FILE) as f:
+        cfg_file = json.load(f)
+    bundle = registry.load_model_bundle(cfg_file["program_model_id"])
+    bundle.params.update(make_weights(
+        sdxl_stream.weight_shapes(cfg_file), 7, jnp.float32,
+        cfg_file["weights"]["rules"],
+    ))
+    return bundle, cfg_file
+
+
+@pytest.fixture(scope="module")
+def tinyxl_served(tinyxl):
+    """[(prompt, source frame, served uint8 frame)] of one session through
+    ``BatchScheduler.claim()`` / ``submit`` / ``fetch``, the prompt written
+    mid-stream (``update_prompt``) after the third frame; with the
+    scheduler's counters as the session left them."""
+    from ai_rtc_agent_tpu.stream.scheduler import BatchScheduler
+
+    bundle, cfg_file = tinyxl
+    s = cfg_file["stream"]
+    overrides = {
+        k: tuple(v) if isinstance(v, list) else v
+        for k, v in cfg_file["program_stream_overrides"].items()
+    }
+    cfg = registry.default_stream_config(
+        cfg_file["program_model_id"], dtype=s["dtype"], **overrides
+    )
+    assert cfg.use_added_cond and cfg.t_index_list == tuple(s["t_index_list"])
+    sched = BatchScheduler(
+        bundle.stream_models, bundle.params, cfg, bundle.encode_prompt,
+        max_sessions=1, guidance_scale=s["guidance_scale"], delta=s["delta"],
+        prewarm=False, dp=1,
+    )
+    try:
+        sess = sched.claim("parity", prompt=_PROMPTS[0], seed=5)
+        served = []
+        for i, frame in enumerate(_frames(6, seed=21)):
+            if i == 3:
+                sess.update_prompt(_PROMPTS[1])
+            out = sess.fetch(sess.submit(frame))
+            served.append((_PROMPTS[i >= 3], frame, np.asarray(out)))
+        sess.release()
+        return served, sched.snapshot()
+    finally:
+        sched.close()
+
+
+def _reference_frames(ref, served, seed=5):
+    sess = ref.session(served[0][0], seed)
+    out = []
+    for prompt, frame, _ in served:
+        sess.cond = ref.encode_prompt(prompt)
+        out.append(sess.step(frame))
+    return out
+
+
+def test_tinyxl_scheduler_frames_agree_with_the_plain_reference(tinyxl, tinyxl_served):
+    """Two towers, the 2048-analog context, ``add_embedding`` and the
+    depth-2 transformer stack through the scheduler, against
+    ``benchmark/reference/sdxl_stream.py`` on the same tree, before and
+    after a prompt write."""
+    from benchmark.reference import sdxl_stream
+
+    bundle, cfg_file = tinyxl
+    served, snap = tinyxl_served
+    ref = sdxl_stream.Reference(cfg_file, bundle.params)
+    for i, (want, (_, _, got)) in enumerate(zip(_reference_frames(ref, served), served)):
+        assert 20 < want.std() and ((want <= 0) | (want >= 255)).mean() < 0.1, i
+        assert got.dtype == np.uint8
+        assert np.abs(got.astype(np.float32) - want).max() <= _PARITY_LEVELS, i
+    # the prompt write changed the picture, on both sides alike
+    assert np.abs(served[2][2].astype(int) - served[3][2].astype(int)).max() > 10
+    # the towers ran three times, each spanned and counted: the default
+    # prompt when the scheduler was built, the claim, the write
+    assert snap["batchsched_hop_count"]["encode_prompt"] == 3
+    assert snap["batchsched_hop_ms_total"]["encode_prompt"] > 0
+
+
+@pytest.mark.parametrize("part", ["add_embedding", "second_tower_context", "deep_block"])
+def test_tinyxl_parity_sees_each_part_of_the_family(tinyxl, tinyxl_served, monkeypatch, part):
+    """The same comparison with one part left out of one side (the
+    reference's: the difference is the same whichever side lacks it, and
+    the served frames need no second scheduler): far outside the
+    tolerance, so the tolerance is one a missing part cannot hide in."""
+    from benchmark.reference import models, sdxl_stream
+
+    bundle, cfg_file = tinyxl
+    served, _ = tinyxl_served
+    weights = bundle.params
+    if part == "add_embedding":
+        real = models.unet
+        monkeypatch.setattr(
+            models, "unet", lambda p, x, t, ctx, u, added=None: real(p, x, t, ctx, u)
+        )
+    elif part == "second_tower_context":
+        real = models.clip_text_projected
+
+        def no_context(p, ids, t):
+            hidden, text = real(p, ids, t)
+            return hidden * 0.0, text
+
+        monkeypatch.setattr(models, "clip_text_projected", no_context)
+    else:  # the second transformer block of the depth-2 stacks
+        def shallow(tree):
+            if isinstance(tree, dict):
+                return {
+                    k: v[:1] if k == "blocks" and len(v) > 1 else shallow(v)
+                    for k, v in tree.items()
+                }
+            return [shallow(v) for v in tree] if isinstance(tree, list) else tree
+
+        weights = dict(weights, unet=shallow(weights["unet"]))
+        assert len(jax.tree.leaves(weights)) < len(jax.tree.leaves(bundle.params))
+    ref = sdxl_stream.Reference(cfg_file, weights)
+    for want, (_, _, got) in zip(_reference_frames(ref, served), served):
+        assert np.abs(got.astype(np.float32) - want).max() > 20 * _PARITY_LEVELS
+
+
+def test_tinyxl_added_cond_stream_and_prompt_swap(tinyxl):
     """The hermetic SDXL-style family (dual text towers + text_time
     addition embeds) streams end to end, and a prompt update swaps the
     POOLED embeds too (reference SDXL conditioning surface)."""
-    bundle = registry.load_model_bundle("tiny-xl-test")
+    bundle, _ = tinyxl
     cfg = registry.default_stream_config("tiny-xl-test")
     assert cfg.use_added_cond
     eng = StreamEngine(

@@ -285,6 +285,38 @@ def _host_spans(xspace: bytes) -> list:
     ]
 
 
+def test_every_prompt_encode_is_spanned_and_counted(bundle, cfg):
+    """The text towers run on a claim, on a session's prompt write and on
+    the global one (``POST /config``): each is one ``rtc:encode_prompt``
+    span in the profiler's trace and one count of the ``encode_prompt``
+    hop, whose first count is the default prompt the scheduler encodes
+    while it is built."""
+    from jax._src.lib import _profiler
+
+    s = _sched(bundle, cfg, max_sessions=1)
+    try:
+        built = s.snapshot()["batchsched_hop_count"]["encode_prompt"]
+        jax.devices()
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        session = _profiler.ProfilerSession(options)
+        a = s.claim("a", prompt="p", seed=1)
+        a.update_prompt("q")
+        s.update_prompt("r")
+        spans = [sp for sp in _host_spans(session.stop()) if sp[0] == "rtc:encode_prompt"]
+        snap = s.snapshot()
+    finally:
+        s.close()
+    assert built == 1 and len(spans) == 3
+    assert snap["batchsched_hop_count"]["encode_prompt"] == 4
+    traced_ms = sum(t1 - t0 for _, _, t0, t1 in spans) / 1e6
+    assert 0 < traced_ms <= snap["batchsched_hop_ms_total"]["encode_prompt"]
+    assert snap["batchsched_hop_ms_max"]["encode_prompt"] * 4 >= (
+        snap["batchsched_hop_ms_total"]["encode_prompt"]
+    )
+
+
 def test_profiler_session_holds_the_hops_with_matching_ids(bundle, cfg, rng):
     from jax._src.lib import _profiler
 

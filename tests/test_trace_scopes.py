@@ -67,6 +67,26 @@ def test_lowered_bucket_step_carries_the_scope(scope_components, scope):
     assert scope in scope_components
 
 
+def test_two_tower_family_names_its_addition_embedding():
+    """The ``text_time`` branch of ``time_cond_embedding`` (the ``sdxl`` and
+    ``tinyxl`` families) is the scope ``add_embedding`` under ``time_embed``:
+    its two linear layers and the ``time_ids`` sinusoid."""
+    bundle = registry.load_model_bundle("tinyxl-test")
+    cfg = registry.default_stream_config("tinyxl-test")
+    s = BatchScheduler(
+        bundle.stream_models, bundle.params, cfg, bundle.encode_prompt,
+        max_sessions=1, prewarm=False,
+    )
+    try:
+        text = s._bucket_step(1, "full").lower(*s._bucket_specs(1)).as_text(
+            debug_info=True
+        )
+    finally:
+        s.close()
+    inside = re.findall(r'loc\("jit\(bucket\)[^"]*time_embed/add_embedding/([a-z_]+)', text)
+    assert "dot_general" in inside and "cos" in inside, sorted(set(inside))
+
+
 def test_no_scope_contains_a_mosaic_kernels_name(scope_components):
     """benchmark/trace_reduce.py and mosaic_kernel_counts find a kernel by
     that substring of an op's name: a scope named after one would add its
