@@ -83,6 +83,8 @@ STAGES = (
     # of the profiler trace and keys of the scheduler's hop counters, never
     # FrameTrace spans, so they carry no SLO budget (obs/slo.py budgets
     # the stages above)
+    "hold",               # the track's pause before the pull that refills a
+                          # pipeline the device paces (counter only)
     "pull_wait",          # the track's wait for its source (counter only:
                           # no span is held across an await)
     "coerce",             # duck-typed frame -> [H,W,3] uint8 (child of submit)

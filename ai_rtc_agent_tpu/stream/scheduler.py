@@ -134,7 +134,7 @@ SESSION_SNAPSHOT_SCHEMA = 2
 # the hops the scheduler counts (``batchsched_hop_*`` in snapshot()): names
 # from the obs/trace.py STAGES taxonomy, the closed key set of the counters
 COUNTED_HOPS = (
-    "pull_wait", "coerce", "stage_h2d", "enqueue_lock_wait", "dispatch",
+    "hold", "pull_wait", "coerce", "stage_h2d", "enqueue_lock_wait", "dispatch",
     "launch", "await_row", "finish_output", "encode_prompt",
 )
 # why a step was dispatched when it was (``batchsched_dispatch_cause_total``):
@@ -203,7 +203,7 @@ class _PendingFrame:
     __slots__ = (
         "frame", "frame_dev", "future", "trace", "t_enq", "t_dispatch",
         "occupancy", "skipped", "readback", "seq", "pull_wait_s",
-        "coerce_s", "stage_s", "lock_wait_s",
+        "coerce_s", "stage_s", "lock_wait_s", "hold_s",
     )
 
     def __init__(self, frame, trace=None, seq=0, pull_wait_s=None,
@@ -223,6 +223,10 @@ class _PendingFrame:
         self.coerce_s: float | None = coerce_s
         self.stage_s: float | None = None
         self.lock_wait_s: float | None = None
+        # how long the track held this frame's pull (server/tracks.py
+        # stamps it on the handle ``submit`` returned, which reaches the
+        # track through wrappers that pass no attribute of the session)
+        self.hold_s: float | None = None
         # (batch, row) of the _DispatchedBatch this frame rode — the
         # submitter resolves it directly at fetch, bypassing the future
         self.readback: tuple | None = None
@@ -2586,7 +2590,8 @@ class BatchScheduler:
         runs)."""
         with self._stats_lock:
             for name, seconds in (
-                ("pull_wait", p.pull_wait_s), ("coerce", p.coerce_s),
+                ("hold", p.hold_s), ("pull_wait", p.pull_wait_s),
+                ("coerce", p.coerce_s),
                 ("stage_h2d", p.stage_s),
                 ("enqueue_lock_wait", p.lock_wait_s),
                 ("await_row", await_s), ("finish_output", finish_s),

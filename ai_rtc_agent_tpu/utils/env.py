@@ -286,6 +286,8 @@ def pipeline_depth() -> int:
     """Frames kept in flight on the device per track (PIPELINE_DEPTH).
 
     1 = fully synchronous (reference behavior).  >1 overlaps dispatch,
-    device compute and device->host copy across consecutive frames —
-    throughput rises at the cost of `depth` frames of latency."""
+    device compute and device->host copy across consecutive frames:
+    throughput rises, and a frame is still one step and a few
+    milliseconds from pull to pixels, because the track holds its pull
+    until the running step is about to end (server/tracks.py)."""
     return max(1, get_int("PIPELINE_DEPTH", 2))

@@ -130,6 +130,8 @@ def test_bare_ndarray_session_counts_every_hop(bundle, cfg, rng):
         for i in range(n):
             a.note_pull_wait(0.001 * (i + 1))
             handles.append(a.submit(_frame(rng)))
+            if i >= 2:  # as the track: the first pulls are not held
+                handles[-1].hold_s = 0.002 * (i + 1)
             if len(handles) == 2:  # depth 2, as the track keeps it
                 a.fetch(handles.pop(0))
         for h in handles:
@@ -142,6 +144,9 @@ def test_bare_ndarray_session_counts_every_hop(bundle, cfg, rng):
         for per_frame in ("pull_wait", "coerce", "stage_h2d", "enqueue_lock_wait",
                           "await_row", "finish_output"):
             assert count[per_frame] == n, per_frame
+        assert count["hold"] == n - 2  # the frames whose pull was held
+        assert ms["hold"] == pytest.approx(2.0 * (3 + 4 + 5 + 6), rel=1e-6)
+        assert snap["batchsched_hop_ms_max"]["hold"] == pytest.approx(12.0)
         assert count["dispatch"] == count["launch"] == snap["batchsched_steps_total"] == n
         assert ms["pull_wait"] == pytest.approx(1.0 + 2 + 3 + 4 + 5 + 6, rel=1e-6)
         assert snap["batchsched_hop_ms_max"]["pull_wait"] == pytest.approx(6.0)
@@ -173,6 +178,8 @@ def test_counters_are_cumulative_and_window_by_subtraction(bundle, cfg, rng):
         assert c1["batchsched_hop_count"]["coerce"] - c0["batchsched_hop_count"]["coerce"] == 3
         assert c1["batchsched_hop_ms_total"]["dispatch"] > c0["batchsched_hop_ms_total"]["dispatch"]
         assert c1["batchsched_hop_count"]["pull_wait"] == 0  # no track told it
+        assert c1["batchsched_hop_count"]["hold"] == 0
+        assert c1["batchsched_hop_ms_total"]["hold"] == 0.0
     finally:
         s.close()
 
@@ -229,6 +236,26 @@ def test_rehearsal_leaves_the_counters_at_zero(bundle, cfg):
         s.close()
 
 
+def test_rehearsal_clears_the_hold_counter(bundle, cfg, rng):
+    s = _sched(bundle, cfg, max_sessions=1)
+    try:
+        a = s.claim("a", prompt="p", seed=1)
+        handle = a.submit(_frame(rng))
+        handle.hold_s = 0.0125
+        a.fetch(handle)
+        a.release()
+        snap = s.snapshot()
+        assert snap["batchsched_hop_count"]["hold"] == 1
+        assert snap["batchsched_hop_ms_total"]["hold"] == pytest.approx(12.5)
+        s.rehearse()
+        snap = s.snapshot()
+        assert snap["batchsched_hop_count"]["hold"] == 0
+        assert snap["batchsched_hop_ms_total"]["hold"] == 0.0
+        assert snap["batchsched_hop_ms_max"]["hold"] == 0.0
+    finally:
+        s.close()
+
+
 def test_track_counts_its_wait_for_the_source(bundle, cfg, rng):
     """VideoStreamTrack._pull_fresh: a counter, never a span across the
     await; reaches the session through any attribute-passing wrapper."""
@@ -267,6 +294,9 @@ def test_track_counts_its_wait_for_the_source(bundle, cfg, rng):
         n = snap["batchsched_hop_count"]["pull_wait"]
         assert n == 3  # frames fetched so far (a fourth is in flight)
         assert snap["batchsched_hop_ms_total"]["pull_wait"] >= n * 9.0
+        # a track that waits for its source holds nothing
+        assert snap["batchsched_hop_count"]["hold"] == 0
+        assert snap["batchsched_hop_ms_total"]["hold"] == 0.0
     finally:
         s.close()
 
