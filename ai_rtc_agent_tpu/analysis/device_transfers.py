@@ -4,8 +4,8 @@ The device-resident frame path (PR 9) has exactly three transfer
 disciplines, each held by ONE helper: H2D staging is
 ``stream/engine.stage_frame`` (async ``device_put`` before any dispatch
 lock), D2H readback is per-slot and memoized
-(``BatchScheduler._resolve_row``; the engine/multipeer ``fetch`` for the
-non-scheduler tiers), and async D2H kicks (``copy_to_host_async``) live
+(``BatchScheduler._resolve_row``; the engine's ``fetch`` for the
+shared-engine plane), and async D2H kicks (``copy_to_host_async``) live
 where the dispatch happens.  A stray transfer anywhere else is exactly
 the bug class PR 9 removed — the scheduler's old dispatcher drained the
 ENTIRE stacked ``[S, ...]`` batch output with one host copy, so every
@@ -76,13 +76,10 @@ _BLESSED = {
         "BatchScheduler._step_batch_locked", "BatchScheduler._resolve_row",
         "BatchScheduler._assemble_frames", "BatchScheduler._rows_from_sharded",
     },
-    "ai_rtc_agent_tpu/parallel/multipeer.py": {
-        "MultiPeerEngine.submit", "MultiPeerEngine.fetch",
-    },
 }
 
 # terminal names of attributes that hold a jitted step callable; calling
-# one produces device values (the engine/scheduler/multipeer idiom)
+# one produces device values (the engine/scheduler idiom)
 _STEP_ATTRS = {"_step", "_step_cached", "_raw_capture_step"}
 # factories whose CALL returns a step callable: self._bucket_step(k, v)(...)
 _STEP_FACTORIES = {"_bucket_step"}

@@ -28,8 +28,8 @@ nested-def spellings), then transitively through same-class
   ``loop.call_soon_threadsafe(self._ev.set)`` — media/plane.py);
 * ``set_result`` / ``set_exception`` on a name or attribute tainted as
   an ASYNCIO future (assigned from ``create_future()`` /
-  ``asyncio.Future()``); ``concurrent.futures.Future`` — the scheduler
-  and multipeer handoff discipline — is thread-safe and stays clean.
+  ``asyncio.Future()``); ``concurrent.futures.Future`` — the scheduler's
+  handoff discipline — is thread-safe and stays clean.
 
 **Loop side** — lexically inside ``async def`` (nested ``def``s are the
 executor-target idiom and exempt, as in async-blocking):

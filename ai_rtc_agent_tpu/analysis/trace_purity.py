@@ -12,8 +12,7 @@ Seeds — a function is considered traced when it is:
   ``partial(fn, ...)``),
 * decorated with any of those (bare or via ``@partial(jax.jit, ...)``),
 * passed to a local jit-wrapper: a same-module function whose own body
-  calls one of the jit entry points (the ``_jit``/``_vjit`` idiom in
-  stream/engine.py and parallel/multipeer.py),
+  calls one of the jit entry points (the ``_jit`` idiom in stream/engine.py),
 * defined inside a factory whose call result is passed to a jit entry
   point (``jax.jit(make_step_fn(...))`` taints every def nested in
   ``make_step_fn``).

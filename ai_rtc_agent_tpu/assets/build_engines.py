@@ -96,42 +96,6 @@ def build(
     return keys, bundle
 
 
-def build_multipeer(
-    model_id: str,
-    peers: int,
-    lora_dict: dict | None = None,
-    cache_dir: str | None = None,
-    controlnet: str | None = None,
-    bundle=None,
-):
-    """Prebuild the ``--multipeer N`` serving engine (peers-N keys; with
-    UNET_CACHE set this is the capture+cached pair).  Uses the serving
-    engine's own adoption path as the builder, so the keys can never drift
-    from what `MultiPeerEngine.use_aot_cache` looks for.  ``bundle``: an
-    already-loaded-and-cast ModelBundle (main() reuses build()'s — the
-    checkpoint read and cast are not paid twice)."""
-    from ..models import registry
-    from ..parallel.multipeer import MultiPeerEngine
-
-    cfg = registry.default_stream_config(
-        model_id, **({"use_controlnet": True} if controlnet else {})
-    )
-    if bundle is None:
-        bundle = registry.load_model_bundle(
-            model_id, lora_dict=lora_dict, controlnet=controlnet
-        )
-        bundle.params = registry.cast_params(bundle.params, cfg.dtype)
-    mp = MultiPeerEngine(
-        bundle.stream_models, bundle.params, cfg, bundle.encode_prompt,
-        max_peers=peers,
-    ).start("engine build probe")
-    if not mp.use_aot_cache(model_id, cache_dir=cache_dir, build_on_miss=True):
-        raise RuntimeError(
-            f"multipeer engine build failed for {model_id} peers={peers}"
-        )
-    logger.info("multipeer engine(s) built for %s peers=%d", model_id, peers)
-
-
 def build_scheduler_buckets(
     model_id: str,
     sessions: int,
@@ -146,7 +110,9 @@ def build_scheduler_buckets(
     geometries are detected via ``EngineCache.has()`` and skipped, so a
     partial earlier build (or a crash mid-way) resumes instead of
     recompiling everything.  Uses the scheduler's own adoption path as the
-    builder — the keys can never drift from what serving looks for."""
+    builder — the keys can never drift from what serving looks for.
+    ``bundle``: an already-loaded-and-cast ModelBundle (main() reuses
+    build()'s — the checkpoint read and cast are not paid twice)."""
     from ..models import registry
     from ..stream.scheduler import BatchScheduler
 
@@ -209,11 +175,6 @@ def main(argv=None):
              "(reference lib/wrapper.py:870-877)",
     )
     ap.add_argument(
-        "--peers", type=int, default=0,
-        help="also build the --multipeer N serving engine (peers-N keys; "
-             "with UNET_CACHE set, the capture+cached pair)",
-    )
-    ap.add_argument(
         "--sched-buckets", type=int, default=0, metavar="S",
         help="also prebuild the continuous batch scheduler's bucket "
              "geometries for S session slots (one engine per power-of-two "
@@ -230,11 +191,6 @@ def main(argv=None):
     _, bundle = build(
         args.model_id, lora_dict or None, args.cache_dir, args.controlnet
     )
-    if args.peers:
-        build_multipeer(
-            args.model_id, args.peers, lora_dict or None, args.cache_dir,
-            controlnet=args.controlnet, bundle=bundle,
-        )
     if args.sched_buckets:
         build_scheduler_buckets(
             args.model_id, args.sched_buckets, lora_dict or None,

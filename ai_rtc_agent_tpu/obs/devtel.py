@@ -41,7 +41,7 @@ hot-path hook is one module-global read + None test):
 * **Transfer accounting** — H2D bytes/count from the single
   :func:`~..stream.engine.stage_frame` staging path, D2H bytes/count
   from the blessed readback sites (the scheduler's per-row resolve, the
-  engine/multipeer fetch) — "fetch isolation" and "staged H2D" as
+  engine's fetch) — "fetch isolation" and "staged H2D" as
   dashboards instead of banked bench numbers.  The static checker
   (analysis/device_transfers.py) holds that these blessed paths stay
   the ONLY transfer sites, so the accounting cannot silently go blind.

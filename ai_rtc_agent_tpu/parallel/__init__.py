@@ -1,1 +1,1 @@
-from . import mesh, ring_attention, sharding, multipeer, trainer  # noqa: F401
+from . import mesh, ring_attention, sharding, trainer  # noqa: F401

@@ -88,11 +88,10 @@ def shard_params(mesh: Mesh, params, rules: Callable = unet_tp_rules):
 
 
 # -- session-axis (dp) sharding: the serving-tier rules ----------------------
-# The batch scheduler's stacked [S, ...] session pytree and the multipeer
-# peer axis shard their LEADING axis over dp; params replicate (or follow
-# the tp rules above when a tp axis is present).  These helpers are the
-# single recipe both serving tiers derive their pjit in/out specs from, so
-# the scheduler and multipeer cannot drift on what shards vs replicates.
+# The batch scheduler's stacked [S, ...] session pytree shards its LEADING
+# axis over dp; params replicate (or follow the tp rules above when a tp
+# axis is present).  These helpers are the single recipe the scheduler
+# derives its pjit in/out specs from.
 
 
 def session_axis_spec(mesh: Mesh, axis: str = "dp"):

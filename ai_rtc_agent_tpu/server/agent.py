@@ -306,7 +306,7 @@ def apply_runtime_config(pipeline, config: dict, encoders=None):
     # "nothing was applied", not "the prompt changed but guidance was
     # refused" — so non-numeric values fail here, not mid-apply
     if guidance_scale is not None or delta is not None:
-        if update_guidance is None:  # multipeer global plane has no knob
+        if update_guidance is None:  # an injected pipeline may lack it
             raise ValueError(
                 "guidance_scale/delta not supported by this pipeline"
             )
@@ -424,24 +424,17 @@ def _release_admission(app, session_key: str):
         ov.release_admission(session_key)
 
 
-def _slots_full_text(app) -> str:
-    """Name the serving plane whose slot pool refused — an operator
-    debugging 503s on a non-multipeer box must not be pointed at peer
-    slots that don't exist (the default path's pool is the batch
-    scheduler's session slots)."""
-    if app.get("multipeer_pipeline") is not None:
-        return "all peer slots in use"
-    return "all batch-scheduler session slots in use"
+# the 503 text names the pool that refused (the only slot pool there is)
+_SLOTS_FULL_TEXT = "all batch-scheduler session slots in use"
 
 
 async def _claim_pipeline(app, session_key: str | None = None,
                           imported=None):
-    """-> (pipeline, release_fn).  In --multipeer mode each connection
-    claims a slot of the batched engine (503 via CapacityError when full);
-    with the continuous batch scheduler active (the default single-device
-    path) each connection claims a scheduler session — per-session stream
-    state batched into one cross-session device step; otherwise every
-    connection shares the single pipeline (reference semantics,
+    """-> (pipeline, release_fn).  With the continuous batch scheduler
+    active (the default) each connection claims a scheduler session —
+    per-session stream state batched into one cross-session device step,
+    (None, None) via CapacityError when every slot is taken; otherwise
+    every connection shares the single pipeline (reference semantics,
     agent.py:423).  Claim runs a prepare() (text-encode + UNet stock
     pass), so it is pushed off the event loop; the returned release_fn is
     loop-safe (schedules its work on a thread).
@@ -450,7 +443,6 @@ async def _claim_pipeline(app, session_key: str | None = None,
     adopted AS the claim (renamed to this connection's session key, no
     fresh prepare: the migrated stream resumes exactly where the source
     froze it)."""
-    mp = app.get("multipeer_pipeline")
     sched = app.get("batch_scheduler")
     if imported is not None:
         imported.session_key = session_key
@@ -464,20 +456,9 @@ async def _claim_pipeline(app, session_key: str | None = None,
             spawn(asyncio.to_thread(imported.release))
 
         return imported, release_imported
-    if mp is None and sched is None:
+    if sched is None:
         return app["pipeline"], lambda: None
-    from .multipeer_serving import CapacityError
-
-    if mp is not None:
-        try:
-            peer = await asyncio.to_thread(mp.claim)
-        except CapacityError:
-            return None, None
-
-        def release():
-            spawn(asyncio.to_thread(peer.release))
-
-        return peer, release
+    from ..stream.scheduler import CapacityError
 
     try:
         session = await asyncio.to_thread(sched.claim, session_key)
@@ -669,8 +650,7 @@ async def migrate_import(request):
                 return _debug_error(
                     409, "no batch scheduler on this agent to restore into"
                 )
-            from ..stream.scheduler import SnapshotMismatch
-            from .multipeer_serving import CapacityError
+            from ..stream.scheduler import CapacityError, SnapshotMismatch
 
             try:
                 sess = await asyncio.to_thread(
@@ -681,7 +661,7 @@ async def migrate_import(request):
                 return _debug_error(409, f"snapshot refused: {e}")
             except CapacityError:
                 _release_admission(app, token)
-                return _overloaded_response(app, _slots_full_text(app))
+                return _overloaded_response(app, _SLOTS_FULL_TEXT)
             except BaseException:
                 # anything unexpected (XLA OOM, runtime error inside the
                 # install): the 500 the router will retry must not strand
@@ -897,8 +877,7 @@ async def _import_handoff(app):
         sess = None
         if (snap.get("kind") == "scheduler" and sched is not None
                 and hasattr(sched, "restore_session")):
-            from ..stream.scheduler import SnapshotMismatch
-            from .multipeer_serving import CapacityError
+            from ..stream.scheduler import CapacityError, SnapshotMismatch
 
             try:
                 sess = await asyncio.to_thread(
@@ -958,7 +937,7 @@ async def offer(request):
     )
     if pipeline is None:
         _release_admission(app, stream_id)
-        return _overloaded_response(app, _slots_full_text(app))
+        return _overloaded_response(app, _SLOTS_FULL_TEXT)
     # fleet journey correlation: bound BEFORE the SDP dance so on_track
     # (which fires inside setRemoteDescription) supervises a session
     # that already knows its journey
@@ -1325,7 +1304,7 @@ async def whip(request):
     )
     if pipeline is None:
         _release_admission(app, session_id)
-        return _overloaded_response(app, _slots_full_text(app))
+        return _overloaded_response(app, _SLOTS_FULL_TEXT)
     jmeta = _bind_journey(app, request, session_id)
 
     pc = None
@@ -1448,15 +1427,10 @@ async def update_config(request):
         return web.Response(status=400, text="invalid JSON body")
     logger.info("received config: %s", config)
     # the operator surface targets the serving plane actually in use:
-    # multipeer slots, else the batch scheduler (applies to every live
-    # session AND becomes the default for future claims — the shared-
-    # pipeline semantics operators already rely on), else the shared
-    # pipeline itself
-    target = (
-        request.app.get("multipeer_pipeline")
-        or request.app.get("batch_scheduler")
-        or request.app["pipeline"]
-    )
+    # the batch scheduler (applies to every live session AND becomes the
+    # default for future claims — the shared-pipeline semantics operators
+    # already rely on), else the shared pipeline itself
+    target = request.app.get("batch_scheduler") or request.app["pipeline"]
     encoders = _encoder_surface(request.app.get("provider"))
     try:
         await asyncio.to_thread(apply_runtime_config, target, config, encoders)
@@ -1541,14 +1515,8 @@ async def capacity(request):
     this box will still admit (-1 = no structural bound); ``saturated``:
     admission is currently refusing; ``retry_after_s``: backpressure hint."""
     app = request.app
-    mp = app.get("multipeer_pipeline")
     sched = app.get("batch_scheduler")
-    if mp is not None:
-        free = mp.free_slots
-    elif sched is not None:
-        free = sched.free_slots
-    else:
-        free = None
+    free = sched.free_slots if sched is not None else None
     ov = app.get("overload")
     if ov is None:
         body = {
@@ -1861,9 +1829,6 @@ async def metrics(request):
     # so this endpoint stays cheap exactly when the box is drowning
     ov = request.app.get("overload")
     if ov is not None:
-        mp = request.app.get("multipeer_pipeline")
-        if mp is not None:
-            out["overload_peer_frames_shed"] = mp.frames_shed
         out.update(ov.snapshot())
     # continuous batch scheduler (stream/scheduler.py): occupancy
     # histogram + window-wait percentiles — the cost-per-user story's
@@ -2067,22 +2032,7 @@ async def on_startup(app):
         )
 
     built_scheduler = False  # an injected (test) scheduler is left as given
-    if app.get("multipeer", 0) and app.get("multipeer_pipeline") is None:
-        from .multipeer_serving import MultiPeerPipeline
-
-        if app.get("fbs", 0) > 1:
-            raise ValueError(
-                "--fbs is not supported with --multipeer (peers are already "
-                "the batch dimension)"
-            )
-        app["multipeer_pipeline"] = MultiPeerPipeline(
-            app["model_id"],
-            max_peers=app["multipeer"],
-            config=_build_config(),
-            controlnet=app.get("controlnet"),
-        )
-        app["pipeline"] = None
-    elif app.get("pipeline") is None and not app.get("multipeer_pipeline"):
+    if app.get("pipeline") is None:
         from ..stream.pipeline import StreamDiffusionPipeline
 
         mesh = None
@@ -2362,16 +2312,11 @@ def _serving_info(app) -> dict:
     from ..utils.device import device_info
 
     info = device_info()
-    mp = app.get("multipeer_pipeline")
     sched = app.get("batch_scheduler")
     info["model_id"] = app["model_id"]
-    info["plane"] = (
-        "multipeer" if mp is not None
-        else "batchsched" if sched is not None
-        else "shared-engine"
-    )
+    info["plane"] = "batchsched" if sched is not None else "shared-engine"
     # injected test doubles carry no stream config
-    cfg = getattr(mp if mp is not None else app["pipeline"], "config", None)
+    cfg = getattr(app["pipeline"], "config", None)
     if cfg is not None:
         from ..stream.engine import current_attn_impl
 
@@ -2449,9 +2394,6 @@ async def on_shutdown(app):
         groups = app["state"].get("broadcast_groups", {})
         await asyncio.gather(*[g.close() for g in groups.values()])
         groups.clear()
-    mp = app.get("multipeer_pipeline")
-    if mp is not None:
-        mp.close()
     sched = app.get("batch_scheduler")
     if sched is not None:
         for entry in app.get("imported_sessions", {}).values():
@@ -2476,8 +2418,6 @@ def build_app(
     provider=None,
     controlnet: str | None = None,
     annotator: str | None = None,
-    multipeer: int = 0,
-    multipeer_pipeline=None,
     batch_scheduler=None,
     tp: int = 0,
     sp: int = 0,
@@ -2491,8 +2431,6 @@ def build_app(
     app["controlnet"] = controlnet
     app["annotator"] = annotator
     app["pipeline"] = pipeline  # injectable for tests; built on startup if None
-    app["multipeer"] = multipeer
-    app["multipeer_pipeline"] = multipeer_pipeline  # injectable for tests
     app["batch_scheduler"] = batch_scheduler  # injectable for tests
     app["tp"] = tp
     app["sp"] = sp
@@ -2569,14 +2507,6 @@ def main(argv=None):
         "reference's detector, in-graph, weights from lllyasviel/Annotators)",
     )
     parser.add_argument(
-        "--multipeer",
-        default=0,
-        type=int,
-        metavar="N",
-        help="serve up to N concurrent peers batched on one engine "
-        "(BASELINE configs[4]); 0 = single shared pipeline",
-    )
-    parser.add_argument(
         "--tp",
         default=0,
         type=int,
@@ -2649,7 +2579,6 @@ def main(argv=None):
         udp_ports=args.udp_ports.split(",") if args.udp_ports else None,
         controlnet=args.controlnet,
         annotator=args.annotator,
-        multipeer=args.multipeer,
         tp=args.tp,
         sp=args.sp,
         fbs=args.fbs,

@@ -491,6 +491,45 @@ def make_step_fn(models: StreamModels, cfg: StreamConfig,
     return step
 
 
+def make_bucket_step(vstep, capacity: int, scatter_output: bool = True):
+    """The batch scheduler's step program: gather -> vmapped step ->
+    scatter over the stacked ``[capacity, ...]`` session pytree, as ONE
+    jitted call so the gather and scatter fuse with the step.
+
+    ``vstep(params, states_k, frames_k) -> (new_states_k, out_k)`` is the
+    vmapped :func:`make_step_fn` step; ``idx`` [k] selects which of the
+    ``capacity`` state rows take part.  Duplicate indices (bucket padding)
+    are sound: the duplicated rows compute identical values, so the
+    duplicate scatter writes land identical data.
+
+    ``scatter_output``: False (what ``stream/scheduler.py`` passes) returns
+    the k-shaped output aligned with ``idx`` — the scheduler resolves
+    waiters by batch position, and the zeros+scatter pass measurably taxes
+    small buckets; True returns a full-capacity output indexed by slot id
+    (rows not in ``idx`` are zeros)."""
+
+    def bucket(params, states, frames_k, idx):
+        # the jitted function keeps the name ``bucket``: the trace readers
+        # find the step's program by it (``jit_bucket``)
+        with jax.named_scope("gather"):
+            sub = jax.tree.map(lambda a: jnp.take(a, idx, axis=0), states)
+        new_sub, out = vstep(params, sub, frames_k)
+        with jax.named_scope("scatter"):
+            new_states = jax.tree.map(
+                lambda full, ns: full.at[idx].set(ns), states, new_sub
+            )
+            if not scatter_output:
+                return new_states, out
+            # scatter into a full-capacity output so callers keep indexing
+            # by slot id (rows not in idx are zeros, discarded)
+            full_out = jnp.zeros(
+                (capacity,) + out.shape[1:], out.dtype
+            ).at[idx].set(out)
+        return new_states, full_out
+
+    return bucket
+
+
 def _has_quantized_kernels(tree) -> bool:
     """True when any {kernel_q, scale} pair (models/quant.py) is present."""
     if isinstance(tree, dict):
@@ -564,9 +603,10 @@ def current_fused_epilogue() -> bool:
 
 def stream_engine_key(model_id: str, cfg: StreamConfig, **extra) -> str:
     """Canonical engine-cache key for a (model, stream config) pair — shared
-    by the build CLI, the serving fast path AND the multipeer engine (which
-    adds ``peers=N``), so every graph-changing flag lives in exactly one
-    key recipe (reference cache-key discipline: lib/wrapper.py:732-746)."""
+    by the build CLI, the serving fast path and the batch scheduler (which
+    adds ``sbucket=k, sessions=S``), so every graph-changing flag lives in
+    exactly one key recipe (reference cache-key discipline:
+    lib/wrapper.py:732-746)."""
     from ..aot.cache import engine_key
 
     return engine_key(
@@ -808,10 +848,10 @@ class StreamEngine:
         # submit() ON THIS THREAD resolved via the similarity filter
         # instead of a device step — a plain attribute write (no clock,
         # no env: trace-purity safe) that the pipeline façade turns into
-        # a trace mark.  Thread-local because the engine is shared by
-        # every non-multipeer session: set-then-read happens within one
-        # to_thread hop, and a concurrent session's submit on another
-        # thread must not cross-contaminate the mark
+        # a trace mark.  Thread-local because the shared-engine plane
+        # gives every session this one engine: set-then-read happens
+        # within one to_thread hop, and a concurrent session's submit on
+        # another thread must not cross-contaminate the mark
         self._submit_skip_flag = threading.local()
         # compute-path fault injection (resilience/faults.py): None unless
         # a plan targeting the engine is active — disabled injection costs
@@ -942,8 +982,7 @@ class StreamEngine:
 
         if self.mesh is not None and any(n > 1 for n in self.mesh.shape.values()):
             # serialized executables are per-topology; the tp/sp serving
-            # meshes keep the plain jit path (same policy as
-            # MultiPeerEngine.use_aot_cache)
+            # meshes keep the plain jit path
             return False
         if self.state is None:
             raise RuntimeError("call prepare() first (state defines the signature)")

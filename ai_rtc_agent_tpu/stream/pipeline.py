@@ -299,12 +299,12 @@ class StreamDiffusionPipeline:
 
 
 def finish_output(out, src_frame=None, safety_checker=None, trace=None):
-    """The single home of the output contract every serving plane shares:
-    safety-check the pixels, then wrap pts metadata unless HW_ENCODE
-    serving wants bare ndarrays (stamping the postprocess span when a
-    trace rides along).  Used by the pipelined fetch paths of the batch
-    scheduler (stream/scheduler.py) and --multipeer's PeerPipeline so the
-    contract cannot drift between serving modes."""
+    """The pipeline's output contract for a plane that resolves its own
+    frames: safety-check the pixels, then wrap pts metadata unless
+    HW_ENCODE serving wants bare ndarrays (stamping the postprocess span
+    when a trace rides along).  ``ScheduledSession.fetch``
+    (stream/scheduler.py) ends in it, so a scheduler session returns what
+    ``StreamDiffusionPipeline.fetch`` returns."""
     if safety_checker is not None:
         out = safety_checker(out)
     if src_frame is not None and hasattr(src_frame, "pts") and not env.hw_encode():

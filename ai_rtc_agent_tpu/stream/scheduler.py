@@ -1,14 +1,14 @@
 """Cross-session continuous batching: one jitted step, many sessions.
 
 The stream-batch law (PAPER.md; reference lib/wrapper.py:159-163) buys
-multi-step quality at one UNet pass per frame — but on the default serving
-path that batch axis carries *bubbles*: every non-``--multipeer`` session
+multi-step quality at one UNet pass per frame — but on the shared-engine
+plane (``BATCHSCHED=0``) that batch axis carries *bubbles*: every session
 shares one :class:`StreamEngine` and serializes through its submit lock, so
 N sessions cost N sequential device steps.  This module fills the batch
 axis with other users' frames instead:
 
-* Per-session stream state lives in a **stacked pytree** ``[S, ...]``
-  (the :class:`MultiPeerEngine` slot design, made dynamic): prompt
+* Per-session stream state lives in a **stacked pytree** ``[S, ...]``,
+  one row a slot, claimed and released as sessions come and go: prompt
   embeddings, guidance/delta, stock noise, the latent ring — everything a
   session owns rides as a batched operand, so sessions keep fully
   independent control planes.
@@ -44,8 +44,9 @@ axis with other users' frames instead:
   params serve unchanged (the dequant lives in the layer primitives; the
   AOT keys gain ``quant-w8``), and the DeepCache cadence (``UNET_CACHE``)
   runs as a GLOBAL tick over (k, capture|cached)-keyed bucket executables
-  — the multipeer discipline: any install/prompt/t-index write resets the
-  cadence so a zeroed or stale deep-feature cache is never consumed.
+  — every slot captures on the same tick, and any install/prompt/t-index
+  write resets the cadence so a zeroed or stale deep-feature cache is
+  never consumed.
 * **The session axis spans the mesh** (ISSUE 12, ROADMAP open item 4):
   with ``BATCHSCHED_DP=N`` (or a ``MESH_SHAPE`` dp axis) the stacked
   ``[S, ...]`` pytree shards its leading axis over a dp mesh
@@ -74,10 +75,12 @@ axis with other users' frames instead:
   the session's row; the similarity filter stays fbs==1-only (a skip
   would desync the group boundaries).
 
-Outputs are bit-identical to a dedicated engine per session (pinned by
-tests/test_batch_scheduler.py across join/leave, prompt updates and
-similarity skips): the bucket step applies the SAME pure step function to
-the session's state row that a dedicated engine would apply to its state.
+Outputs match a dedicated engine per session to within one uint8
+quantisation step (pinned by tests/test_batch_scheduler.py across
+join/leave, prompt updates and similarity skips): the bucket step applies
+the SAME pure step function to the session's state row that a dedicated
+engine would apply to its state, and executables of different batch size
+may fuse differently.
 
 Single-session behavior is pass-through-cheap: with one live session the
 dispatcher never waits out the window — the frame dispatches immediately
@@ -99,13 +102,13 @@ import numpy as np
 from ..obs import devtel
 from ..obs.trace import get_trace, hop, safe_list
 from ..ops.pallas import mosaic_kernel_counts
-from ..parallel.multipeer import CapacityError, make_bucket_step
 from ..resilience import faults as _faults
 from ..resilience.overload import DeadlineQueue, ShedFrame
 from ..utils import env
 from .engine import (
     SimilarityFilter,
     StreamEngine,
+    make_bucket_step,
     make_step_fn,
     params_variant_extra,
     stage_frame,
@@ -143,6 +146,11 @@ COUNTED_HOPS = (
 # backpressure = the dispatcher, every live session ready, held back only
 # by the in-flight cap until a batch resolved
 DISPATCH_CAUSES = ("solo", "inline_full", "window", "backpressure")
+
+
+class CapacityError(RuntimeError):
+    """Every session slot is claimed, or the engine is quarantined (maps
+    to HTTP 503 in the agent)."""
 
 
 class SnapshotMismatch(ValueError):
@@ -239,7 +247,7 @@ class ScheduledSession:
     wrapper expect — ``__call__`` / ``submit`` / ``fetch`` /
     ``update_prompt`` / ``update_t_index_list`` / ``update_guidance`` /
     ``restart`` — so the track layer is identical to single-engine
-    serving (the same contract PeerPipeline keeps for ``--multipeer``)."""
+    serving."""
 
     # the scheduler feeds the admission step-EWMA per-batch-amortized
     # latency itself; the resilient wrapper must not double-feed the raw
@@ -676,11 +684,10 @@ class BatchScheduler:
             schedule=schedule, jit_compile=False,
         )
         # DeepCache (UNET_CACHE) rides the scheduler as a GLOBAL cadence
-        # over TWO vmapped graphs per bucket size — the multipeer
-        # discipline: every slot captures on the same tick, installs and
-        # control-plane writes reset the cadence so a zeroed/stale deep
-        # cache is never consumed (sessions stay output-identical to a
-        # dedicated engine stepping the same cadence)
+        # over TWO vmapped graphs per bucket size: every slot captures on
+        # the same tick, installs and control-plane writes reset the
+        # cadence so a zeroed/stale deep cache is never consumed (sessions
+        # follow a dedicated engine stepping the same cadence)
         self._cache_interval = (
             cfg.unet_cache_interval if cfg.unet_cache_interval >= 2 else 0
         )
@@ -797,7 +804,7 @@ class BatchScheduler:
         self._fault_scope = _faults.scope("engine")
         # snapshot bank: per-slot DEVICE-side state rows refreshed on a
         # cadence after successful dispatches.  The bucket steps DONATE the
-        # stacked states (multipeer donate_argnums=(1,)), so at trip time
+        # stacked states (donate_argnums=(1,)), so at trip time
         # self.states is already unreadable — bit-exact restore is only
         # possible from rows banked BEFORE the fault (each x[slot] slice is
         # a fresh buffer the donation cannot invalidate, the
@@ -1295,9 +1302,8 @@ class BatchScheduler:
             )
         if self._cache_interval:
             # the fresh slot's unet_cache row is zeros — make the NEXT
-            # global step a capture (multipeer install() contract) AND
-            # track the slot: if it sits out that batch, its first ride
-            # still forces a capture
+            # global step a capture AND track the slot: if it sits out
+            # that batch, its first ride still forces a capture
             self._tick = 0
             self._uncaptured.add(slot)
 
@@ -1569,9 +1575,8 @@ class BatchScheduler:
     def bucket_keys(self, model_id: str | None = None) -> dict:
         """{(bucket size k, unet variant) -> engine-cache key} — the
         single key recipe shared by serving adoption and the build CLI
-        (``sbucket``/``sessions`` extend the stream key exactly like
-        ``peers`` does for --multipeer; a DeepCache config keys a
-        capture+cached PAIR per bucket, w8-quantized params add
+        (``sbucket``/``sessions`` extend the stream key; a DeepCache
+        config keys a capture+cached PAIR per bucket, w8 params add
         ``quant-w8`` the way ``attn``/``fused`` already ride the key, and
         a dp mesh adds ``dp-N`` via ``aot/cache.mesh_key_extra`` so a
         sharded executable never collides with the single-device slot,
@@ -1618,7 +1623,7 @@ class BatchScheduler:
         geometry).  All-or-nothing: a partial adoption would stall the
         missing occupancy on a lazy compile mid-serve.  dp-sharded
         schedulers are not exported (a serialized program is
-        per-topology — the StreamEngine/MultiPeerEngine mesh policy);
+        per-topology — the StreamEngine mesh policy);
         prewarm_buckets is their no-retrace guarantee instead."""
         if self.dp > 1:
             return False
@@ -2471,7 +2476,7 @@ class BatchScheduler:
 
     # keep up to this many batch steps in flight: step N's readback
     # overlaps step N+1's dispatch (same rationale as the single-engine
-    # submit/fetch pipeline and the multipeer coordinator)
+    # submit/fetch pipeline)
     PIPELINE_DEPTH = 2
 
     def _run(self):

@@ -21,8 +21,8 @@ vs_baseline is against the 30 fps real-time bar (BASELINE.json north_star:
 Weights are random (zero-egress image) — identical FLOPs/shapes to real
 weights, which is what fps depends on.
 
-Flags: --config {turbo512, lcm4x512, sdxl1024, controlnet512, multipeer,
-tiny64} --frames N
+Flags: --config {turbo512, lcm4x512, sdxl1024, controlnet512, tiny64}
+--frames N
 """
 
 from __future__ import annotations
@@ -234,64 +234,12 @@ def _estimate_mfu(eng, frame, fps: float, fbs: int, peak: float):
     return round(flops * (fps / fbs) / peak, 4)
 
 
-def run_bench_multipeer(frames: int, peers: int = 4, pipeline_depth: int = 4,
-                        active: int | None = None, unet_cache: int = 0):
-    """BASELINE configs[4]: N concurrent streams batched on one chip.
-    fps is AGGREGATE (frames/sec across ACTIVE peers).
-
-    ``active < peers`` measures below-capacity occupancy — the active-count
-    bucket path (VERDICT r2 weak #5: a --multipeer 8 agent with 1 peer must
-    pay ~1 peer of step time, not 8; this row proves it on hardware)."""
-    from ai_rtc_agent_tpu.models import registry
-    from ai_rtc_agent_tpu.parallel.multipeer import MultiPeerEngine
-
-    active = peers if active is None else active
-    if not 0 < active <= peers:
-        raise ValueError(f"--active must be in [1, {peers}]")
-    dtype = "bfloat16"
-    model_id = "stabilityai/sd-turbo"
-    bundle = registry.load_model_bundle(model_id)
-    overrides = {}
-    if unet_cache >= 2:
-        overrides["unet_cache_interval"] = unet_cache
-    cfg = registry.default_stream_config(model_id, dtype=dtype, **overrides)
-    bundle.params = registry.cast_params(bundle.params, dtype)
-    eng = MultiPeerEngine(
-        bundle.stream_models, bundle.params, cfg, bundle.encode_prompt,
-        max_peers=peers,
-    ).start("a benchmark prompt")
-    for i in range(active):
-        eng.connect(f"bench peer {i}", seed=i)
-
-    rng = np.random.default_rng(0)
-    batch = rng.integers(0, 256, (peers, cfg.height, cfg.width, 3), dtype=np.uint8)
-    t0 = time.monotonic()
-    for _ in range(3):
-        eng.step_all(batch)
-    logger.info("warm-up (incl. compile): %.1fs", time.monotonic() - t0)
-
-    ticks = max(1, frames // active)
-    r, _ = _pipelined_loop(
-        eng.submit, eng.fetch, lambda i: batch, ticks, pipeline_depth, active
-    )
-    r["peers"] = peers
-    if active != peers:
-        r["active"] = active
-    if cfg.unet_cache_interval >= 2:
-        r["unet_cache"] = cfg.unet_cache_interval  # built config, not flag
-    return r
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", default="turbo512",
                     choices=["turbo512", "lcm4x512", "sdxl1024",
-                             "controlnet512", "multipeer", "tiny64"])
+                             "controlnet512", "tiny64"])
     ap.add_argument("--frames", type=int, default=30)
-    ap.add_argument("--peers", type=int, default=4)
-    ap.add_argument("--active", type=int, default=None,
-                    help="multipeer only: claimed slots (< peers measures "
-                         "the below-capacity bucket path)")
     ap.add_argument("--fbs", type=int, default=1,
                     help="frames per stream-batch step (frame_buffer_size)")
     ap.add_argument("--pipeline-depth", type=int, default=4,
@@ -335,23 +283,16 @@ def main():
     if (os.getenv("QUANT_WEIGHTS") or "").lower() in ("w8", "int8"):
         result["quant"] = "w8"
 
-    if args.config == "multipeer":
-        r = run_bench_multipeer(args.frames, args.peers,
-                                pipeline_depth=args.pipeline_depth,
-                                active=args.active,
-                                unet_cache=args.unet_cache)
-    else:
-        r = run_bench(args.config, args.frames, peak,
-                      pipeline_depth=args.pipeline_depth, fbs=args.fbs,
-                      unet_cache=args.unet_cache)
+    r = run_bench(args.config, args.frames, peak,
+                  pipeline_depth=args.pipeline_depth, fbs=args.fbs,
+                  unet_cache=args.unet_cache)
     result.update(
         value=round(r["fps"], 2),
         vs_baseline=round(r["fps"] / 30.0, 3),
         latency_p50_ms=round(r["latency_p50_ms"], 1),
         latency_p90_ms=round(r["latency_p90_ms"], 1),
     )
-    for extra in ("peers", "active", "stage_ms", "mfu", "unet_cache",
-                  "compile_s"):
+    for extra in ("stage_ms", "mfu", "unet_cache", "compile_s"):
         if r.get(extra) is not None:
             result[extra] = r[extra]
     print(json.dumps(result), flush=True)

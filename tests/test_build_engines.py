@@ -84,13 +84,16 @@ def test_build_deepcache_pair(tmp_path, monkeypatch):
         assert [f for f in os.listdir(d) if f.endswith(".jaxexport")]
 
 
-def test_build_engines_peers_flag(tmp_path, monkeypatch):
-    """--peers N prebuilds the multipeer engine through the serving
-    adoption path (keys can't drift); a fresh MultiPeerEngine then loads
-    without building."""
+def test_build_engines_sched_buckets_flag(tmp_path, monkeypatch):
+    """--sched-buckets S prebuilds the batch scheduler's bucket geometries
+    through the scheduler's own adoption path (keys can't drift): a fresh
+    BatchScheduler then adopts every bucket without building and serves a
+    frame — tier-1's AOT build + adopt round trip."""
+    import numpy as np
+
     from ai_rtc_agent_tpu.assets import build_engines
     from ai_rtc_agent_tpu.models import registry
-    from ai_rtc_agent_tpu.parallel.multipeer import MultiPeerEngine
+    from ai_rtc_agent_tpu.stream.scheduler import BatchScheduler
     from ai_rtc_agent_tpu.utils import device
 
     # main() is a process entry point and places XLA's persistent compile
@@ -99,14 +102,35 @@ def test_build_engines_peers_flag(tmp_path, monkeypatch):
     monkeypatch.setattr(device, "configure_compile_cache", lambda: "off")
     build_engines.main([
         "--model-id", "tiny-test", "--cache-dir", str(tmp_path),
-        "--peers", "2",
+        "--sched-buckets", "2",
     ])
     bundle = registry.load_model_bundle("tiny-test")
     cfg = registry.default_stream_config("tiny-test")
-    mp = MultiPeerEngine(
+    sched = BatchScheduler(
         bundle.stream_models, bundle.params, cfg, bundle.encode_prompt,
-        max_peers=2,
-    ).start("adopt prebuilt")
-    assert mp.use_aot_cache(
-        "tiny-test", cache_dir=str(tmp_path), build_on_miss=False
+        model_id="tiny-test", max_sessions=2, prewarm=False,
+        aot_build_on_miss=False, cache_dir=str(tmp_path), dp=1,
     )
+    try:
+        assert sched._aot_adopted  # ctor adoption found every bucket
+        assert all(
+            sched.aot_status("tiny-test", cache_dir=str(tmp_path)).values()
+        )
+        sess = sched.claim("adopt prebuilt")
+        frame = np.random.default_rng(0).integers(
+            0, 256, (cfg.height, cfg.width, 3), np.uint8
+        )
+        out = sess(frame)
+        assert out.shape == frame.shape and out.dtype == np.uint8
+    finally:
+        sched.close()
+    # another capacity is another key family: nothing to adopt
+    other = BatchScheduler(
+        bundle.stream_models, bundle.params, cfg, bundle.encode_prompt,
+        model_id="tiny-test", max_sessions=4, prewarm=False,
+        aot_build_on_miss=False, cache_dir=str(tmp_path), dp=1,
+    )
+    try:
+        assert not other._aot_adopted
+    finally:
+        other.close()

@@ -208,8 +208,9 @@ def test_cadence_with_frame_batching():
 @pytest.mark.slow  # AOT pair build x fresh-adoption composition (~8s;
 # ISSUE 15 budget pairing): test_engine_cadence_and_flops keeps the
 # cadence pin in tier-1, the scheduler's pair-key discipline rides
-# test_refuses_incompatible_configs, and test_multipeer_aot_cache_
-# roundtrip keeps an AOT build+adopt roundtrip in tier-1
+# test_refuses_incompatible_configs, and
+# test_build_engines_sched_buckets_flag keeps an AOT build+adopt
+# roundtrip in tier-1
 def test_aot_pair_build_and_fresh_adoption(tmp_path):
     """The TRT-engine-cache analog covers DeepCache: build_engines-style
     pair build (capture + cached executables, distinct keys), then a fresh
@@ -240,93 +241,6 @@ def test_aot_pair_build_and_fresh_adoption(tmp_path):
         out = eng2(rng.integers(0, 256, (cfg.height, cfg.width, 3), np.uint8))
         assert np.isfinite(out.astype(np.float64)).all()
     assert eng2._tick == 3
-
-
-@pytest.mark.slow
-def test_multipeer_global_cadence():
-    """Multipeer + DeepCache: one GLOBAL cadence for all slots (the vmapped
-    step applies one graph to every slot anyway); buckets now COMPOSE with
-    the cache (VERDICT r3 item 7); a connect resets the cadence so a fresh
-    slot's zeroed cache is never consumed before its first capture.
-
-    `slow` tier (ISSUE 12 budget satellite, ~15s of capture+cached
-    compiles): the global-cadence semantics keep lighter tier-1 siblings
-    — the engine-level cadence pin (test_engine_cadence_and_flops), the
-    scheduler's uncaptured-rider forcing (test_batch_scheduler) and the
-    equivalence driver's DC leg (bit-exact through the same global-tick
-    discipline this test exercises on the multipeer tier)."""
-    from ai_rtc_agent_tpu.models import registry
-    from ai_rtc_agent_tpu.parallel.multipeer import MultiPeerEngine
-
-    bundle = registry.load_model_bundle("tiny-test")
-    cfg = registry.default_stream_config("tiny-test", unet_cache_interval=3)
-    mp = MultiPeerEngine(
-        bundle.stream_models, bundle.params, cfg, bundle.encode_prompt,
-        max_peers=2,
-    ).start("deepcache peers")
-    assert mp._use_buckets is True  # buckets and the cache compose now
-    mp.connect("peer a")
-    assert mp._tick == 0  # connect resets the cadence
-    rng = np.random.default_rng(0)
-    frames = rng.integers(0, 256, (2, cfg.height, cfg.width, 3), np.uint8)
-    for _ in range(3):
-        out = mp.step_all(frames)
-        assert out.shape == (2, cfg.height, cfg.width, 3)
-        assert np.isfinite(out.astype(np.float64)).all()
-    assert mp._tick == 3
-    mp.connect("peer b")
-    assert mp._tick == 0  # second connect forces a recapture again
-    out = mp.step_all(frames)
-    assert np.isfinite(out.astype(np.float64)).all()
-    # control-plane updates force a global recapture too (same contract as
-    # the single-stream engine)
-    mp.update_prompt(0, "new prompt for a")
-    assert mp._tick == 0
-    mp.step_all(frames)
-    mp.update_t_index(0, list(cfg.t_index_list))
-    assert mp._tick == 0
-
-
-@pytest.mark.slow  # 4 bucket-variant compiles (~15s); the global-cadence
-# multipeer test + the scheduler's EQUIV_DC_OK legs keep the DeepCache
-# composition covered in tier-1
-def test_multipeer_buckets_compose_with_deepcache(monkeypatch):
-    """VERDICT r3 item 7: below-capacity occupancy must keep the bucket
-    FLOPs saving WITH DeepCache — per-bucket (size, variant) pairs, and the
-    bucketed stream's active-slot output equals the unbucketed one's."""
-    from ai_rtc_agent_tpu.models import registry
-    from ai_rtc_agent_tpu.parallel.multipeer import MultiPeerEngine
-
-    bundle = registry.load_model_bundle("tiny-test")
-    cfg = registry.default_stream_config("tiny-test", unet_cache_interval=2)
-
-    def engine():
-        return MultiPeerEngine(
-            bundle.stream_models, bundle.params, cfg, bundle.encode_prompt,
-            max_peers=4,
-        ).start("compose")
-
-    rng = np.random.default_rng(5)
-    frames = rng.integers(0, 256, (4, cfg.height, cfg.width, 3), np.uint8)
-
-    mp = engine()
-    assert mp._use_buckets is True
-    mp.connect("solo peer")
-    outs_bucketed = [mp.step_all(frames)[0] for _ in range(4)]
-    # both cadence variants ran through the BUCKET path at occupancy 1
-    assert (1, "full") in mp._bucket_steps
-    assert (1, "cached") in mp._bucket_steps
-
-    monkeypatch.setenv("MULTIPEER_BUCKETS", "0")
-    mp2 = engine()
-    assert mp2._use_buckets is False
-    mp2.connect("solo peer")
-    outs_full = [mp2.step_all(frames)[0] for _ in range(4)]
-
-    for a, b in zip(outs_bucketed, outs_full):
-        np.testing.assert_allclose(
-            a.astype(np.float64), b.astype(np.float64), atol=1.0
-        )
 
 
 @pytest.mark.slow  # two sharded-mesh x deepcache composition compiles

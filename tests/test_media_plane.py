@@ -297,6 +297,121 @@ def test_agent_native_rtp_real_engine_e2e(native_lib, monkeypatch):
     asyncio.run(go())
 
 
+def test_native_rtp_two_udp_clients_two_scheduler_sessions(
+    native_lib, monkeypatch
+):
+    """Two UDP clients through native-rtp onto the default serving plane:
+    each /offer claims its own scheduler session (two slots, one engine),
+    and each client gets its own processed stream back on its own socket
+    (BASELINE configs[4] end to end on a real wire).  Asserts counts,
+    never timing."""
+    monkeypatch.setenv("WARMUP_FRAMES", "0")
+    monkeypatch.setenv("OVERLOAD_TX_DEADLINE_MS", "0")  # compile is not overload
+    # the admission gate refuses (503) when the event loop looks laggy,
+    # and this test admits TWO sessions back to back on a box running the
+    # whole suite: the lag shield is not what it exercises
+    monkeypatch.setenv("OVERLOAD_LOOP_LAG_BUDGET_MS", "10000")
+    monkeypatch.setenv("BATCHSCHED_MAX_SESSIONS", "2")
+    use_h264 = _h264()
+
+    async def go():
+        provider = NativeRtpProvider(use_h264=use_h264)
+        app = build_app(model_id="tiny-test", provider=provider)
+        client = TestClient(TestServer(app))
+        await client.start_server()  # pipeline + scheduler (k=1, k=2 compile)
+        sched = app["batch_scheduler"]
+        cfg = app["pipeline"].config
+        w, h = cfg.width, cfg.height
+        loop = asyncio.get_event_loop()
+        clients = []
+        try:
+            for n in range(2):
+                q: asyncio.Queue = asyncio.Queue()
+
+                class _Recv(asyncio.DatagramProtocol):
+                    def __init__(self, q=q):
+                        self.q = q
+
+                    def datagram_received(self, data, addr):
+                        self.q.put_nowait(data)
+
+                tr, _ = await loop.create_datagram_endpoint(
+                    _Recv, local_addr=("127.0.0.1", 0)
+                )
+                offer = json.dumps(
+                    {
+                        "native_rtp": True,
+                        "video": True,
+                        "client_addr": [
+                            "127.0.0.1", tr.get_extra_info("sockname")[1]
+                        ],
+                        "width": w,
+                        "height": h,
+                    }
+                )
+                r = await client.post(
+                    "/offer",
+                    json={
+                        "room_id": f"rtp{n}",
+                        "offer": {"sdp": offer, "type": "offer"},
+                    },
+                )
+                assert r.status == 200, await r.text()
+                server_port = json.loads((await r.json())["sdp"])["server_port"]
+                send, _ = await loop.create_datagram_endpoint(
+                    asyncio.DatagramProtocol,
+                    remote_addr=("127.0.0.1", server_port),
+                )
+                clients.append(
+                    dict(
+                        q=q, recv_tr=tr, send=send,
+                        sink=H264Sink(w, h, use_h264=use_h264, ssrc=0x100 + n),
+                        back=H264RingSource(w, h, use_h264=use_h264),
+                        decoded=[],
+                    )
+                )
+            assert sched.free_slots == 0  # one session a client
+            slots = {s["slot"] for s in sched.session_snapshots().values()}
+            assert slots == {0, 1}
+
+            rng = np.random.default_rng(1)
+            for i in range(300):
+                for c in clients:
+                    f = VideoFrame.from_ndarray(
+                        rng.integers(0, 256, (h, w, 3), dtype=np.uint8)
+                    )
+                    f.pts = i * 3000
+                    for pkt in c["sink"].consume(f):
+                        c["send"].sendto(pkt)
+                await asyncio.sleep(0.05)
+                for c in clients:
+                    try:
+                        while True:
+                            c["back"].feed_packet(c["q"].get_nowait())
+                    except asyncio.QueueEmpty:
+                        pass
+                    while (item := c["back"]._ring.pop()) is not None:
+                        c["decoded"].append(item[0])
+                if all(c["decoded"] for c in clients):
+                    break
+            for n, c in enumerate(clients):
+                assert c["decoded"], f"client {n} got no frames back"
+                assert c["decoded"][0].shape == (h, w, 3)
+            # both sessions stepped frames of their own
+            snaps = sched.session_snapshots()
+            assert len(snaps) == 2
+            assert all(s["frames_submitted"] >= 1 for s in snaps.values())
+        finally:
+            for c in clients:
+                c["sink"].close()
+                c["back"].close()
+                c["recv_tr"].close()
+                c["send"].close()
+            await client.close()
+
+    asyncio.run(go())
+
+
 def test_rtp_reorder_buffer_orders_and_recovers():
     """Out-of-order delivery and single-packet loss through the reorder
     stage (real UDP reorders; FU-A assembly needs order)."""

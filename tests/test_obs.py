@@ -408,7 +408,7 @@ def test_snapshot_survives_concurrent_ring_appends():
 
 
 def test_last_submit_was_skip_is_thread_local():
-    """Sessions share ONE engine outside --multipeer: a concurrent
+    """Sessions of the shared-engine plane share ONE engine: a concurrent
     session's submit on another thread must not cross-contaminate this
     thread's similar_skip trace mark."""
     import threading

@@ -169,10 +169,10 @@ def test_capacity_shapes():
 
 
 def test_capacity_slot_exhaustion_is_saturated():
-    """Review finding: a slot-exhausted multipeer box (free_slots=0) with
+    """Review finding: a slot-exhausted box (free_slots=0) with
     pressure under budget and no session cap reported saturated=False —
     an orchestrator routing on the flag would send a session straight
-    into /offer's 'all peer slots in use' 503."""
+    into /offer's 'all ... slots in use' 503."""
     a = AdmissionController()  # no cap, no pressure
     cap = a.capacity(live_sessions=4, free_slots=0)
     assert cap["capacity"] == 0
@@ -769,38 +769,8 @@ def test_fetch_capacity_tolerates_garbled_response(monkeypatch):
     assert worker.fetch_capacity("http://127.0.0.1:1/capacity") is None
 
 
-def test_multipeer_slot_queue_sheds_oldest_as_passthrough():
-    """Bounded per-slot queues: a peer outrunning the batch step gets its
-    oldest frame back as passthrough instead of unbounded queueing."""
-    from concurrent.futures import Future
-
-    from ai_rtc_agent_tpu.server.multipeer_serving import MultiPeerPipeline
-
-    mp = MultiPeerPipeline.__new__(MultiPeerPipeline)  # no engine build
-    mp.queue_bound = 2
-    mp.frames_shed = 0
-    mp._lock = threading.Lock()
-    mp._has_work = threading.Condition(mp._lock)
-    from collections import deque
-
-    mp._queues = [deque(maxlen=2)]
-    frames = [np.full((2, 2, 3), i, np.uint8) for i in range(4)]
-    futs = [mp._enqueue(0, f) for f in frames]
-    assert len(mp._queues[0]) == 2
-    assert mp.frames_shed == 2
-    # the two shed futures resolved as passthrough with their own pixels,
-    # ShedFrame-marked so the wrapper never mistakes them for engine output
-    from ai_rtc_agent_tpu.resilience.overload import ShedFrame
-
-    assert futs[0].done() and isinstance(futs[0].result(), ShedFrame)
-    assert np.array_equal(futs[0].result().frame, frames[0])
-    assert futs[1].done() and np.array_equal(futs[1].result().frame, frames[1])
-    assert not futs[2].done() and not futs[3].done()
-    assert isinstance(futs[2], Future)
-
-
 def test_shed_frames_do_not_feed_admission_ewma():
-    """Review finding: a shed multipeer frame used to resolve its Future
+    """Review finding: a shed frame used to resolve its Future
     with raw source pixels, which the resilience wrapper counted as a
     ~0ms healthy engine step — diluting the step EWMA exactly when the
     shed condition (slow batch steps) was evidence of overload.  The

@@ -26,8 +26,8 @@ def test_make_mesh_shapes():
 
 
 def test_session_axis_rules_and_knobs(monkeypatch):
-    """ISSUE 12 units: the session-axis sharding recipe (shared by the
-    dp scheduler and multipeer) and the MESH_SHAPE/BATCHSCHED_DP knob
+    """ISSUE 12 units: the session-axis sharding recipe (the dp
+    scheduler's) and the MESH_SHAPE/BATCHSCHED_DP knob
     parsing — all compile-free."""
     from ai_rtc_agent_tpu.utils import env
 

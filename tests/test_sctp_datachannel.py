@@ -349,27 +349,27 @@ class TestReviewR5Fixes:
         assert got == ["still alive"]
 
 
-def test_multipeer_per_peer_prompts_over_native_datachannels(native_lib):
-    """--multipeer on the NATIVE secure tier: each peer's datachannel
-    config lands on ITS OWN slot (the per-peer prompt isolation the
-    reference serves through aiortc datachannels, reference
-    agent.py:154-168 + multipeer claim semantics)."""
+def test_per_session_prompts_over_native_datachannels(native_lib):
+    """The batch scheduler on the NATIVE secure tier: each peer's
+    datachannel config lands on ITS OWN scheduler session (the per-peer
+    prompt isolation the reference cannot give: its datachannel handler
+    mutates one global pipeline, reference agent.py:154-168)."""
     from aiohttp.test_utils import TestClient, TestServer
 
     from ai_rtc_agent_tpu.media import native
     from ai_rtc_agent_tpu.server.agent import build_app
     from ai_rtc_agent_tpu.server.rtc_native import NativeRtpProvider
-    from tests.test_multipeer_serving import _FakeMultiPeer
+    from tests.test_batch_scheduler import _FakeScheduler, _StubPipeline
 
-    # the ONE multipeer fake (tests/test_multipeer_serving.py) so a
+    # the ONE scheduler fake (tests/test_batch_scheduler.py) so a
     # claim/release contract change breaks every consumer loudly
-    mp = _FakeMultiPeer(capacity=2)
+    sched = _FakeScheduler(max_sessions=2)
 
     async def go():
         provider = NativeRtpProvider(use_h264=native.h264_available())
         app = build_app(
-            pipeline=None, provider=provider, multipeer=2,
-            multipeer_pipeline=mp,
+            pipeline=_StubPipeline(), provider=provider,
+            batch_scheduler=sched,
         )
         client = TestClient(TestServer(app))
         await client.start_server()
@@ -398,9 +398,10 @@ def test_multipeer_per_peer_prompts_over_native_datachannels(native_lib):
                 await asyncio.sleep(0.1)
                 for peer in peers:
                     await peer.drain_dc(0.05)
-                if all(p.prompt for p in mp.peers):
+                if all(s.prompt for s in sched.claimed):
                     break
-            assert [p.prompt for p in mp.peers] == ["neon fox", "pale moon"]
+            assert [s.prompt for s in sched.claimed] == ["neon fox", "pale moon"]
+            assert sched.prompt is None  # never the global plane
         finally:
             for peer in peers:
                 peer.close()

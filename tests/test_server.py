@@ -89,7 +89,7 @@ def test_config_endpoint_updates_pipeline():
 
 def test_config_guidance_capability_checked_before_mutation():
     """A /config body mixing prompt with guidance against a pipeline that
-    cannot do guidance (multipeer global plane) must apply NOTHING —
+    cannot do guidance (an injected one without the knob) must apply NOTHING —
     a 400 has to mean 'rejected', never 'half-applied'."""
     import pytest
 

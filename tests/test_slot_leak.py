@@ -12,7 +12,7 @@ import threading
 import pytest
 from aiohttp.test_utils import TestClient, TestServer
 
-from ai_rtc_agent_tpu.parallel.multipeer import CapacityError
+from ai_rtc_agent_tpu.resilience.overload import DeadlineQueue
 from ai_rtc_agent_tpu.server.agent import build_app
 from ai_rtc_agent_tpu.server.signaling import (
     LoopbackPeerConnection,
@@ -20,11 +20,14 @@ from ai_rtc_agent_tpu.server.signaling import (
     SessionDescription,
     make_loopback_offer,
 )
+from ai_rtc_agent_tpu.stream.scheduler import CapacityError
 
 
-class FakePeer:
-    def __init__(self, owner):
+class FakeSession:
+    def __init__(self, owner, session_key):
         self._owner = owner
+        self.session_key = session_key
+        self.window_queue = DeadlineQueue(2)
         self._released = False
 
     def release(self):
@@ -38,22 +41,23 @@ class FakePeer:
         return frame
 
 
-class FakeSlotPipeline:
-    """Claim/release ledger standing in for MultiPeerPipeline."""
+class FakeScheduler:
+    """Claim/release ledger standing in for BatchScheduler."""
 
     def __init__(self, slots=1):
         self.slots = slots
         self.free = slots
         self.claims = 0
+        self.on_step = None
         self._lock = threading.Lock()
 
-    def claim(self):
+    def claim(self, session_key=None):
         with self._lock:
             if self.free == 0:
                 raise CapacityError("full")
             self.free -= 1
             self.claims += 1
-        return FakePeer(self)
+        return FakeSession(self, session_key)
 
     @property
     def free_slots(self):
@@ -64,9 +68,12 @@ class FakeSlotPipeline:
 
 
 def _app(provider=None, slots=1):
-    fake = FakeSlotPipeline(slots)
+    fake = FakeScheduler(slots)
+    # a stub pipeline so startup builds no model; with a scheduler present
+    # the claim path never touches it
     app = build_app(
-        provider=provider or LoopbackProvider(), multipeer_pipeline=fake
+        pipeline=lambda frame: frame,
+        provider=provider or LoopbackProvider(), batch_scheduler=fake,
     )
     return app, fake
 
@@ -81,8 +88,8 @@ async def _assert_slot_free_and_claimable(client, fake):
     ledger directly, since several scenarios leave the provider itself
     deliberately broken."""
     assert fake.free == fake.slots, "slot leaked"
-    peer = fake.claim()  # would raise CapacityError on a leak
-    peer.release()
+    sess = fake.claim()  # would raise CapacityError on a leak
+    sess.release()
 
 
 def _run(provider, drive):
