@@ -9,6 +9,9 @@ on the CPU must not quietly interpret its kernels instead.
 
 from __future__ import annotations
 
+import collections
+import contextlib
+import contextvars
 import functools
 import logging
 
@@ -57,3 +60,32 @@ def mosaic_kernel_counts(hlo_text: str) -> dict:
         name = next((k for k in KERNEL_NAMES if k in op_name), "unnamed")
         counts[name] = counts.get(name, 0) + 1
     return counts
+
+
+# who is counting the attention calls traced right now
+_ATTENTION_PATHS: contextvars.ContextVar = contextvars.ContextVar(
+    "attention_paths", default=None
+)
+
+
+@contextlib.contextmanager
+def count_attention_paths():
+    """-> a Counter of the ``flash_attention`` calls traced inside the
+    block, by the layout their operands took: ``packed`` (``[B, L, H*D]``,
+    as the projections leave them) or ``per_head`` (transposed to a head a
+    program).  A jitted function's Python body runs when it is traced, so
+    wrap its ``lower()``: the evidence, beside ``mosaic_kernel_counts``, of
+    which kernel variant a compiled step holds."""
+    counts = collections.Counter()
+    token = _ATTENTION_PATHS.set(counts)
+    try:
+        yield counts
+    finally:
+        _ATTENTION_PATHS.reset(token)
+
+
+def note_attention_path(path: str) -> None:
+    """One ``flash_attention`` call traced down ``path``, for whoever counts."""
+    counts = _ATTENTION_PATHS.get()
+    if counts is not None:
+        counts[path] += 1

@@ -2329,6 +2329,7 @@ def _serving_info(app) -> dict:
     kernels = getattr(sched, "mosaic_kernels", None)
     if kernels is not None:
         info["mosaic_kernels"] = dict(kernels)
+        info["attention_paths"] = dict(sched.attention_paths)
     return info
 
 

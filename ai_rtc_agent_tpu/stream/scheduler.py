@@ -101,7 +101,7 @@ import numpy as np
 
 from ..obs import devtel
 from ..obs.trace import get_trace, hop, safe_list
-from ..ops.pallas import mosaic_kernel_counts
+from ..ops.pallas import count_attention_paths, mosaic_kernel_counts
 from ..resilience import faults as _faults
 from ..resilience.overload import DeadlineQueue, ShedFrame
 from ..utils import env
@@ -726,6 +726,9 @@ class BatchScheduler:
         # HLO}; filled by prewarm_buckets (an AOT-adopted or lazily
         # compiled bucket has no compiled object to read, and no entry)
         self.mosaic_kernels: dict = {}
+        # bucket label -> {"packed" | "per_head": flash_attention calls
+        # traced into it with that operand layout} (ops/pallas/attention.py)
+        self.attention_paths: dict = {}
         self.active = [False] * S
         self._sessions: dict = {}  # slot -> ScheduledSession
         self._queues = [
@@ -1676,21 +1679,24 @@ class BatchScheduler:
                 # (_step_batch_locked) keeps breach semantics
                 label = self._bucket_label(k, v)
                 with devtel.compile_scope(label, expected=True):
-                    compiled = (
-                        self._bucket_step(k, v)
-                        .lower(params_s, states_s, frames_s, idx_s)
-                        .compile()
-                    )
+                    with count_attention_paths() as paths:
+                        lowered = self._bucket_step(k, v).lower(
+                            params_s, states_s, frames_s, idx_s
+                        )
+                    compiled = lowered.compile()
                 # what the executable that will serve really contains:
                 # /health reports it, chip_smoke.py asserts on it
                 self.mosaic_kernels[label] = mosaic_kernel_counts(
                     compiled.as_text()
                 )
+                self.attention_paths[label] = dict(paths)
                 self._bucket_steps[(k, v)] = compiled
                 self._warmed_buckets.add((k, v))
                 logger.info(
-                    "prewarmed batchsched bucket %d/%d (%s, dp=%d)",
+                    "prewarmed batchsched bucket %d/%d (%s, dp=%d): "
+                    "kernels %s, attention paths %s",
                     k, self.max_sessions, v, self.dp,
+                    self.mosaic_kernels[label], self.attention_paths[label],
                 )
 
     def compiled_text(self) -> dict:
@@ -2690,6 +2696,7 @@ class BatchScheduler:
             "batchsched_dispatch_starved_total": self._starved_n,
             "batchsched_h2d_bytes_total": self._h2d_bytes,
             "batchsched_d2h_bytes_total": self._d2h_bytes,
+            "batchsched_attention_paths": dict(self.attention_paths),
         }
         if self._adapter_rank:
             # style-adapter plane (adapters/): live sessions riding a

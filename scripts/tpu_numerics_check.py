@@ -14,10 +14,16 @@ kernel takes whole as one block, so it runs in the kernel):
 * SD2.1 (sd-turbo, 512x512): L 4096/1024/256/64, heads 5/10/20/20, d 64
 * SD1.5 4-stage stream batch (B=4): L 4096/1024/256/64, heads 8,
   d 40/80/160/160, self and cross at every tier
-* SDXL (1024x1024): 4096 x 10 heads, 1024 x 20 heads, 77 keys of context
+* SDXL (1024x1024): 4096 x 10 heads, 1024 x 20 heads, 77 keys of context;
+  SDXL-Turbo at 512x512 (``sdxlturbo512``): 256 x 20 heads, self and cross,
+  under ``vmap`` k=1 as its bucket step runs 120 of its 140 calls
 * one self-attention shape under ``vmap`` k=2 (the scheduler's bucket step)
 * the fused epilogue at B=1 ``none`` (64x64 and 128x128 latents), at B=4
   ``self`` (the reference's default stream batch), and under ``vmap`` k=2
+
+Each attention line names the operand layout the call's shapes chose
+(``ops/pallas/attention.py``): ``packed`` (``[B, L, H*D]``, a group of heads
+a program; every head dim 64 call with an even head count) or ``per_head``.
 
 ``--tiny`` swaps in small shapes so the same code runs on the CPU in
 interpret mode (``JAX_PLATFORMS=cpu``) in seconds.  Prints one line per
@@ -62,6 +68,8 @@ ATTN_CASES = {
     "sdxl_self_4096x10x64": ((1, 4096, 10, 64), (1, 4096, 10, 64), 0),
     "sdxl_self_1024x20x64": ((1, 1024, 20, 64), (1, 1024, 20, 64), 0),
     "sdxl_cross_1024x20x64_k77": ((1, 1024, 20, 64), (1, 77, 20, 64), 0),
+    "sdxlturbo_self_256x20x64_vmap1": ((1, 256, 20, 64), (1, 256, 20, 64), 1),
+    "sdxlturbo_cross_256x20x64_k77_vmap1": ((1, 256, 20, 64), (1, 77, 20, 64), 1),
     "sd21_self_4096x5x64_vmap2": ((1, 4096, 5, 64), (1, 4096, 5, 64), 2),
 }
 ATTN_CASES_TINY = {
@@ -93,6 +101,7 @@ def check_attention(cases: dict) -> dict:
     import jax
     import jax.numpy as jnp
 
+    from ai_rtc_agent_tpu.ops.pallas import count_attention_paths
     from ai_rtc_agent_tpu.ops.pallas.attention import (
         _xla_attention,
         flash_attention,
@@ -117,13 +126,15 @@ def check_attention(cases: dict) -> dict:
         if vk:
             kernel, ref = jax.vmap(kernel), jax.vmap(ref)
         t0 = time.monotonic()
-        got = np.asarray(jax.jit(kernel)(q, k, v)).astype(np.float32)
+        with count_attention_paths() as paths:
+            got = np.asarray(jax.jit(kernel)(q, k, v)).astype(np.float32)
+        (path,) = paths  # one call traced, down one path
         want = np.asarray(jax.jit(ref)(q, k, v))
         diff = float(np.max(np.abs(got - want)))
         ok = bool(np.isfinite(got).all() and diff < ATTN_ATOL)
-        out[name] = {"ok": ok, "max_abs_diff": round(diff, 6)}
+        out[name] = {"ok": ok, "max_abs_diff": round(diff, 6), "path": path}
         print(
-            f"attention {name}: max|d|={diff:.5f} tol={ATTN_ATOL} "
+            f"attention {name}: {path} max|d|={diff:.5f} tol={ATTN_ATOL} "
             f"{'ok' if ok else 'FAIL'} ({time.monotonic() - t0:.1f}s)",
             flush=True,
         )

@@ -6,8 +6,10 @@ refuses (the B>1 epilogue block shape was one: "last two dimensions of your
 block shape [must be] divisible by 8 and 128") fails in the sandbox first.
 These are compile facts, not speeds and not numerics: parity on the chip is
 ``scripts/tpu_numerics_check.py`` (the kernel phase of ``chip_smoke.py``).
-Kernel-only compiles, about a second each.
+Kernel-only compiles and single attention blocks, about a second each.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -15,7 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from ai_rtc_agent_tpu.ops.lcm import StepCoeffs
-from ai_rtc_agent_tpu.ops.pallas import mosaic_kernel_counts
+from ai_rtc_agent_tpu.ops.pallas import count_attention_paths, mosaic_kernel_counts
 from ai_rtc_agent_tpu.ops.pallas.attention import flash_attention
 from ai_rtc_agent_tpu.ops.pallas.fused_scheduler import fused_stream_epilogue
 
@@ -34,20 +36,26 @@ def v5e():
 
 
 def _served_attention_shapes():
-    """Every distinct (B, lq, lk, heads, head_dim) the two benchmark
-    configurations reach: four tiers x self / 77-key cross x SD2.1 (sd-turbo:
-    B=1, head dim 64) / SD1.5 (4-stage stream batch: B=4, 8 heads), each
-    under ``vmap`` k=1 as the bucket step runs it, and one at k=2."""
+    """Every distinct (B, lq, lk, heads, head_dim) the three benchmark
+    configurations reach: the tiers x self / 77-key cross of SD2.1
+    (``turbo512``: B=1, head dim 64), SD1.5 (``lcm4x512``: 4-stage stream
+    batch, B=4, 8 heads) and SDXL (``sdxlturbo512``: B=1, head dim 64, the
+    1024- and 256-token tiers, which the kernel sees as SD2.1's middle two:
+    its 2048-wide context is the block test's), each under ``vmap`` k=1 as
+    the bucket step runs it, and one at k=2."""
     tiers = [
-        # (B, heads, head_dim) per tier, tokens 4096 / 1024 / 256 / 64
-        ((1, 5, 64), (1, 10, 64), (1, 20, 64), (1, 20, 64)),
-        ((4, 8, 40), (4, 8, 80), (4, 8, 160), (4, 8, 160)),
+        # (tokens, B, heads, head_dim) per tier
+        ((4096, 1, 5, 64), (1024, 1, 10, 64), (256, 1, 20, 64), (64, 1, 20, 64)),
+        ((4096, 4, 8, 40), (1024, 4, 8, 80), (256, 4, 8, 160), (64, 4, 8, 160)),
+        ((1024, 1, 10, 64), (256, 1, 20, 64)),
     ]
     cases = []
     for config in tiers:
-        for tokens, (b, h, d) in zip((4096, 1024, 256, 64), config):
+        for tokens, b, h, d in config:
             for lk in (tokens, 77):
-                cases.append(((b, tokens, h, d), (b, lk, h, d), 1))
+                case = ((b, tokens, h, d), (b, lk, h, d), 1)
+                if case not in cases:
+                    cases.append(case)
     cases.append(((1, 4096, 5, 64), (1, 4096, 5, 64), 2))
     return cases
 
@@ -62,6 +70,79 @@ def test_flash_attention_compiles_for_v5e(v5e, q_shape, kv_shape, vmap_k):
         v5e(kv_shape, jnp.bfloat16),
     ).compile()
     assert mosaic_kernel_counts(compiled.as_text()) == {"flash_attention": 1}
+
+
+# (B, tokens, heads, head_dim, context width or None for self-attention): the
+# attention blocks whose kernel takes its operands packed, per configuration
+_PACKED_BLOCKS = [
+    # turbo512 (SD2.1, OpenCLIP-H context): all but the 5-head tier
+    (1, 1024, 10, 64, None), (1, 1024, 10, 64, 1024),
+    (1, 256, 20, 64, None), (1, 256, 20, 64, 1024),
+    (1, 64, 20, 64, None), (1, 64, 20, 64, 1024),
+    # sdxlturbo512: all 140 calls of its step are one of these four
+    (1, 1024, 10, 64, 2048), (1, 256, 20, 64, 2048),
+    # lcm4x512 (SD1.5, CLIP-L context): head dims 80 (self) and 160
+    (4, 1024, 8, 80, None), (4, 256, 8, 160, None), (4, 256, 8, 160, 768),
+    (4, 64, 8, 160, None), (4, 64, 8, 160, 768),
+]
+
+
+@pytest.mark.parametrize("b,tokens,heads,head_dim,context_dim", _PACKED_BLOCKS)
+def test_attention_block_compiles_for_v5e_without_relayout_copies(
+    v5e, monkeypatch, b, tokens, heads, head_dim, context_dim
+):
+    """ISSUE 32: the guard on what surrounds the kernel.  One whole
+    ``models/layers.py attention()`` block, weights as jit arguments and
+    ``vmap`` k=1 as ``bucket(params, ...)`` has them, compiled for the chip.
+    While the kernel asked for ``[B*H, L, D]``, XLA folded that transpose
+    into the projections by copying each projection's weight into the
+    transposed layout on every step (a jit argument's layout is fixed), and
+    copied the output back to ``[L, H*D]``: 4 ``copy`` ops a block, 560 a
+    ``sdxlturbo512`` step.  With the operands packed there is one Mosaic
+    call and no ``copy`` of a weight's shape or of the ``[.., L, H, D]``
+    output's."""
+    import ai_rtc_agent_tpu.ops.pallas.attention as A
+    from ai_rtc_agent_tpu.models.layers import attention
+
+    # the program asks the backend whether to interpret its kernels; this
+    # process sees a CPU, the compile below is for the chip
+    monkeypatch.setattr(A, "interpret_default", lambda: False)
+    inner = heads * head_dim
+    kv_in = context_dim or inner
+    weights = {
+        "to_q": {"kernel": v5e((inner, inner), jnp.bfloat16)},
+        "to_k": {"kernel": v5e((kv_in, inner), jnp.bfloat16)},
+        "to_v": {"kernel": v5e((kv_in, inner), jnp.bfloat16)},
+        "to_out": {
+            "kernel": v5e((inner, inner), jnp.bfloat16),
+            "bias": v5e((inner,), jnp.bfloat16),
+        },
+    }
+    x = v5e((1, b, tokens, inner), jnp.bfloat16)
+    context = v5e((1, b, 77, context_dim), jnp.bfloat16) if context_dim else None
+
+    def block(p, x, context):
+        one = lambda x, c: attention(p, x, c, heads, attn_impl="pallas")
+        if context is None:
+            return jax.vmap(lambda x: one(x, None))(x)
+        return jax.vmap(one)(x, context)
+
+    with count_attention_paths() as paths:
+        lowered = jax.jit(block).lower(weights, x, context)
+    assert dict(paths) == {"packed": 1}
+    text = lowered.compile().as_text()
+    assert mosaic_kernel_counts(text) == {"flash_attention": 1}
+    copied = [
+        tuple(int(n) for n in dims.split(",") if n != "1")
+        for dims in re.findall(r"= \w+\[([\d,]*)\]\S* copy\(", text)
+    ]
+    forbidden = {(inner, inner), (kv_in, inner)}
+    token_major = sorted(n for n in (b, tokens, heads, head_dim) if n != 1)
+    relayouts = [
+        dims for dims in copied
+        if dims in forbidden or sorted(dims) == token_major
+    ]
+    assert not relayouts, relayouts
 
 
 @pytest.mark.parametrize(

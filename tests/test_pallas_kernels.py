@@ -9,6 +9,7 @@ from ai_rtc_agent_tpu.ops import lcm as L
 from ai_rtc_agent_tpu.ops import rcfg as R
 from ai_rtc_agent_tpu.ops import schedule as S
 from ai_rtc_agent_tpu.ops.pallas import attention as PA
+from ai_rtc_agent_tpu.ops.pallas import count_attention_paths
 from ai_rtc_agent_tpu.ops.pallas import fused_scheduler as FS
 
 
@@ -102,24 +103,116 @@ def test_flash_attention_f32_caller_keeps_f32(rng, lk):
 
 
 @pytest.mark.parametrize(
-    "lq,lk,head_dim,itemsize,want",
+    "lq,lk,heads,head_dim,itemsize,want",
     [
-        # what the chip sweep of PR 26 picked, per served shape
-        (4096, 4096, 64, 2, (512, 1024)),
-        (4096, 4096, 40, 2, (512, 1024)),
-        (1024, 1024, 80, 2, (1024, 1024)),
-        (256, 256, 160, 2, (256, 256)),
-        (64, 64, 160, 2, (64, 64)),
-        (4096, 77, 64, 2, (4096, 77)),
-        (64, 77, 160, 2, (64, 77)),
+        # what the chip sweep of PR 26 picked, per served shape, for a head
+        # a program: kept (group 0) for 5 heads, which no pair divides,
+        (4096, 4096, 5, 64, 2, (0, 512, 1024)),
+        (4096, 77, 5, 64, 2, (0, 4096, 77)),
+        # where all 8 heads' K and V do not fit a program,
+        (4096, 4096, 8, 40, 2, (0, 512, 1024)),
+        # and for heads astride lane tiles with over four queries a key
+        (4096, 77, 8, 40, 2, (0, 4096, 77)),
+        (1024, 77, 8, 80, 2, (0, 1024, 77)),
+        # packed (PR 32): the fewest heads that fill whole 128-lane tiles
+        (1024, 1024, 10, 64, 2, (2, 1024, 1024)),
+        (1024, 77, 10, 64, 2, (2, 1024, 77)),
+        (256, 256, 20, 64, 2, (2, 256, 256)),
+        (256, 77, 20, 64, 2, (2, 256, 77)),
+        (64, 77, 20, 64, 2, (2, 64, 77)),
+        (1024, 1024, 8, 80, 2, (8, 256, 1024)),
+        (256, 256, 8, 160, 2, (4, 256, 256)),
+        (64, 77, 8, 160, 2, (4, 64, 77)),
+        # two heads' unrolled K loops: half the queries a tile
+        (4096, 4096, 10, 64, 2, (2, 256, 1024)),
+        # one head of 128 is a group of its own
+        (1024, 1024, 4, 128, 2, (1, 1024, 1024)),
         # float32 operands: half the queries a tile
-        (4096, 4096, 64, 4, (256, 1024)),
+        (4096, 4096, 1, 64, 4, (1, 256, 1024)),
         # 3 x 512 keys: the largest power of two that divides them
-        (4096, 1536, 64, 2, (1024, 512)),
+        (4096, 1536, 1, 64, 2, (1, 1024, 512)),
     ],
 )
-def test_choose_blocks(lq, lk, head_dim, itemsize, want):
-    assert PA._choose_blocks(lq, lk, head_dim, itemsize) == want
+def test_choose_blocks(lq, lk, heads, head_dim, itemsize, want):
+    assert PA._choose_blocks(lq, lk, heads, head_dim, itemsize) == want
+
+
+@pytest.mark.parametrize("vmapped", [False, True], ids=["plain", "vmap"])
+@pytest.mark.parametrize("batch", [1, 4])
+@pytest.mark.parametrize("lk", [77, None], ids=["cross77", "self"])
+@pytest.mark.parametrize(
+    "heads,head_dim,lq,group",
+    [(10, 64, 256, 2), (20, 64, 64, 2), (10, 64, 100, 2), (8, 160, 64, 4),
+     (8, 80, 128, 8), (8, 40, 128, 8), (5, 64, 256, 5)],
+)
+def test_packed_path_is_bit_equal_to_per_head(
+    monkeypatch, heads, head_dim, lq, group, lk, batch, vmapped
+):
+    """ISSUE 32: the same arithmetic on the same values, met in another
+    layout.  Operands as ``[B, L, H*D]``, a group of heads a program (a
+    pair at head dim 64; 4 at 160; all 8 at 80 and 40), against a head a
+    program on transposed operands: equal bit for bit, in bf16 as served,
+    under ``vmap`` as the bucket step runs it, and with an ``lq`` no block
+    divides (100 rows in blocks of 64).  Five heads of 64 stay a head a
+    program by the rule; asked for all five together, they agree too."""
+    lk = lk or lq
+    keys = jax.random.split(jax.random.PRNGKey(heads * head_dim + lq + lk), 3)
+    q = jax.random.normal(keys[0], (batch, lq, heads, head_dim), jnp.bfloat16)
+    k = jax.random.normal(keys[1], (batch, lk, heads, head_dim), jnp.bfloat16)
+    v = jax.random.normal(keys[2], (batch, lk, heads, head_dim), jnp.bfloat16)
+    kw = {"block_q": 64} if lq == 100 else {}
+    rule = PA._choose_blocks
+    assert rule(lq, lk, heads, head_dim, 2)[0] == (group if heads != 5 else 0)
+
+    def run(group):
+        # the same key blocks both ways; rows of q are independent
+        monkeypatch.setattr(
+            PA, "_choose_blocks", lambda *shape: (group,) + rule(*shape)[1:]
+        )
+        fn = lambda q, k, v: PA.flash_attention(q, k, v, interpret=True, **kw)
+        with count_attention_paths() as paths:
+            if vmapped:
+                out = jax.vmap(fn)(q[None], k[None], v[None])[0]
+            else:
+                out = fn(q, k, v)
+        return out, dict(paths)
+
+    packed, paths = run(group)
+    assert paths == {"packed": 1}
+    per_head, paths = run(0)
+    assert paths == {"per_head": 1}
+    assert packed.shape == q.shape and packed.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(
+        np.asarray(packed.astype(jnp.float32)),
+        np.asarray(per_head.astype(jnp.float32)),
+    )
+    want = PA._xla_attention(*(a.astype(jnp.float32) for a in (q, k, v)))
+    diff = np.max(np.abs(np.asarray(packed.astype(jnp.float32)) - np.asarray(want)))
+    assert diff < ATTN_ATOL, diff
+
+
+def test_attention_path_counter_counts_traced_calls_by_layout():
+    """The counter ``BatchScheduler`` reports per bucket: one count a call
+    site traced inside the block, by the path the call's shapes chose;
+    nothing outside a block, nothing for a cached trace, nothing for the
+    ragged-``lk`` fall-back (no Mosaic call)."""
+    def attend(heads, head_dim, lk, **kw):
+        q = jnp.zeros((1, 64, heads, head_dim), jnp.bfloat16)
+        kv = jnp.zeros((1, lk, heads, head_dim), jnp.bfloat16)
+        return PA.flash_attention(q, kv, kv, interpret=True, **kw)
+
+    step = jax.jit(
+        lambda: (attend(20, 64, 64), attend(8, 160, 77), attend(5, 64, 77))
+    )
+    with count_attention_paths() as paths:
+        step.lower()
+        attend(2, 16, 10, block_k=8)  # 10 keys in blocks of 8: plain XLA
+    assert dict(paths) == {"packed": 2, "per_head": 1}
+    with count_attention_paths() as again:
+        step.lower()  # the trace is cached: the Python body does not run
+    assert dict(again) == {}
+    attend(20, 64, 64)  # nobody counting
+    assert dict(paths) == {"packed": 2, "per_head": 1}
 
 
 def test_flash_attention_ragged_falls_back(rng):
