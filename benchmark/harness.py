@@ -87,7 +87,11 @@ class Benchmark:
         return self._metrics_for("end_to_end", cell)
 
     def per_layer(self, cell: dict) -> list:
-        return self._metrics_for("per_layer", cell)
+        """The cell's per-layer metrics: those that list it or list no cell,
+        and whose ``moves`` the cell reports (a metric that moves a tail is
+        not read in a cell that is not judged on the tail)."""
+        reported = {m["name"] for m in self.end_to_end(cell)}
+        return [m for m in self._metrics_for("per_layer", cell) if m["moves"] in reported]
 
     def _load(self, kind: str, name: str, what: str):
         """The module ``<home>/<kind>/<name>.py``, loaded from its file under

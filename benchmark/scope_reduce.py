@@ -36,10 +36,9 @@ import re
 from collections import Counter
 
 from .trace_reduce import (
-    MODULES_LINE, OPS_LINE, WINDOW_SPAN, _DEVICE_PLANE, gaps,
+    MODULES_LINE, OPS_LINE, STEP_MODULE, WINDOW_SPAN, _DEVICE_PLANE, gaps,
 )
 
-STEP_MODULE = "bucket"   # as the readers of step_device_ms and step_mfu
 HOP_PREFIX = "rtc:"
 # spans in which a thread is parked on the device or on the window: such a
 # thread cannot have fed the chip, so a gap is put down to one of them only

@@ -23,7 +23,7 @@ from dataclasses import dataclass, field
 import jax
 import numpy as np
 
-from .source import PacedSource
+from .source import PacedSource, session_starts
 
 logger = logging.getLogger("benchmark")
 
@@ -75,14 +75,16 @@ class SpanLog:
 
 class SpannedSession:
     """The benchmark's spans around the calls the track makes into a
-    session.  Adds nothing else: every call goes to the session as is."""
+    session.  Adds nothing else: every call goes to the session as is, and
+    so does every attribute this wrapper does not define
+    (``frame_buffer_size``, ``note_pull_wait``: the track looks them up on
+    what it is given)."""
 
     def __init__(self, inner, log: SpanLog):
         self._inner, self._log = inner, log
 
-    @property
-    def frame_buffer_size(self) -> int:
-        return self._inner.frame_buffer_size
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
 
     def submit(self, frame):
         t0 = time.monotonic()
@@ -215,6 +217,31 @@ class WindowResult:
         after = c1.get("batchsched_occupancy_hist", {})
         return {int(k): v - before.get(k, 0) for k, v in after.items()}
 
+    def counters_window(self) -> dict:
+        """What the program counted over the window: steps by riders,
+        dispatch causes, batches in flight at a dispatch, starved steps, the
+        hops' milliseconds and counts (``hold``, ``launch`` ...)."""
+        return counters_delta(self.counters_open, self.counters_close)
+
+
+def counters_delta(c0: dict, c1: dict) -> dict:
+    """Later snapshot minus earlier, for every cumulative counter of
+    ``BatchScheduler.snapshot()`` (numbers and dicts of numbers whose name
+    says total, count or hist); a maximum, or a percentile of the program's
+    reservoir of waits, is the later snapshot's."""
+    out = {}
+    for key, after in c1.items():
+        before = c0.get(key)
+        if key.endswith("_max") or "_wait_ms_p" in key:
+            out[key] = after
+        elif not key.endswith(("_total", "_count", "_hist")):
+            continue
+        elif isinstance(after, dict):
+            out[key] = {k: v - (before or {}).get(k, 0) for k, v in after.items()}
+        else:
+            out[key] = after - (before or 0)
+    return out
+
 
 def memory_peak() -> int:
     """The process's peak on its fullest chip so far: it never falls."""
@@ -289,8 +316,11 @@ async def _drive(sched, stream_cfg, traffic: dict, seed: int, seconds: float,
             logs.append(log)
             sinks.append(Sink(log, track, src, rng, stateful))
         t_claimed = time.monotonic()
-        for src in sources:
-            src.start()
+        starts = session_starts(
+            t_claimed, n, traffic["source_fps"], traffic.get("phase", "aligned")
+        )
+        for src, at in zip(sources, starts):
+            src.start(at)
 
         async def prime(sink):
             for _ in range(PRIME_RECVS):

@@ -41,6 +41,21 @@ def frame_at(texture: np.ndarray, k: int, height: int, width: int) -> np.ndarray
     return np.ascontiguousarray(texture[oy:oy + height, ox:ox + width])
 
 
+PHASES = ("aligned", "staggered")
+
+
+def session_starts(t0: float, sessions: int, fps: float, phase: str = "aligned") -> list:
+    """When frame 0 of each session is due (the traffic file's ``phase``).
+    ``aligned``: every schedule starts at the one instant ``t0``, so frame k
+    of every session comes due together.  ``staggered``: session i starts
+    ``i / (sessions * fps)`` later, so the sessions' frames come due evenly
+    spread over one source period.  With one session both are ``[t0]``."""
+    if phase not in PHASES:
+        raise ValueError(f"traffic phase {phase!r}: one of {PHASES}")
+    step = 1.0 / (sessions * fps) if phase == "staggered" else 0.0
+    return [t0 + i * step for i in range(sessions)]
+
+
 class PacedSource:
     """``await recv()`` -> uint8 frame; duck-types the track a
     ``VideoStreamTrack`` pulls from.  All times are ``time.monotonic()``."""
@@ -68,9 +83,10 @@ class PacedSource:
     def due_time(self, k: int) -> float:
         return self.t0 + k / self.fps
 
-    def start(self):
-        """Frame 0 is due now."""
-        self.t0 = self._clock()
+    def start(self, at: float | None = None):
+        """Frame 0 is due now, or at ``at`` on the source's clock: the
+        offset that sets this session's phase against the others'."""
+        self.t0 = self._clock() if at is None else at
         self._pacer = asyncio.get_running_loop().create_task(self._pace())
 
     async def stop(self):

@@ -37,6 +37,11 @@ def tiny_spec() -> dict:
          "why": "two sessions, CPU test"}
         for n in TINY_CONFIGS
     ]
+    # the tiny cells are judged on every end-to-end metric, also on one
+    # that lists the real cells it is judged in
+    spec["end_to_end"] = [
+        {k: v for k, v in m.items() if k != "workloads"} for m in spec["end_to_end"]
+    ]
     return spec
 
 

@@ -147,7 +147,9 @@ def test_new_pieces_are_added_by_files_and_entries_alone(tmp_path):
     assert len(flops.attention_calls(cfg)) == 2 * (2 + 2 + 4)  # depth 2: down, mid, up x 2
     assert bench.traffic(cell)["source_fps"] == 5
     names = [m["name"] for m in bench.per_layer(cell)]
-    assert names[-1] == "dummy_count" and len(names) == len(spec["per_layer"])
+    # every metric that lists no cells of its own, and the one that lists this cell
+    assert names[-1] == "dummy_count" and names[:-1] == [
+        m["name"] for m in spec["per_layer"] if "workloads" not in m]
     # the old cells do not see the new metric, which lists its own cells
     assert "dummy_count" not in [m["name"] for m in bench.per_layer(bench.cell("tiny64.duo20"))]
 
@@ -167,10 +169,44 @@ def test_the_real_benchmark_json_finds_all_its_pieces():
         cfg, traffic = bench.config(cell), bench.traffic(cell)
         assert traffic["slots"] == traffic["sessions"]
         assert set(cfg["check"]["limits"]) == {"session_bias_rel_max"}
+        # a source-paced cell is not judged on the tail (PERF.md section 2)
+        tail = set() if cell["name"] == "turbo512.trio15" else {"frame_latency_p95_ms"}
         assert {m["name"] for m in bench.end_to_end(cell)} == {
-            "stylized_fps", "frame_latency_p50_ms", "frame_latency_p95_ms", "setup_s"}
+            "stylized_fps", "frame_latency_p50_ms", "setup_s"} | tail
         assert bench.flops(cfg).frame_flops(cfg) > 1e12
         assert callable(bench.reference(cfg).weight_shapes)
         for m in bench.per_layer(cell):
             assert callable(bench.reader(m))
             assert m["moves"] in {e["name"] for e in bench.end_to_end(cell)}
+
+
+def test_the_traffic_of_three_sessions_is_on_disk_as_the_issue_states_it():
+    from benchmark.harness import Benchmark
+
+    bench = Benchmark(REPO)
+    t = bench.traffic(bench.cell("turbo512.trio15"))
+    assert (t["sessions"], t["slots"], t["source_fps"]) == (3, 3, 15)
+    assert t["phase"] == "staggered" and t["source"] == "paced_latest_wins"
+    assert t["pipeline_depth"] == 2 and t["warmup_frames"] == 10
+
+
+def test_the_cell_that_works_the_scheduler_and_its_readers_are_found():
+    from benchmark.harness import Benchmark
+
+    bench = Benchmark(REPO)
+    cell = bench.cell("turbo512.trio15")
+    assert cell["config"] == "turbo512" and cell["chips"] == 1 and cell["traffic"] == "trio15"
+    names = [m["name"] for m in bench.per_layer(cell)]
+    for metric in ("dispatch_window_share", "hold_share", "batch_occupancy_mean",
+                   "window_wait_p50_ms", "step_device_ms", "step_mfu",
+                   "source_late_p50_ms"):
+        assert metric in names
+        assert callable(bench.reader({"name": metric}))
+    # a one-session cell dispatches solo alone: it does not list the share
+    solo = [m["name"] for m in bench.per_layer(bench.cell("turbo512.solo60"))]
+    assert "dispatch_window_share" not in solo and "hold_share" in solo
+    assert "source_late_p95_ms" in solo and "source_late_p50_ms" not in solo
+    # a metric that moves the tail is not read where the tail is not judged
+    assert "source_late_p95_ms" not in names
+    # the accepted cells' traffic files state no phase: one session has none
+    assert "phase" not in bench.traffic(bench.cell("turbo512.solo60"))

@@ -6,7 +6,7 @@ picoseconds)."""
 
 import pytest
 
-from benchmark import scope_reduce
+from benchmark import scope_reduce, serve
 from benchmark.tools import trace_report
 
 # what compiled.as_text() looks like, cut to what the parser reads: a
@@ -230,7 +230,7 @@ def test_counters_delta_keeps_cumulative_counters_only():
     c1 = {"batchsched_hop_count": {"dispatch": 12}, "batchsched_steps_total": 12,
           "batchsched_hop_ms_max": {"dispatch": 9.5}, "batchsched_sessions": 1,
           "batchsched_dispatch_inflight_hist": {"1": 10}}
-    assert trace_report.counters_delta(c0, c1) == {
+    assert serve.counters_delta(c0, c1) == {
         "batchsched_hop_count": {"dispatch": 10}, "batchsched_steps_total": 10,
         "batchsched_hop_ms_max": {"dispatch": 9.5},
         "batchsched_dispatch_inflight_hist": {"1": 10},

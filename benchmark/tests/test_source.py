@@ -4,8 +4,9 @@ consumer; superseded frames dropped and counted; lateness reported."""
 import asyncio
 
 import numpy as np
+import pytest
 
-from benchmark.source import PacedSource, frame_at, session_texture
+from benchmark.source import PacedSource, frame_at, session_starts, session_texture
 
 
 def test_frames_are_a_function_of_seed_and_index():
@@ -66,3 +67,60 @@ def test_a_fast_consumer_waits_for_the_next_due_frame():
     assert src.superseded == 0
     # the fifth frame cannot be handed out before it is due
     assert src.handed[-1][2] >= src.t0 + 4 / 50.0
+
+
+class FakeClock:
+    def __init__(self, now=100.0):
+        self.now = now
+
+    def __call__(self):
+        return self.now
+
+
+@pytest.mark.parametrize("sessions,fps", [(4, 30.0), (3, 15.0)])
+def test_the_two_phases_on_a_fake_clock(sessions, fps):
+    """``aligned``: frame k of every session is due at one instant.
+    ``staggered``: session i runs ``i / (sessions * fps)`` behind session 0,
+    so a period's frames come due evenly spread over it."""
+    clock = FakeClock()
+
+    def sources(phase):
+        out = []
+        for i, at in enumerate(session_starts(clock(), sessions, fps, phase)):
+            src = PacedSource(i, fps, 8, 8, clock=clock)
+            src.t0 = at  # start() without the pacer task: no loop here
+            out.append(src)
+        return out
+
+    for k in (0, 1, 7):
+        due = [s.due_time(k) for s in sources("aligned")]
+        assert due == [100.0 + k / fps] * sessions
+        due = [s.due_time(k) for s in sources("staggered")]
+        assert due == pytest.approx(
+            [100.0 + k / fps + i / (sessions * fps) for i in range(sessions)])
+        assert max(due) - min(due) < 1 / fps  # all inside one period
+    with pytest.raises(ValueError, match="phase"):
+        session_starts(0.0, 2, 30.0, "random")
+
+
+def test_one_session_starts_as_it_did_before_there_was_a_phase():
+    """Both phases give one session the instant itself, and a source started
+    at no stated time takes its clock's now: what ``solo30`` / ``solo60``
+    ran before the traffic file could state a phase."""
+    assert session_starts(5.0, 1, 60.0, "aligned") == [5.0]
+    assert session_starts(5.0, 1, 60.0, "staggered") == [5.0]
+    assert session_starts(5.0, 1, 60.0) == [5.0]
+
+    async def go():
+        clock = FakeClock(7.0)
+        a = PacedSource(1, 1000.0, 8, 8, clock=clock)
+        b = PacedSource(1, 1000.0, 8, 8, clock=clock)
+        a.start()
+        b.start(at=7.25)
+        await a.stop()
+        await b.stop()
+        return a, b
+
+    a, b = asyncio.run(go())
+    assert a.t0 == 7.0 and a.due_time(3) == 7.003
+    assert b.t0 == 7.25 and b.due_time(0) == 7.25

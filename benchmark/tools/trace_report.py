@@ -29,24 +29,6 @@ import time
 T_PROCESS_START = time.monotonic()
 
 
-def counters_delta(c0: dict, c1: dict) -> dict:
-    """Later snapshot minus earlier, for every cumulative counter of
-    ``BatchScheduler.snapshot()`` (numbers and dicts of numbers whose name
-    says total, count or hist); a maximum is the later snapshot's."""
-    out = {}
-    for key, after in c1.items():
-        before = c0.get(key)
-        if key.endswith("_max"):
-            out[key] = after
-        elif not key.endswith(("_total", "_count", "_hist")):
-            continue
-        elif isinstance(after, dict):
-            out[key] = {k: v - (before or {}).get(k, 0) for k, v in after.items()}
-        else:
-            out[key] = after - (before or 0)
-    return out
-
-
 def launch_join(pd) -> dict:
     """The join of host to device: the n-th ``rtc:launch`` span on the host
     against the n-th ``jit_bucket`` event of the chip's ``XLA Modules``
@@ -56,6 +38,7 @@ def launch_join(pd) -> dict:
     largest delay from a launch span's start to its program's start (at
     depth 2 a program queues behind the one running: most of a step)."""
     from ..scope_reduce import STEP_MODULE, load
+    from ..trace_reduce import leading_unjoined
 
     _, hosts, chips = load(pd)
     launches = sorted(
@@ -68,11 +51,7 @@ def launch_join(pd) -> dict:
     # a program launched before the trace began has no span, and the host
     # tracer stops before the device's: skip leading programs until every
     # program starts after its launch span opened, pair from there
-    skipped = 0
-    while skipped < len(programs) and any(
-        p < l[0] for l, p in zip(launches, programs[skipped:])
-    ):
-        skipped += 1
+    skipped = leading_unjoined([l[0] for l in launches], programs)
     pairs = list(zip(launches, programs[skipped:]))
     delays = sorted(p - l[0] for l, p in pairs)
     steps = [l[1] for l, _ in pairs]
@@ -139,7 +118,7 @@ def main(argv=None) -> int:
     out = {
         "workload": args.workload, "seed": args.seed, "device": device,
         "compiles_in_window": result.compiles_in_window,
-        "counters_window": counters_delta(result.counters_open, result.counters_close),
+        "counters_window": result.counters_window(),
     }
     if not args.trace:
         print(json.dumps(out), flush=True)
@@ -164,7 +143,7 @@ def main(argv=None) -> int:
         "by_depth": scope_reduce.by_scope(pd, tables, kernels, depth=args.depth)["parts"],
         "idle_gaps": scope_reduce.blame_gaps(pd),
         "launch_join": launch_join(pd),
-        "counters_traced": counters_delta(*result.counters_trace),
+        "counters_traced": serve.counters_delta(*result.counters_trace),
     })
     print(json.dumps(out), flush=True)
     return 0

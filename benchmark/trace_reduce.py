@@ -19,6 +19,8 @@ import re
 
 WINDOW_SPAN = "bench:trace_window"
 SPAN_PREFIX = "bench:"
+DISPATCH_SPAN = "rtc:dispatch"  # the program's own, one per step: stats k, riders, cause
+STEP_MODULE = "bucket"          # the scheduler's step programs: jit_bucket(<fingerprint>)
 _DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
@@ -61,9 +63,13 @@ def gaps(intervals, lo, hi) -> list:
 
 
 def _label(event) -> str:
-    """An op's searchable text: its name plus any string stat (the trace
-    keeps the JAX op name, which carries a Pallas kernel's ``name=``, in a
-    stat, while the event name is the HLO instruction's)."""
+    """An op's searchable text: its own names and no other op's.  The trace
+    names a device event by its whole HLO instruction, operands included
+    (``%fusion.3 = bf16[..] fusion(%flash_attention.7, ..)``), so of the
+    name and of any string stat only the part before `` = `` is kept: the
+    instruction's own name, or a JAX op name (which carries a Pallas
+    kernel's ``name=``) whole.  An op that consumes a kernel's result is
+    not the kernel."""
     parts = [event.name]
     try:
         for _, v in event.stats:
@@ -71,21 +77,52 @@ def _label(event) -> str:
                 parts.append(v)
     except Exception:  # a stat the binding cannot decode: the name suffices
         pass
-    return " ".join(parts)
+    return " ".join(p.split(" = ", 1)[0] for p in parts)
+
+
+def leading_unjoined(spans: list, programs: list) -> int:
+    """Host spans against device programs, one in-order stream: the n-th
+    span (a dispatch, a launch) started the n-th program.  ``spans`` and
+    ``programs`` are start times in order.  A program started before the
+    trace began has no span: -> how many leading programs to skip until
+    every program starts after its span opened."""
+    skipped = 0
+    while skipped < len(programs) and any(
+        p < s for s, p in zip(spans, programs[skipped:])
+    ):
+        skipped += 1
+    return skipped
+
+
+def join_riders(dispatches: list, programs: list) -> list:
+    """Riders of each step program.  ``dispatches``: (start, riders) of the
+    program's ``rtc:dispatch`` spans; ``programs``: the start of every step
+    program on one chip; both in time order (``leading_unjoined``).
+    -> riders per program, None for the skipped and for any past the last
+    span."""
+    skipped = leading_unjoined([d[0] for d in dispatches], programs)
+    riders = [None] * skipped + [d[1] for d in dispatches]
+    return (riders + [None] * len(programs))[: len(programs)]
 
 
 def reduce_trace(pd, kernel_names=()) -> dict:
     """-> dict with, over the chips: ``window_s``, ``busy_s`` (mean over
     chips of the union of op intervals), ``device_ops`` (top 10 [name, s]),
     ``idle_gaps`` (top 10 [host span, s]), ``kernels`` {name: [seconds...]},
-    ``modules`` {name: [seconds...]}, ``chips``."""
-    host_spans, window = [], None
+    ``modules`` {name: [seconds...]}, ``steps`` [[program, seconds, riders
+    or None]...] (the step programs whole inside the window, in order, each
+    with the riders of the dispatch that launched it), ``chips``."""
+    host_spans, dispatches, window = [], [], None
     for plane in pd.planes:
         if plane.name.startswith("/host:"):
             for line in plane.lines:
                 for ev in line.events:
                     if ev.name == WINDOW_SPAN:
                         window = (ev.start_ns, ev.start_ns + ev.duration_ns)
+                    elif ev.name == DISPATCH_SPAN:
+                        riders = dict(ev.stats).get("riders")
+                        if riders is not None:
+                            dispatches.append((ev.start_ns, int(riders)))
                     elif ev.name.startswith(SPAN_PREFIX):
                         host_spans.append(
                             (ev.start_ns, ev.start_ns + ev.duration_ns, ev.name[len(SPAN_PREFIX):])
@@ -100,6 +137,8 @@ def reduce_trace(pd, kernel_names=()) -> dict:
     kernels = {k: [] for k in kernel_names}
     named: dict = {}
     modules: dict = {}
+    steps: list = []
+    dispatches.sort()
     for plane in chips:
         ops, mods = [], []
         for line in plane.lines:
@@ -133,10 +172,17 @@ def reduce_trace(pd, kernel_names=()) -> dict:
                     kernels[k].append((t - s) / 1e9)
         busy.append(union_seconds(spans) / 1e9)
         all_gaps.extend(gaps(spans, lo, hi))
+        programs = sorted((e for e in mods if STEP_MODULE in e.name), key=lambda e: e.start_ns)
+        rode = dict(zip(
+            (e.start_ns for e in programs),
+            join_riders(dispatches, [e.start_ns for e in programs]),
+        ))
         for e in mods:
             if e.start_ns < lo or e.start_ns + e.duration_ns > hi:
                 continue  # a program cut by the window's edge is not a whole step
             modules.setdefault(e.name, []).append(e.duration_ns / 1e9)
+            if STEP_MODULE in e.name:
+                steps.append([e.name, e.duration_ns / 1e9, rode[e.start_ns]])
 
     def blame(gap):
         """The host span covering most of a device gap."""
@@ -160,4 +206,5 @@ def reduce_trace(pd, kernel_names=()) -> dict:
         "idle_gaps": [[blame(g), (g[1] - g[0]) / 1e9] for g in longest],
         "kernels": kernels,
         "modules": modules,
+        "steps": steps,
     }
