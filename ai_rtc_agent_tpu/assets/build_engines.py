@@ -28,7 +28,6 @@ def build(
     model_id: str,
     lora_dict: dict | None = None,
     cache_dir: str | None = None,
-    controlnet: str | None = None,
 ):
     from ..aot.cache import EngineCache
     from ..models import registry
@@ -39,12 +38,8 @@ def build(
         stream_engine_key,
     )
 
-    bundle = registry.load_model_bundle(
-        model_id, lora_dict=lora_dict, controlnet=controlnet
-    )
-    cfg = registry.default_stream_config(
-        model_id, **({"use_controlnet": True} if controlnet else {})
-    )
+    bundle = registry.load_model_bundle(model_id, lora_dict=lora_dict)
+    cfg = registry.default_stream_config(model_id)
     # params dtype is part of the engine signature — must match serving
     # (StreamDiffusionPipeline casts identically)
     bundle.params = registry.cast_params(bundle.params, cfg.dtype)
@@ -101,7 +96,6 @@ def build_scheduler_buckets(
     sessions: int,
     lora_dict: dict | None = None,
     cache_dir: str | None = None,
-    controlnet: str | None = None,
     bundle=None,
 ):
     """Prebuild the continuous batch scheduler's bucket geometries
@@ -116,13 +110,9 @@ def build_scheduler_buckets(
     from ..models import registry
     from ..stream.scheduler import BatchScheduler
 
-    cfg = registry.default_stream_config(
-        model_id, **({"use_controlnet": True} if controlnet else {})
-    )
+    cfg = registry.default_stream_config(model_id)
     if bundle is None:
-        bundle = registry.load_model_bundle(
-            model_id, lora_dict=lora_dict, controlnet=controlnet
-        )
+        bundle = registry.load_model_bundle(model_id, lora_dict=lora_dict)
         bundle.params = registry.cast_params(bundle.params, cfg.dtype)
     # dp=1 explicitly: serialized executables are per-topology, so only
     # the single-device geometries are buildable — a BATCHSCHED_DP env
@@ -171,8 +161,8 @@ def main(argv=None):
     ap.add_argument("--cache-dir", default=None)
     ap.add_argument(
         "--controlnet", default=None,
-        help="ControlNet model id: builds the conditioned engine variant "
-             "(reference lib/wrapper.py:870-877)",
+        help="ControlNet model id: builds the conditioned variant of "
+             "<model-id>+<this id> (reference lib/wrapper.py:870-877)",
     )
     ap.add_argument(
         "--sched-buckets", type=int, default=0, metavar="S",
@@ -188,13 +178,14 @@ def main(argv=None):
     for spec in args.lora:
         path, _, scale = spec.rpartition(":")
         lora_dict[path or spec] = float(scale) if path else 1.0
-    _, bundle = build(
-        args.model_id, lora_dict or None, args.cache_dir, args.controlnet
-    )
+    from ..models.registry import compose_model_id
+
+    model_id = compose_model_id(args.model_id, args.controlnet)
+    _, bundle = build(model_id, lora_dict or None, args.cache_dir)
     if args.sched_buckets:
         build_scheduler_buckets(
-            args.model_id, args.sched_buckets, lora_dict or None,
-            args.cache_dir, controlnet=args.controlnet, bundle=bundle,
+            model_id, args.sched_buckets, lora_dict or None,
+            args.cache_dir, bundle=bundle,
         )
 
 

@@ -120,35 +120,41 @@ def apply_controlnet(
     context = context.astype(x.dtype)
 
     # embed conditioning image to latent resolution and add to conv_in output
-    c = conv2d(p["cond_embedding"]["conv_in"], cond_image.astype(x.dtype))
-    c = silu(c)
-    for blk in p["cond_embedding"]["blocks"]:
-        c = silu(conv2d(blk["conv1"], c))
-        c = silu(conv2d(blk["conv2"], c, stride=2, padding=1))
-    c = conv2d(p["cond_embedding"]["conv_out"], c)
-
-    h = conv2d(p["conv_in"], x) + c
+    with jax.named_scope("hint"):
+        c = conv2d(p["cond_embedding"]["conv_in"], cond_image.astype(x.dtype))
+        c = silu(c)
+        for blk in p["cond_embedding"]["blocks"]:
+            c = silu(conv2d(blk["conv1"], c))
+            c = silu(conv2d(blk["conv2"], c, stride=2, padding=1))
+        c = conv2d(p["cond_embedding"]["conv_out"], c)
+        h = conv2d(p["conv_in"], x) + c
     outs = [h]
     for i, blk in enumerate(p["down_blocks"]):
-        for j, rn in enumerate(blk["resnets"]):
-            h = _resnet(rn, h, temb, cfg.norm_groups)
-            if blk["attentions"]:
-                h = _transformer(
-                    blk["attentions"][j], h, context, cfg, cfg.num_heads_per_block[i], attn_impl
-                )
-            outs.append(h)
-        if blk["downsample"] is not None:
-            h = conv2d(blk["downsample"], h, stride=2, padding=1)
-            outs.append(h)
+        with jax.named_scope(f"down_{i}"):
+            for j, rn in enumerate(blk["resnets"]):
+                h = _resnet(rn, h, temb, cfg.norm_groups)
+                if blk["attentions"]:
+                    h = _transformer(
+                        blk["attentions"][j], h, context, cfg,
+                        cfg.num_heads_per_block[i], attn_impl,
+                    )
+                outs.append(h)
+            if blk["downsample"] is not None:
+                h = conv2d(blk["downsample"], h, stride=2, padding=1)
+                outs.append(h)
 
-    mb = p["mid_block"]
-    h = _resnet(mb["resnet1"], h, temb, cfg.norm_groups)
-    h = _transformer(mb["attention"], h, context, cfg, cfg.num_heads_per_block[-1], attn_impl)
-    h = _resnet(mb["resnet2"], h, temb, cfg.norm_groups)
+    with jax.named_scope("mid"):
+        mb = p["mid_block"]
+        h = _resnet(mb["resnet1"], h, temb, cfg.norm_groups)
+        h = _transformer(
+            mb["attention"], h, context, cfg, cfg.num_heads_per_block[-1], attn_impl
+        )
+        h = _resnet(mb["resnet2"], h, temb, cfg.norm_groups)
 
-    scale = jnp.asarray(conditioning_scale, dtype=x.dtype)
-    down_res = [conv2d(zc, o) * scale for zc, o in zip(p["zero_convs"], outs)]
-    mid_res = conv2d(p["mid_zero_conv"], h) * scale
+    with jax.named_scope("zero_conv"):
+        scale = jnp.asarray(conditioning_scale, dtype=x.dtype)
+        down_res = [conv2d(zc, o) * scale for zc, o in zip(p["zero_convs"], outs)]
+        mid_res = conv2d(p["mid_zero_conv"], h) * scale
     return down_res, mid_res
 
 
@@ -159,22 +165,31 @@ def canny_soft(img_nhwc, low: float = 0.1, high: float = 0.3):
     518-519) with the canny conditioning BASELINE.json tracks: Sobel gradient
     magnitude on luma with a smooth double-threshold, returned as 3-channel
     [0,1] NHWC so it feeds apply_controlnet directly.
+
+    Computed in float32 whatever the input's dtype and cast back at the
+    end: the threshold has a slope of 60 per unit of gradient, so a bf16
+    luma's rounding (2**-9 relative) would move an edge pixel by a tenth of
+    its range.  The stencil is shifted slices of the zero-padded luma, not
+    a one-channel convolution: eight adds over [H,W] a frame, and no
+    layout with a minor dimension of one.
     """
-    luma = (
-        0.299 * img_nhwc[..., 0] + 0.587 * img_nhwc[..., 1] + 0.114 * img_nhwc[..., 2]
-    )[..., None]
-    kx = jnp.array([[-1, 0, 1], [-2, 0, 2], [-1, 0, 1]], img_nhwc.dtype) / 4.0
-    ky = kx.T
-    def conv1(img, k):
-        return jax.lax.conv_general_dilated(
-            img,
-            k[:, :, None, None],
-            (1, 1),
-            "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        )
-    gx = conv1(luma, kx)
-    gy = conv1(luma, ky)
+    img = img_nhwc.astype(jnp.float32)
+    luma = 0.299 * img[..., 0] + 0.587 * img[..., 1] + 0.114 * img[..., 2]
+    h, w = luma.shape[-2:]
+    pad = jnp.pad(luma, [(0, 0)] * (luma.ndim - 2) + [(1, 1), (1, 1)])
+
+    def at(dy, dx):  # luma[y + dy, x + dx], zero outside the frame
+        return pad[..., 1 + dy : 1 + dy + h, 1 + dx : 1 + dx + w]
+
+    # Sobel over 4: [-1 0 1; -2 0 2; -1 0 1] and its transpose
+    gx = (
+        (at(-1, 1) - at(-1, -1)) + 2.0 * (at(0, 1) - at(0, -1))
+        + (at(1, 1) - at(1, -1))
+    ) / 4.0
+    gy = (
+        (at(1, -1) - at(-1, -1)) + 2.0 * (at(1, 0) - at(-1, 0))
+        + (at(1, 1) - at(-1, 1))
+    ) / 4.0
     mag = jnp.sqrt(gx * gx + gy * gy + 1e-12)
-    edge = jax.nn.sigmoid((mag - low) / jnp.maximum(high - low, 1e-6) * 12.0 - 6.0)
-    return jnp.repeat(edge, 3, axis=-1)
+    edge = jax.nn.sigmoid((mag - low) / max(high - low, 1e-6) * 12.0 - 6.0)
+    return jnp.repeat(edge[..., None], 3, axis=-1).astype(img_nhwc.dtype)

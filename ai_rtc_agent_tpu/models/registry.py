@@ -60,8 +60,31 @@ class ModelBundle:
     loaded_real_weights: bool
 
 
+def split_model_id(model_id: str) -> tuple:
+    """``<base>+<side network>`` -> (base, side network); a plain id ->
+    (id, None).  One model id names everything a serving plane loads, so
+    whoever is handed the id alone (``BatchScheduler`` keys, snapshot
+    fingerprints, ``/health``, the benchmark's ``program_model_id``) builds
+    the ControlNet-conditioned stream from it:
+    ``lykon/dreamshaper-8+lllyasviel/control_v11p_sd15_canny``."""
+    base, sep, side = model_id.partition("+")
+    return (base, side) if sep and side else (model_id, None)
+
+
+def compose_model_id(model_id: str, controlnet: str | None) -> str:
+    """Inverse of :func:`split_model_id` (``--controlnet`` on the CLIs)."""
+    if not controlnet:
+        return model_id
+    if split_model_id(model_id)[1] is not None:
+        raise ValueError(
+            f"model id {model_id!r} already names a side network; drop "
+            f"--controlnet {controlnet!r} or the +suffix"
+        )
+    return f"{model_id}+{controlnet}"
+
+
 def family_of(model_id: str) -> str:
-    m = model_id.lower()
+    m = split_model_id(model_id)[0].lower()
     if ("tiny" in m or "test" in m) and "xl" in m:
         return "tinyxl"
     if "tiny" in m or "test" in m:
@@ -76,6 +99,7 @@ def family_of(model_id: str) -> str:
 def default_stream_config(model_id: str, **overrides) -> StreamConfig:
     """Per-family serving defaults mirroring BASELINE.json's tracked configs."""
     fam = family_of(model_id)
+    model_id, side_network = split_model_id(model_id)
     m = model_id.lower()
     if "turbo" in m and fam != "sdxl":
         base = dict(
@@ -127,6 +151,10 @@ def default_stream_config(model_id: str, **overrides) -> StreamConfig:
             cfg_type="self",
         )
     base.update(overrides)
+    if side_network is not None:
+        # the id names a side network: the annotator stays StreamConfig's
+        # default (canny) unless overridden
+        base.setdefault("use_controlnet", True)
     # fused Pallas epilogue on real TPUs (interpret-mode is slow on CPU).
     # FUSED_EPILOGUE=0 is the operator kill-switch: if the kernel fails to
     # compile at a new geometry the boot fails with the compiler's message
@@ -243,8 +271,11 @@ def load_model_bundle(
 ) -> ModelBundle:
     """``controlnet``: ControlNet model id / local path (e.g.
     "lllyasviel/control_v11p_sd15_canny") — attaches a conditioned branch
-    (reference's ControlNet path, lib/wrapper.py:617-643).  ``latent_scale``
+    (reference's ControlNet path, lib/wrapper.py:617-643); a composite
+    ``model_id`` (:func:`split_model_id`) names it too.  ``latent_scale``
     sets the annotator downsample depth (8 for SD, 4 for tiny tests)."""
+    model_id, side = split_model_id(model_id)
+    controlnet = controlnet or side
     fam = family_of(model_id)
     unet_cfg, clip_cfg, taesd_cfg = _model_configs(fam)
     key = jax.random.PRNGKey(seed)

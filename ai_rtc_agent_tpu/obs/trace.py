@@ -101,6 +101,8 @@ STAGES = (
     "finish_output",      # safety check + pts wrap (child of fetch)
     "encode_prompt",      # the text towers: a claim's prompt, a /config or
                           # datachannel prompt write (never on a frame's path)
+    "scale_write",        # a /config or datachannel write of a session row's
+                          # side-network conditioning scale (span only)
 )
 
 # terminal markers — how a frame left the pipeline

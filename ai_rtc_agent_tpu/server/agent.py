@@ -338,6 +338,18 @@ def apply_runtime_config(pipeline, config: dict, encoders=None):
         adapter = config["adapter"]
         if adapter is not None and not isinstance(adapter, str):
             raise ValueError("adapter must be a string name or null")
+    # the side network's conditioning strength: capability and value
+    # checked here, so a 400 still means nothing was applied
+    controlnet_scale = config.get("controlnet_scale")
+    if controlnet_scale is not None:
+        if not getattr(pipeline, "has_controlnet", False):
+            raise ValueError(
+                "controlnet_scale: no side network is served (start the "
+                "agent with --controlnet <id>)"
+            )
+        controlnet_scale = float(controlnet_scale)
+        if not 0.0 <= controlnet_scale <= 2.0:  # diffusers' documented range
+            raise ValueError("controlnet_scale must lie in [0, 2]")
     if has_adapter:
         # applied FIRST: update_adapter validates the name against the
         # registry before touching any slot (unknown -> ValueError -> 400
@@ -351,6 +363,8 @@ def apply_runtime_config(pipeline, config: dict, encoders=None):
         pipeline.update_prompt(prompt)
     if guidance_scale is not None or delta is not None:
         update_guidance(guidance_scale=guidance_scale, delta=delta)
+    if controlnet_scale is not None:
+        pipeline.update_controlnet_scale(controlnet_scale)
     if encoder is not None:
         encoders.apply_encoder_config(encoder)
 
@@ -2002,7 +2016,9 @@ async def on_startup(app):
     if app.get("mode") and app["mode"] != "img2img":
         overrides["mode"] = app["mode"]
     if app.get("annotator"):
-        if not app.get("controlnet"):
+        from ..models.registry import split_model_id
+
+        if split_model_id(app["model_id"])[1] is None:
             raise ValueError("--annotator requires --controlnet")
         overrides["annotator"] = app["annotator"]
     if app.get("sp", 0) > 1:
@@ -2025,11 +2041,7 @@ async def on_startup(app):
             return None
         from ..models import registry as _registry
 
-        return _registry.default_stream_config(
-            app["model_id"],
-            **overrides,
-            **({"use_controlnet": True} if app.get("controlnet") else {}),
-        )
+        return _registry.default_stream_config(app["model_id"], **overrides)
 
     built_scheduler = False  # an injected (test) scheduler is left as given
     if app.get("pipeline") is None:
@@ -2061,7 +2073,6 @@ async def on_startup(app):
         app["pipeline"] = StreamDiffusionPipeline(
             app["model_id"],
             config=_build_config(),
-            controlnet=app.get("controlnet"),
             mesh=mesh,
         )
         # Continuous batch scheduler (stream/scheduler.py): the DEFAULT
@@ -2428,8 +2439,13 @@ def build_app(
 ) -> web.Application:
     app = web.Application(middlewares=[cors_middleware])
     app["udp_ports"] = udp_ports
+    if controlnet:
+        # one id names base + side network from here on: the pipeline, the
+        # scheduler's AOT keys and snapshot fingerprint, /health
+        from ..models.registry import compose_model_id
+
+        model_id = compose_model_id(model_id, controlnet)
     app["model_id"] = model_id
-    app["controlnet"] = controlnet
     app["annotator"] = annotator
     app["pipeline"] = pipeline  # injectable for tests; built on startup if None
     app["batch_scheduler"] = batch_scheduler  # injectable for tests
@@ -2498,7 +2514,9 @@ def main(argv=None):
     parser.add_argument(
         "--controlnet",
         default=None,
-        help="optional ControlNet model id (enables canny-conditioned stream)",
+        help="optional ControlNet model id: serves <model-id>+<this id>, the "
+        "edge-conditioned stream, on whichever plane serves (POST /config "
+        '{"controlnet_scale": x} turns the control up or down)',
     )
     parser.add_argument(
         "--annotator",

@@ -348,14 +348,25 @@ def make_step_fn(models: StreamModels, cfg: StreamConfig,
         new_cnet_ring = None
         if cfg.use_controlnet:
             with jax.named_scope("annotate"):
-                src = I.preprocess_uint8(frame_u8, dtype=dt)
-                cond_new = _annotate(src, cfg, params)  # [fbs,H,W,3]
+                # the frame the step was handed, read a second time: no
+                # second host-to-device copy, no host-side edge detector
+                # (canny thresholds a gradient: it reads the frame in
+                # float32 and only its edge map is kept in the step's dtype)
+                src = I.preprocess_uint8(
+                    frame_u8, dtype=jnp.float32 if cfg.annotator == "canny" else dt
+                )
+                cond_new = _annotate(src, cfg, params).astype(dt)  # [fbs,H,W,3]
             # state["cnet_cond"] is [B-fbs,H,W,3] (possibly empty), aligned
-            # with x_buf; rotation mirrors the latent ring exactly
-            cond_full = jnp.concatenate(
-                [cond_new, state["cnet_cond"].astype(dt)], axis=0
-            )
-            new_cnet_ring = cond_full[: B - fbs]
+            # with x_buf: row j of the stream batch denoises the frame that
+            # came in j steps ago, and is conditioned on that frame's edge
+            # map; rotation mirrors the latent ring exactly.  A fresh
+            # session's ring is zeros (no edges), as its latent ring is
+            # noise (no frame): the first B-1 outputs are warm-up.
+            with jax.named_scope("cnet_ring"):
+                cond_full = jnp.concatenate(
+                    [cond_new, state["cnet_cond"].astype(dt)], axis=0
+                )
+                new_cnet_ring = cond_full[: B - fbs]
 
         # ---- assemble the stream batch and run the UNet ----
         if cfg.use_denoising_batch:
@@ -878,8 +889,12 @@ class StreamEngine:
         delta: float = 1.0,
         seed: int = 2,
         negative_prompt: str = "",
+        controlnet_scale: float = 1.0,
     ):
-        """Build the initial StreamState (reference prepare(): lib/wrapper.py:197-234)."""
+        """Build the initial StreamState (reference prepare(): lib/wrapper.py:197-234).
+        ``controlnet_scale``: the side network's conditioning strength this
+        state starts with (diffusers' default 1.0); data in the state, so
+        :meth:`update_controlnet_scale` swaps it with no recompile."""
         cfg = self.cfg
         if (
             num_inference_steps is not None
@@ -922,7 +937,7 @@ class StreamEngine:
             state["cnet_cond"] = jnp.zeros(
                 (B - cfg.frame_buffer_size, cfg.height, cfg.width, 3), cfg.jdtype
             )
-            state["cnet_scale"] = jnp.asarray(1.0, jnp.float32)
+            state["cnet_scale"] = jnp.asarray(controlnet_scale, jnp.float32)
         if cfg.cfg_type == "initialize":
             # Onetime-Negative: seed the stock noise with one real uncond pass
             coeffs = _as_step_coeffs(state["coeffs"])
@@ -1237,6 +1252,6 @@ class StreamEngine:
         """Runtime conditioning-strength swap (no recompile) — analog of the
         reference's fixed conditioning scale (lib/wrapper.py:870-877)."""
         if not self.cfg.use_controlnet:
-            raise RuntimeError("engine built without use_controlnet")
+            raise ValueError("engine built without use_controlnet")
         with self._submit_lock:
             self.state["cnet_scale"] = jnp.asarray(scale, jnp.float32)

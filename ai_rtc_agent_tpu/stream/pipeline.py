@@ -37,6 +37,7 @@ DEFAULT_T_INDEX_LIST = (18, 26, 35, 45)
 DEFAULT_NUM_INFERENCE_STEPS = 50
 DEFAULT_GUIDANCE_SCALE = 1.2
 DEFAULT_DELTA = 1.0
+DEFAULT_CONTROLNET_SCALE = 1.0  # diffusers' controlnet_conditioning_scale
 
 
 class StreamDiffusionPipeline:
@@ -50,10 +51,11 @@ class StreamDiffusionPipeline:
         prompt: str = DEFAULT_PROMPT,
         lora_dict: dict | None = None,
         seed: int = 2,
-        controlnet: str | None = None,
         use_safety_checker: bool | None = None,
         mesh=None,
     ):
+        """``model_id``: ``<base>+<side network>`` builds the
+        ControlNet-conditioned stream (``registry.split_model_id``)."""
         self.prompt = prompt
         self.model_id = model_id
         # live control-plane params — restart() restores THESE, never the
@@ -61,19 +63,19 @@ class StreamDiffusionPipeline:
         # ROADMAP open item 2, held by the restart-defaults checker)
         self.guidance_scale = DEFAULT_GUIDANCE_SCALE
         self.delta = DEFAULT_DELTA
+        self.controlnet_scale = DEFAULT_CONTROLNET_SCALE
         # optional NSFW gate (reference use_safety_checker,
         # lib/wrapper.py:930-942); env SAFETY_CHECKER enables it globally
         self.safety_checker = maybe_load_safety_checker(model_id, use_safety_checker)
-        cfg = config or registry.default_stream_config(
-            model_id, **({"use_controlnet": True} if controlnet else {})
-        )
-        if cfg.use_controlnet and controlnet is None:
+        cfg = config or registry.default_stream_config(model_id)
+        if cfg.use_controlnet != (registry.split_model_id(model_id)[1] is not None):
             raise ValueError(
-                "StreamConfig.use_controlnet=True requires a controlnet model "
-                "id (pass controlnet=... to StreamDiffusionPipeline)"
+                f"StreamConfig.use_controlnet={cfg.use_controlnet} but the "
+                f"model id {model_id!r} says otherwise: a side network is "
+                "named as <base>+<controlnet id>"
             )
         bundle = registry.load_model_bundle(
-            model_id, lora_dict=lora_dict, controlnet=controlnet,
+            model_id, lora_dict=lora_dict,
             latent_scale=cfg.latent_scale,
             attn_impl=cfg.attn_impl or None,
             annotator=cfg.annotator if cfg.use_controlnet else None,
@@ -95,6 +97,7 @@ class StreamDiffusionPipeline:
             guidance_scale=self.guidance_scale,
             delta=self.delta,
             seed=seed,
+            controlnet_scale=self.controlnet_scale,
         )
         # Serving fast path: adopt a prebuilt AOT engine when one exists
         # (always), or export-and-persist one when AOT_ENGINES=1 (reference
@@ -165,6 +168,7 @@ class StreamDiffusionPipeline:
                 guidance_scale=self.guidance_scale,
                 delta=self.delta,
                 seed=self._seed,
+                controlnet_scale=self.controlnet_scale,
             )
         finally:
             lock.release()
@@ -195,6 +199,17 @@ class StreamDiffusionPipeline:
             self.guidance_scale = g
         if d is not None:
             self.delta = d
+
+    @property
+    def has_controlnet(self) -> bool:
+        return self.config.use_controlnet
+
+    def update_controlnet_scale(self, scale: float):
+        """Runtime conditioning strength (POST /config ``controlnet_scale``);
+        tracked here like guidance so restart() re-prepares with it."""
+        scale = float(scale)
+        self.engine.update_controlnet_scale(scale)
+        self.controlnet_scale = scale
 
     # -- frame path (reference lib/pipeline.py:50-96) -----------------------
 

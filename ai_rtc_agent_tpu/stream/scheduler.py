@@ -132,6 +132,10 @@ __all__ = [
 # ("adapters" subtree — migration moves style bit-exact) and the payload
 # gains the "adapter" name field; the fingerprint gains adapter_rank /
 # adapter_targets when a bank is bound.
+# Still v2 with a side network (ISSUE 34): only a scheduler that serves one
+# adds the "controlnet_scale" field and the fingerprint's "cnet"; its rows
+# carry cnet_cond / cnet_scale, which _check_row holds to the template.
+# Every other scheduler's payload is byte for byte what it was.
 SESSION_SNAPSHOT_SCHEMA = 2
 
 # the hops the scheduler counts (``batchsched_hop_*`` in snapshot()): names
@@ -265,6 +269,7 @@ class ScheduledSession:
         self.guidance_scale = owner.guidance_scale
         self.delta = owner.delta
         self.t_index_list = list(owner.t_index_list)
+        self.controlnet_scale = owner.controlnet_scale
         self.adapter: str | None = None  # set by claim/restore/update paths
         self._seed = seed
         self._released = False
@@ -494,6 +499,17 @@ class ScheduledSession:
         if d is not None:
             self.delta = d
 
+    @property
+    def has_controlnet(self) -> bool:
+        return self._owner.has_controlnet
+
+    def update_controlnet_scale(self, scale: float):
+        """THIS session's conditioning strength: one float32 written into
+        its state row, read by the next step it rides (never a retrace)."""
+        scale = float(scale)
+        self._owner._apply_controlnet_scale(self.slot, scale)
+        self.controlnet_scale = scale
+
     def update_adapter(self, name: str | None):
         """Hot-swap THIS slot's style-adapter factor rows (``None`` clears
         back to the zero bank).  A same-shaped ``.at[slot].set`` write on
@@ -519,6 +535,7 @@ class ScheduledSession:
         state = self._owner._build_state(
             self.prompt, self.guidance_scale, self.delta, self._seed,
             t_index_list=self.t_index_list, adapter=self.adapter,
+            controlnet_scale=self.controlnet_scale,
         )
         self._owner._install(self.slot, state)
 
@@ -545,6 +562,8 @@ class ScheduledSession:
             # factor rows and the bank's padded rank
             out["adapter"] = self.adapter
             out["adapter_rank"] = owner._adapter_rank
+        if owner.has_controlnet:
+            out["controlnet_scale"] = self.controlnet_scale
         return out
 
 
@@ -568,6 +587,7 @@ class BatchScheduler:
         default_prompt: str = "",
         guidance_scale: float | None = None,
         delta: float | None = None,
+        controlnet_scale: float | None = None,
         schedule=None,
         safety_checker=None,
         prewarm: bool | None = None,
@@ -578,6 +598,7 @@ class BatchScheduler:
         adapters=None,
     ):
         from .pipeline import (
+            DEFAULT_CONTROLNET_SCALE,
             DEFAULT_DELTA,
             DEFAULT_GUIDANCE_SCALE,
             DEFAULT_PROMPT,
@@ -652,6 +673,13 @@ class BatchScheduler:
             DEFAULT_GUIDANCE_SCALE if guidance_scale is None else guidance_scale
         )
         self.delta = DEFAULT_DELTA if delta is None else delta
+        # the side network's strength (cfg.use_controlnet): a float32 in
+        # every session's row (``cnet_scale``), beside the conditioning
+        # ring (``cnet_cond``) that make_step_fn rotates with the latents
+        self.controlnet_scale = (
+            DEFAULT_CONTROLNET_SCALE if controlnet_scale is None
+            else float(controlnet_scale)
+        )
         self.t_index_list = list(cfg.t_index_list)
         # -- per-session style adapters (adapters/, ISSUE 20) ----------------
         # the registry's bank shape is BOUND here, once: rank = the largest
@@ -775,7 +803,7 @@ class BatchScheduler:
         # (after the counters exist: the prepare encodes the default prompt)
         self._template.prepare(
             self.prompt, guidance_scale=self.guidance_scale,
-            delta=self.delta, seed=0,
+            delta=self.delta, seed=0, controlnet_scale=self.controlnet_scale,
         )
         tmpl_state = self._template.state
         if self._adapter_rank:
@@ -873,6 +901,7 @@ class BatchScheduler:
             default_prompt=pipeline.prompt,
             guidance_scale=pipeline.guidance_scale,
             delta=pipeline.delta,
+            controlnet_scale=getattr(pipeline, "controlnet_scale", None),
             schedule=eng.schedule,
             safety_checker=pipeline.safety_checker,
             **kw,
@@ -924,6 +953,7 @@ class BatchScheduler:
             state = self._build_state(
                 prompt, self.guidance_scale, self.delta, seed,
                 t_index_list=self.t_index_list, adapter=adapter,
+                controlnet_scale=self.controlnet_scale,
             )
         except Exception:
             with self._lock:
@@ -1027,6 +1057,11 @@ class BatchScheduler:
 
             fp["adapter_rank"] = self._adapter_rank
             fp["adapter_targets"] = targets_digest(self._adapter_targets)
+        if self.has_controlnet:
+            # the row carries a conditioning ring of this annotator's maps
+            # (the side network's identity rides model_id); a scheduler
+            # without one omits the key, as the adapter keys are omitted
+            fp["cnet"] = self.cfg.annotator
         return fp
 
     def snapshot_session(self, session_key: str) -> dict:
@@ -1111,6 +1146,10 @@ class BatchScheduler:
         }
         if sess._sim is not None:
             snap["similarity"] = sess._sim.export_state()
+        if self.has_controlnet:
+            # the conditioning scale and ring travel bit-exact in the row;
+            # this is the session object's copy, which restart() restores
+            snap["controlnet_scale"] = float(sess.controlnet_scale)
         return snap
 
     def _check_row(self, row):
@@ -1181,6 +1220,9 @@ class BatchScheduler:
             delta = float(snapshot["delta"])
             t_index_list = [int(t) for t in snapshot["t_index_list"]]
             seed = int(snapshot.get("seed", 0))
+            cnet_scale = float(
+                snapshot.get("controlnet_scale", self.controlnet_scale)
+            )
             if len(t_index_list) != self.cfg.n_stages:
                 raise ValueError(
                     f"t_index_list length {len(t_index_list)} != compiled "
@@ -1205,6 +1247,7 @@ class BatchScheduler:
         sess.guidance_scale = guidance
         sess.delta = delta
         sess.t_index_list = t_index_list
+        sess.controlnet_scale = cnet_scale
         adapter = snapshot.get("adapter")
         sess.adapter = str(adapter) if adapter is not None else None
         sess._had_output = bool(snapshot.get("had_output", False))
@@ -1265,7 +1308,8 @@ class BatchScheduler:
         )
 
     def _build_state(self, prompt, guidance, delta, seed, t_index_list=None,
-                     adapter: str | None = None):
+                     adapter: str | None = None,
+                     controlnet_scale: float = 1.0):
         from .engine import _coeff_state
 
         rows = self._adapter_rows(adapter)  # validate before the heavy build
@@ -1274,7 +1318,8 @@ class BatchScheduler:
         # breaches (the watchdog still records + attributes them)
         with self._heavy_lock, devtel.expected_scope("sched-state-build"):
             self._template.prepare(
-                prompt, guidance_scale=guidance, delta=delta, seed=seed
+                prompt, guidance_scale=guidance, delta=delta, seed=seed,
+                controlnet_scale=controlnet_scale,
             )
             state = self._template.state
             if t_index_list is not None and tuple(t_index_list) != tuple(
@@ -1384,6 +1429,28 @@ class BatchScheduler:
                     .set(jnp.asarray(delta, jnp.float32))
                 )
 
+    @property
+    def has_controlnet(self) -> bool:
+        return bool(self.cfg.use_controlnet)
+
+    def _require_controlnet(self):
+        if not self.has_controlnet:
+            raise ValueError(
+                "controlnet_scale: this scheduler serves no side network "
+                "(name one in the model id: <base>+<controlnet id>)"
+            )
+
+    def _apply_controlnet_scale(self, slot: int, scale: float):
+        self._require_controlnet()
+        with hop("scale_write", slot=slot), self._lock, \
+                devtel.expected_scope("sched-control-write"):
+            self.states["cnet_scale"] = (
+                self.states["cnet_scale"]
+                .at[slot]
+                .set(jnp.asarray(scale, jnp.float32))
+            )
+            self._cnet_scale_writes += 1
+
     def _apply_adapter(self, slot: int, name: str | None):
         """Swap one slot's factor rows in the stacked bank — the hot-swap
         core: same-shaped ``.at[slot].set`` writes per target (the closed
@@ -1464,6 +1531,20 @@ class BatchScheduler:
             self.guidance_scale = g
         if d is not None:
             self.delta = d
+
+    def update_controlnet_scale(self, scale: float):
+        """Global conditioning strength (POST /config ``controlnet_scale``):
+        every live session's row AND the default future claims start with."""
+        scale = float(scale)
+        self._require_controlnet()  # also with no live session to write
+        with self._lock:
+            slots = list(self._sessions)
+        for s in slots:
+            self._apply_controlnet_scale(s, scale)
+            sess = self._sessions.get(s)
+            if sess is not None:
+                sess.controlnet_scale = scale
+        self.controlnet_scale = scale
 
     def update_adapter(self, name: str | None):
         """Global adapter swap (POST /config parity with the other
@@ -2584,6 +2665,10 @@ class BatchScheduler:
         # index = batches dispatched and not yet resolved at a dispatch
         self._inflight_n = [0] * (self._batches.maxlen + 1)
         self._starved_n = 0
+        # session rows that rode a step with the side network in it, and
+        # /config or datachannel writes of a row's conditioning scale
+        self._cnet_rows = 0
+        self._cnet_scale_writes = 0
         self._h2d_bytes = 0
         self._d2h_bytes = 0
 
@@ -2622,6 +2707,8 @@ class BatchScheduler:
             self._inflight_n[min(batch.inflight, len(self._inflight_n) - 1)] += 1
             if batch.starved:
                 self._starved_n += 1
+            if self.has_controlnet:
+                self._cnet_rows += occupancy
             self._occ.append(occupancy)
             # copy-on-new-key: snapshot() iterates this dict WITHOUT the
             # stats lock (it must never block on a dispatch) — replacing
@@ -2698,6 +2785,11 @@ class BatchScheduler:
             "batchsched_d2h_bytes_total": self._d2h_bytes,
             "batchsched_attention_paths": dict(self.attention_paths),
         }
+        if self.has_controlnet:
+            out["batchsched_controlnet_rows_total"] = self._cnet_rows
+            out["batchsched_controlnet_scale_writes_total"] = (
+                self._cnet_scale_writes
+            )
         if self._adapter_rank:
             # style-adapter plane (adapters/): live sessions riding a
             # non-zero factor bank + total hot-swap control writes.

@@ -66,7 +66,6 @@ def build_engine(config: str, fbs: int = 1, unet_cache: int = 0):
 
     # float32 only on a CPU asked for by name; main() below never is
     dtype = "bfloat16" if jax.default_backend() != "cpu" else "float32"
-    controlnet = None
     if config == "turbo512":
         model_id, overrides = "stabilityai/sd-turbo", dict(dtype=dtype)
     elif config == "lcm4x512":
@@ -78,9 +77,8 @@ def build_engine(config: str, fbs: int = 1, unet_cache: int = 0):
         overrides = dict(dtype=dtype, height=1024, width=1024)
     elif config == "controlnet512":
         # BASELINE configs[3]: ControlNet-canny conditioned stream (SD1.5+LCM)
-        model_id = "lykon/dreamshaper-8"
-        overrides = dict(dtype=dtype, use_controlnet=True)
-        controlnet = "lllyasviel/control_v11p_sd15_canny"
+        model_id = "lykon/dreamshaper-8+lllyasviel/control_v11p_sd15_canny"
+        overrides = dict(dtype=dtype)
     elif config == "tiny64":
         # hermetic tiny model (64x64, random weights): the whole bench
         # pipeline in seconds of compile — a plumbing check, never a cell
@@ -92,7 +90,7 @@ def build_engine(config: str, fbs: int = 1, unet_cache: int = 0):
         overrides["frame_buffer_size"] = fbs
     if unet_cache >= 2:
         overrides["unet_cache_interval"] = unet_cache
-    bundle = registry.load_model_bundle(model_id, controlnet=controlnet)
+    bundle = registry.load_model_bundle(model_id)
     cfg = registry.default_stream_config(model_id, **overrides)
     bundle.params = registry.cast_params(bundle.params, dtype)
     eng = StreamEngine(

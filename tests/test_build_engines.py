@@ -66,8 +66,8 @@ def test_build_controlnet_engine_variant(tmp_path):
     """ControlNet engine variant gets its own cache key (reference compiles a
     separate UNet+ControlNet engine, lib/wrapper.py:870-877)."""
     (key_plain,), _ = build("tiny-test", cache_dir=str(tmp_path))
-    (key_cnet,), _ = build("tiny-test", cache_dir=str(tmp_path), controlnet="tiny-cnet")
-    assert key_plain != key_cnet
+    (key_cnet,), _ = build("tiny-test+tiny-cnet", cache_dir=str(tmp_path))
+    assert key_plain != key_cnet and "tiny-test+tiny-cnet" in key_cnet
     assert os.path.isdir(os.path.join(tmp_path, key_cnet))
 
 
