@@ -112,18 +112,48 @@ def conv2d(p, x, stride: int = 1, padding="SAME"):
     return y
 
 
-def group_norm(p, x, groups: int = 32, eps: float = 1e-5):
-    """GroupNorm over NHWC (stats in fp32)."""
+def group_norm(
+    p, x, groups: int = 32, eps: float = 1e-5, act: str | None = None
+):
+    """GroupNorm over NHWC, then ``act`` (a key of ``ACTIVATIONS``) if given:
+    float32 statistics (two-pass, biased variance) and float32 application
+    whatever the activation's dtype, which the result keeps.
+
+    The statistics are summed a channel first: XLA fuses the convert into
+    the two reductions over ``(h, w)`` and the groups meet on the tiny
+    ``[n, g, c // g]``, so the activation itself is never reshaped and never
+    float32 in HBM; the application is one multiply-add a channel.  Written
+    as ``x.astype(f32).reshape(n, h*w, g, c//g)`` the compiled step wrote
+    the activation out in float32 and transposed that copy so that a
+    group's channels lay together (PERF.md section 6, PR 35)."""
     n, h, w, c = x.shape
     g = min(groups, c)
     while c % g:
         g -= 1
-    xf = x.astype(jnp.float32).reshape(n, h * w, g, c // g)
-    mean = xf.mean(axis=(1, 3), keepdims=True)
-    var = xf.var(axis=(1, 3), keepdims=True)
-    xf = (xf - mean) * jax.lax.rsqrt(var + eps)
-    xf = xf.reshape(n, h, w, c)
-    return (xf * p["scale"] + p["bias"]).astype(x.dtype)
+    count = h * w * (c // g)
+    if n > 1:
+        # a convolution over several rows hands its consumer float32 in its
+        # own windowed layout when the consumer's convert fuses into it, and
+        # the reductions below then re-lay that out twice; behind the
+        # barrier it writes the activation once, in its own dtype (on the
+        # chip a [4,64,64,320] resnet 0.84 -> 0.67 ms; at n=1 there is no
+        # such layout and the barrier costs 2-9 %: the same section)
+        x = jax.lax.optimization_barrier(x)
+
+    def over_group(per_channel):  # [n, c] sums -> [n, 1, 1, c] group means
+        grouped = per_channel.reshape(n, g, c // g).sum(-1, keepdims=True)
+        grouped = jnp.broadcast_to(grouped / count, (n, g, c // g))
+        return grouped.reshape(n, 1, 1, c)
+
+    mean = over_group(jnp.sum(x, axis=(1, 2), dtype=jnp.float32))
+    centred = x.astype(jnp.float32) - mean
+    var = over_group(jnp.sum(centred * centred, axis=(1, 2)))
+    a = jax.lax.rsqrt(var + eps) * p["scale"].astype(jnp.float32)
+    b = p["bias"].astype(jnp.float32) - mean * a
+    y = x.astype(jnp.float32) * a + b
+    if act is not None:
+        y = ACTIVATIONS[act](y)
+    return y.astype(x.dtype)
 
 
 def layer_norm(p, x, eps: float = 1e-5):

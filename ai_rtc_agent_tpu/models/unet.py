@@ -276,11 +276,13 @@ def init_unet(key, cfg: UNetConfig):
 # --------------------------------------------------------------------------
 
 def _resnet(p, x, temb, groups: int = 32):
-    h = group_norm(p["norm1"], x, groups)
-    h = conv2d(p["conv1"], silu(h))
+    with jax.named_scope("norm"):
+        h = group_norm(p["norm1"], x, groups, act="silu")
+    h = conv2d(p["conv1"], h)
     h = h + linear(p["time_emb_proj"], silu(temb))[:, None, None, :]
-    h = group_norm(p["norm2"], h, groups)
-    h = conv2d(p["conv2"], silu(h))
+    with jax.named_scope("norm"):
+        h = group_norm(p["norm2"], h, groups, act="silu")
+    h = conv2d(p["conv2"], h)
     if "conv_shortcut" in p:
         x = conv2d(p["conv_shortcut"], x)
     return x + h
@@ -399,8 +401,9 @@ def apply_unet(
 
     def conv_out(h):
         with jax.named_scope("conv_out"):
-            h = group_norm(p["conv_norm_out"], h, cfg.norm_groups)
-            return conv2d(p["conv_out"], silu(h))
+            with jax.named_scope("norm"):
+                h = group_norm(p["conv_norm_out"], h, cfg.norm_groups, act="silu")
+            return conv2d(p["conv_out"], h)
 
     def up_resnet(j, rn, h, skip):
         with jax.named_scope(f"resnet_{j}"):  # the skip concat is its input

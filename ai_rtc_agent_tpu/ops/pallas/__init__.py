@@ -14,6 +14,8 @@ import contextlib
 import contextvars
 import functools
 import logging
+import math
+import re
 
 import jax
 
@@ -60,6 +62,46 @@ def mosaic_kernel_counts(hlo_text: str) -> dict:
         name = next((k for k in KERNEL_NAMES if k in op_name), "unnamed")
         counts[name] = counts.get(name, 0) + 1
     return counts
+
+
+# an instruction of a compiled module: its name, its output's dtype and
+# dimensions (a tuple-valued one does not match), its opcode
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(")
+# what counts as the size of an activation: the served graphs' GroupNorms
+# at 16x16 and up see 1.6e5 elements a row or more, the largest tensor of
+# the tiny test models holds 3e4
+_ACTIVATION_ELEMS = 100_000
+
+
+def f32_relayout_copies(hlo_text: str) -> dict:
+    """{"count", "bytes"} of the activation-sized float32 tensors a compiled
+    executable (``compiled.as_text()``) writes only to hold the same numbers
+    in another layout: outputs of ``copy`` instructions and of ``copy_*``
+    fusions, outside fused computations.  What GroupNorm cost under XLA
+    before PR 35: a float32 copy of the activation, transposed so that a
+    group's channels lie together, and after a convolution over several
+    rows two re-layouts of its float32 output.  A compile fact, beside
+    ``mosaic_kernel_counts``: which of the two a step holds."""
+    count = total = 0
+    fused = False
+    for line in hlo_text.splitlines():
+        if line[:1].strip():  # a computation's header (or its closing brace)
+            fused = "fused_computation" in line.partition("(")[0]
+            continue
+        m = None if fused else _INSTRUCTION.match(line)
+        if m is None:
+            continue
+        name, dtype, dims, opcode = m.groups()
+        if dtype != "f32" or not (
+            opcode == "copy"
+            or opcode == "fusion" and "copy" in name.partition(".")[0].split("_")
+        ):
+            continue
+        elems = math.prod(int(d) for d in dims.split(",") if d)
+        if elems > _ACTIVATION_ELEMS:
+            count += 1
+            total += 4 * elems
+    return {"count": count, "bytes": total}
 
 
 # who is counting the attention calls traced right now

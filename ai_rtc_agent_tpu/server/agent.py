@@ -2341,6 +2341,7 @@ def _serving_info(app) -> dict:
     if kernels is not None:
         info["mosaic_kernels"] = dict(kernels)
         info["attention_paths"] = dict(sched.attention_paths)
+        info["f32_relayout_copies"] = dict(sched.relayout_copies)
     return info
 
 

@@ -101,7 +101,11 @@ import numpy as np
 
 from ..obs import devtel
 from ..obs.trace import get_trace, hop, safe_list
-from ..ops.pallas import count_attention_paths, mosaic_kernel_counts
+from ..ops.pallas import (
+    count_attention_paths,
+    f32_relayout_copies,
+    mosaic_kernel_counts,
+)
 from ..resilience import faults as _faults
 from ..resilience.overload import DeadlineQueue, ShedFrame
 from ..utils import env
@@ -757,6 +761,9 @@ class BatchScheduler:
         # bucket label -> {"packed" | "per_head": flash_attention calls
         # traced into it with that operand layout} (ops/pallas/attention.py)
         self.attention_paths: dict = {}
+        # bucket label -> {"count", "bytes"} of the activation-sized float32
+        # re-layouts left in it (ops/pallas f32_relayout_copies)
+        self.relayout_copies: dict = {}
         self.active = [False] * S
         self._sessions: dict = {}  # slot -> ScheduledSession
         self._queues = [
@@ -1767,30 +1774,37 @@ class BatchScheduler:
                     compiled = lowered.compile()
                 # what the executable that will serve really contains:
                 # /health reports it, chip_smoke.py asserts on it
-                self.mosaic_kernels[label] = mosaic_kernel_counts(
-                    compiled.as_text()
-                )
+                text = compiled.as_text()
+                self.mosaic_kernels[label] = mosaic_kernel_counts(text)
                 self.attention_paths[label] = dict(paths)
+                self.relayout_copies[label] = f32_relayout_copies(text)
                 self._bucket_steps[(k, v)] = compiled
                 self._warmed_buckets.add((k, v))
                 logger.info(
                     "prewarmed batchsched bucket %d/%d (%s, dp=%d): "
-                    "kernels %s, attention paths %s",
+                    "kernels %s, attention paths %s, float32 re-layouts %s",
                     k, self.max_sessions, v, self.dp,
                     self.mosaic_kernels[label], self.attention_paths[label],
+                    self.relayout_copies[label],
                 )
 
-    def compiled_text(self) -> dict:
-        """{bucket label: HLO text of its compiled executable}, for the
-        buckets prewarm_buckets compiled (an AOT-adopted or lazily jitted
-        bucket has no compiled object to read).  A profiler trace names a
-        device op by its HLO instruction only; the instruction's
-        ``metadata={op_name=...}`` in this text is where its
-        ``jax.named_scope`` path is (benchmark/scope_reduce.py)."""
+    def compiled_steps(self) -> dict:
+        """{bucket label: compiled executable}, for the buckets
+        prewarm_buckets compiled (an AOT-adopted or lazily jitted bucket has
+        no compiled object to read)."""
         return {
-            self._bucket_label(k, v): step.as_text()
+            self._bucket_label(k, v): step
             for (k, v), step in self._bucket_steps.items()
             if hasattr(step, "as_text")
+        }
+
+    def compiled_text(self) -> dict:
+        """{bucket label: HLO text of its compiled executable}.  A profiler
+        trace names a device op by its HLO instruction only; the
+        instruction's ``metadata={op_name=...}`` in this text is where its
+        ``jax.named_scope`` path is (benchmark/scope_reduce.py)."""
+        return {
+            label: step.as_text() for label, step in self.compiled_steps().items()
         }
 
     def rehearse(self):
@@ -2784,6 +2798,7 @@ class BatchScheduler:
             "batchsched_h2d_bytes_total": self._h2d_bytes,
             "batchsched_d2h_bytes_total": self._d2h_bytes,
             "batchsched_attention_paths": dict(self.attention_paths),
+            "batchsched_f32_relayout_copies": dict(self.relayout_copies),
         }
         if self.has_controlnet:
             out["batchsched_controlnet_rows_total"] = self._cnet_rows

@@ -6,7 +6,8 @@ refuses (the B>1 epilogue block shape was one: "last two dimensions of your
 block shape [must be] divisible by 8 and 128") fails in the sandbox first.
 These are compile facts, not speeds and not numerics: parity on the chip is
 ``scripts/tpu_numerics_check.py`` (the kernel phase of ``chip_smoke.py``).
-Kernel-only compiles and single attention blocks, about a second each.
+Kernel-only compiles, single attention blocks and single resnets, one to
+ten seconds each.
 """
 
 import re
@@ -17,7 +18,11 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from ai_rtc_agent_tpu.ops.lcm import StepCoeffs
-from ai_rtc_agent_tpu.ops.pallas import count_attention_paths, mosaic_kernel_counts
+from ai_rtc_agent_tpu.ops.pallas import (
+    count_attention_paths,
+    f32_relayout_copies,
+    mosaic_kernel_counts,
+)
 from ai_rtc_agent_tpu.ops.pallas.attention import flash_attention
 from ai_rtc_agent_tpu.ops.pallas.fused_scheduler import fused_stream_epilogue
 
@@ -176,6 +181,42 @@ def test_fused_epilogue_compiles_for_v5e(v5e, batch, cfg_type, vmap_k):
     assert mosaic_kernel_counts(compiled.as_text()) == {
         "fused_stream_epilogue": 1
     }
+
+
+@pytest.mark.parametrize(
+    "vmap_k,n,side,c",
+    [
+        (1, 1, 64, 320),  # turbo512 / sdxlturbo512, tier 0: 2 at the parent
+        (1, 1, 32, 640),  # 2 at the parent
+        (1, 4, 64, 320),  # lcm4x512, tier 0: 3 at the parent
+    ],
+)
+def test_resnet_compiles_for_v5e_without_float32_relayouts(v5e, vmap_k, n, side, c):
+    """ISSUE 35: one ``models/unet.py _resnet`` under ``vmap`` as the bucket
+    step runs it, compiled for the chip.  ``group_norm`` written as
+    ``x.astype(f32).reshape(n, h*w, g, c//g)`` made XLA write the activation
+    out in float32 and transpose that copy so that a group's channels lay
+    together (two activation-sized float32 ``copy`` / ``copy_*_fusion``
+    outputs a resnet, read off the parent's tree: PERF.md section 6); after
+    a convolution at n=4 its float32 output in the windowed layout
+    was re-laid out twice more.  With per-channel sums (and, at several
+    rows, the barrier that keeps the convert out of the convolution) no
+    such tensor is left."""
+    from ai_rtc_agent_tpu.models.unet import _resnet
+
+    leaf = lambda *shape: v5e(shape, jnp.bfloat16)
+    p = {
+        "norm1": {"scale": leaf(c), "bias": leaf(c)},
+        "conv1": {"kernel": leaf(3, 3, c, c), "bias": leaf(c)},
+        "time_emb_proj": {"kernel": leaf(1280, c), "bias": leaf(c)},
+        "norm2": {"scale": leaf(c), "bias": leaf(c)},
+        "conv2": {"kernel": leaf(3, 3, c, c), "bias": leaf(c)},
+    }
+    fn = lambda p, x, temb: jax.vmap(lambda x, t: _resnet(p, x, t, 32))(x, temb)
+    text = jax.jit(fn).lower(
+        p, leaf(vmap_k, n, side, side, c), leaf(vmap_k, n, 1280)
+    ).compile().as_text()
+    assert f32_relayout_copies(text) == {"count": 0, "bytes": 0}
 
 
 def test_scoped_bucket_step_compiles_for_v5e_with_the_same_kernels(monkeypatch):

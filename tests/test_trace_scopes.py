@@ -23,7 +23,7 @@ STEP_SCOPES = (
 UNET_SCOPES = (
     "time_embed", "conv_in", "down_0", "down_1", "mid", "up_0", "up_1",
     "conv_out", "resnet_0", "transformer_0", "downsample", "upsample",
-    "self_attn", "cross_attn", "ff", "proj",
+    "self_attn", "cross_attn", "ff", "proj", "norm",
 )
 _WRAPPED = re.compile(r"^[a-z_]+\((.*)\)$")
 
