@@ -103,6 +103,9 @@ STAGES = (
                           # datachannel prompt write (never on a frame's path)
     "scale_write",        # a /config or datachannel write of a session row's
                           # side-network conditioning scale (span only)
+    "step_ready",         # the ready watcher's wait for a launched bucket
+                          # step to leave the device; its end is the
+                          # watcher's sight of it done (span; its own counters)
 )
 
 # terminal markers — how a frame left the pipeline

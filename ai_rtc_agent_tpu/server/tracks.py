@@ -123,19 +123,22 @@ class _PullHold:
         self.seconds = max(0.0, min(self.seconds + step, cap))
 
 
-def _stamp_hold(handle, seconds: float):
-    """Hop ``hold``: the seconds ride the handle ``submit`` returned, to be
-    folded into the scheduler's counters when it is fetched.  A wrapper
-    around the session hands its handle on as it is (the benchmark's) or
-    inside a tuple of its own (the supervisor's, the agent's timing one);
-    another pipeline's handle has no place for the seconds."""
+def _stamp_handle(handle, **stamps):
+    """The track's own stamps of a frame ride the handle ``submit``
+    returned, to be folded into the scheduler's counters when it is
+    fetched: ``t_pickup`` (its pull ended), and for a held pull ``t_hold``
+    (the hold began) and ``hold_s`` (hop ``hold``).  A wrapper around the
+    session hands its handle on as it is (the benchmark's) or inside a
+    tuple of its own (the supervisor's, the agent's timing one); another
+    pipeline's handle has no place for them."""
     stack = [handle]
     while stack:
         h = stack.pop()
         if isinstance(h, tuple):
             stack.extend(h)
         elif hasattr(h, "hold_s"):
-            h.hold_s = seconds
+            for name, value in stamps.items():
+                setattr(h, name, value)
             return
 
 
@@ -296,9 +299,14 @@ class VideoStreamTrack:
                 await self._sleep(hold.seconds)
             t_pull = clock()
             frame = await self._pull_fresh()
+            t_pickup = clock()
             handle = await asyncio.to_thread(self.pipeline.submit, frame)
             if held:
-                _stamp_hold(handle, t_pull - t_held)
+                _stamp_handle(
+                    handle, t_pickup=t_pickup, t_hold=t_held, hold_s=t_pull - t_held
+                )
+            else:
+                _stamp_handle(handle, t_pickup=t_pickup)
             self._pending.append((frame, handle))
         src, handle = self._pending.popleft()
         t_fetch = clock()

@@ -90,6 +90,7 @@ through the k=1 bucket.
 from __future__ import annotations
 
 import logging
+import queue
 import threading
 import time
 from collections import deque
@@ -154,6 +155,50 @@ COUNTED_HOPS = (
 # backpressure = the dispatcher, every live session ready, held back only
 # by the in-flight cap until a batch resolved
 DISPATCH_CAUSES = ("solo", "inline_full", "window", "backpressure")
+# what the host was doing while the device sat idle between two steps
+# (``batchsched_device_gap_*``): pull_wait = the launching frame's track
+# waited for its camera; hold = that track held the pull; window_wait = the
+# dispatcher waited for riders; host = anything else (submit's own hops,
+# the event loop, a stall of the process)
+GAP_CAUSES = ("pull_wait", "hold", "window_wait", "host")
+# a fetched frame's time in the program (``batchsched_<piece>_ms_hist``):
+# residence = pick-up to the fetch's return = to_launch (to the jitted
+# call's return) + on_device (to the step's ready stamp) + after_ready;
+# in bins of 0.1 ms
+RESIDENCE_PIECES = ("residence", "to_launch", "on_device", "after_ready")
+_RES_BINS_PER_S = 1e4
+
+
+def _bump(counts: dict, key, by=1) -> dict:
+    """``counts[key] += by``, copy-on-new-key: snapshot() iterates these
+    dicts WITHOUT the stats lock (it must never block on a dispatch), so a
+    dict gains a key only by being replaced wholesale, which keeps every
+    published dict iteration-safe.  -> the dict to keep."""
+    if key in counts:
+        counts[key] += by
+        return counts
+    counts = dict(counts)
+    counts[key] = by
+    return counts
+
+
+def _gap_cause(g0: float, g1: float, pull_wait, hold, window_wait) -> str:
+    """The member of GAP_CAUSES that covers most of the device's idle gap
+    ``[g0, g1]``: each of the first three is a (start, end) interval of the
+    host's clock or None; ``host`` covers what none of them does.  Ties go
+    to the earlier member."""
+    cover, clipped = {}, []
+    for name, span in zip(GAP_CAUSES, (pull_wait, hold, window_wait)):
+        lo, hi = (g0, g0) if span is None else (max(g0, span[0]), min(g1, span[1]))
+        cover[name] = max(0.0, hi - lo)
+        if hi > lo:
+            clipped.append((lo, hi))
+    union, end = 0.0, g0
+    for lo, hi in sorted(clipped):
+        union += max(0.0, hi - max(lo, end))
+        end = max(end, hi)
+    cover["host"] = (g1 - g0) - union
+    return max(GAP_CAUSES, key=cover.get)
 
 
 class CapacityError(RuntimeError):
@@ -184,12 +229,14 @@ class _DispatchedBatch:
     __slots__ = (
         "rows", "host", "rlocks", "entries", "t_dispatch", "occupancy",
         "resolved", "feed", "cause", "inflight", "starved", "dispatch_s",
-        "launch_s",
+        "launch", "out", "step", "k", "frames", "launcher", "window",
+        "epoch", "ready_by", "ready",
     )
 
     def __init__(self, rows, entries, t_dispatch, occupancy, feed=True,
                  cause="solo", inflight=0, starved=False, dispatch_s=0.0,
-                 launch_s=0.0):
+                 launch=(0.0, 0.0), out=None, step=0, k=1, frames="",
+                 launcher=None, window=None, epoch=0):
         self.rows = rows  # per-entry device buffers (async D2H in flight)
         self.host = [None] * len(rows)  # memoized per-row host copies
         self.rlocks = [threading.Lock() for _ in rows]
@@ -201,13 +248,32 @@ class _DispatchedBatch:
         # the dispatch's own stamps, folded into the scheduler's counters
         # by the first resolver (_note_step): why it was dispatched (one of
         # DISPATCH_CAUSES), the batches then in flight, whether it found
-        # the device drained, and the host seconds of _step_batch_locked
-        # and of the jitted call inside it
+        # the device drained, the host seconds of _step_batch_locked, and
+        # the two clock reads around the jitted call inside it
         self.cause = cause
         self.inflight = inflight
         self.starved = starved
         self.dispatch_s = dispatch_s
-        self.launch_s = launch_s
+        self.launch = launch
+        # what the ready watcher reads (BatchScheduler._watch_ready): the
+        # bucket output it blocks on (dropped once it is ready), the ids
+        # ``rtc:launch`` and ``rtc:dispatch`` carry, the submitting frame
+        # of an inline dispatch, the dispatcher's wait for riders before
+        # it ((start, end) or None), and the counters' generation
+        self.out = out
+        self.step = step
+        self.k = k
+        self.frames = frames
+        self.launcher = launcher
+        self.window = window
+        self.epoch = epoch
+        # the earliest instant another thread saw this step done (a drain
+        # check found its output ready, a fetch had its row on the host):
+        # the watcher's stamp is the earlier of that and its own wake-up
+        self.ready_by: float | None = None
+        # the watcher's own sight of this step done (None until it woke, or
+        # if the step raised)
+        self.ready: float | None = None
 
 
 class _PendingFrame:
@@ -219,7 +285,7 @@ class _PendingFrame:
     __slots__ = (
         "frame", "frame_dev", "future", "trace", "t_enq", "t_dispatch",
         "occupancy", "skipped", "readback", "seq", "pull_wait_s",
-        "coerce_s", "stage_s", "lock_wait_s", "hold_s",
+        "coerce_s", "stage_s", "lock_wait_s", "hold_s", "t_hold", "t_pickup",
     )
 
     def __init__(self, frame, trace=None, seq=0, pull_wait_s=None,
@@ -239,10 +305,13 @@ class _PendingFrame:
         self.coerce_s: float | None = coerce_s
         self.stage_s: float | None = None
         self.lock_wait_s: float | None = None
-        # how long the track held this frame's pull (server/tracks.py
-        # stamps it on the handle ``submit`` returned, which reaches the
-        # track through wrappers that pass no attribute of the session)
+        # how long the track held this frame's pull, when that hold began,
+        # and when its pull ended (the pick-up) — server/tracks.py stamps
+        # them on the handle ``submit`` returned, which reaches the track
+        # through wrappers that pass no attribute of the session
         self.hold_s: float | None = None
+        self.t_hold: float | None = None
+        self.t_pickup: float | None = None
         # (batch, row) of the _DispatchedBatch this frame rode — the
         # submitter resolves it directly at fetch, bypassing the future
         self.readback: tuple | None = None
@@ -477,7 +546,7 @@ class ScheduledSession:
                 out, src_frame,
                 safety_checker=owner.safety_checker, trace=trace,
             )
-        owner._fold_frame(handle, wait.seconds, finish.seconds)
+        owner._fold_frame(handle, wait.seconds, finish.seconds, finish.t1)
         return result
 
     def __call__(self, frame):
@@ -791,6 +860,18 @@ class BatchScheduler:
         # dispatch lock)
         self._batches: deque = deque(maxlen=16)
         self._throttled = False
+        # launched steps in dispatch order (put under the dispatch lock),
+        # None to stop: what the ready watcher blocks on one at a time.  A
+        # thread control queue, not the frame path: it holds the steps the
+        # device has not finished, which the in-flight cap bounds
+        self._ready_q: queue.SimpleQueue = queue.SimpleQueue()
+        # the steps the watcher has stamped and the counters have not yet
+        # booked (_book_ready_locked, at the next fetched frame or
+        # snapshot): the watcher itself takes no lock.  It holds the steps
+        # stamped since the last fetch, a few at most; past the bound (no
+        # fetch and no snapshot for 256 steps) the oldest stamps go
+        # unbooked, never a frame
+        self._stamped: deque = deque(maxlen=256)
         self._stats_lock = threading.Lock()
         # (bucket size, variant) pairs that have completed at least one
         # dispatch (or were prewarmed/AOT-adopted): a bucket's FIRST use
@@ -805,10 +886,12 @@ class BatchScheduler:
         # active sets).  Bounded: cleared wholesale if it ever grows past
         # 512 entries (possible only under pathological churn).
         self._idx_cache: dict = {}
-        # observability reservoirs (bounded; appended by the dispatcher
+        # observability reservoir (bounded; appended by the dispatcher
         # only, percentiles computed per snapshot over <=512 floats)
-        self._occ: deque = deque(maxlen=512)
         self._waits: deque = deque(maxlen=512)
+        # the counters' generation: a step launched before a reset is
+        # booked nothing, whenever its stamp lands
+        self._epoch = 0
         self._reset_counters_locked()
         # ONE template prepare, tiled: inactive rows are placeholders —
         # claim() installs a freshly prepared state before any frame runs
@@ -837,6 +920,11 @@ class BatchScheduler:
         # reset: the n-th ``rtc:launch`` span is the n-th ``jit_bucket``
         # event of the chip's in-order program stream (dispatch lock held)
         self._dispatch_seq = 0
+        # the newest step whose jitted call returned (dispatch lock), and
+        # the number of the newest step the ready watcher saw leave the
+        # device (_device_drained_locked)
+        self._newest: _DispatchedBatch | None = None
+        self._ready_seq = 0
         self._aot_adopted = False
         # -- engine fault domain (resilience/engine_guard.py) ---------------
         # duck-typed attach (attach_guard) — no construction-order coupling
@@ -887,6 +975,10 @@ class BatchScheduler:
             target=self._run, name="batchsched-dispatch", daemon=True
         )
         self._thread.start()
+        self._ready_thread = threading.Thread(
+            target=self._watch_ready, name="batchsched-ready", daemon=True
+        )
+        self._ready_thread.start()
 
     @classmethod
     def from_pipeline(cls, pipeline, **kw) -> "BatchScheduler":
@@ -2117,22 +2209,25 @@ class BatchScheduler:
         self._dispatch_entries_locked(entries, submitter, "inline_full")
 
     def _device_drained_locked(self) -> bool:
-        """Has every batch dispatched before now left the device?  True
-        when each earlier batch is resolved or its rows are all
-        ``is_ready()`` (a non-blocking question, no transfer): the step
-        about to be dispatched then finds the chip idle — a bubble the
-        host let open, counted with no profiler attached
-        (``batchsched_dispatch_starved_total``)."""
-        for b in self._batches:
-            if b.resolved:
-                continue
-            for r in b.rows:
-                try:
-                    if r is not None and not r.is_ready():
-                        return False
-                except (AttributeError, RuntimeError):
-                    pass  # not a device buffer any more: nothing in flight
-        return True
+        """Has every step launched before now left the device?  The ready
+        watcher's newest stamp answers it; where the watcher has not woken
+        yet, one non-blocking ``is_ready()`` of the newest step's output
+        does (steps leave the device in launch order), and a yes is kept
+        as that step's ``ready_by``.  The step about to be dispatched then
+        finds the chip idle — a bubble the host let open, counted with no
+        profiler attached (``batchsched_dispatch_starved_total``)."""
+        b = self._newest
+        if b is None or self._ready_seq >= b.step:
+            return True
+        out = b.out
+        try:
+            # the watcher drops the output only once the step is done
+            done = out is None or out.is_ready()
+        except (AttributeError, RuntimeError):
+            return True  # not a device buffer: nothing in flight
+        if done and b.ready_by is None:
+            b.ready_by = time.monotonic()
+        return done
 
     def _step_batch_locked(self, entries):
         """The ONE dispatch sequence both paths share (dispatcher loop and
@@ -2143,9 +2238,11 @@ class BatchScheduler:
         Caller holds the lock; a raising step is the caller's to deliver
         to the waiters.  Runs inside the caller's ``rtc:dispatch`` span
         and opens its children ``assemble``, ``launch`` and
-        ``readback_start``.  -> (rows, t_disp, feed, launch seconds):
+        ``readback_start``.  -> (rows, t_disp, feed, launch, out, k):
         ``feed`` False on a bucket variant's first use (a lazy compile may
-        ride it — not a capacity signal)."""
+        ride it — not a capacity signal); ``launch`` the finished hop
+        around the jitted call (its ``t0``, ``t1``), ``out`` the bucket's
+        output, which the ready watcher blocks on."""
         idx = [s for s, _ in entries]
         k = self._bucket_for(len(idx))
         with hop("assemble", k=k):
@@ -2251,7 +2348,7 @@ class BatchScheduler:
                     r.copy_to_host_async()
                 except (AttributeError, RuntimeError):
                     pass
-        return rows, t_disp, feed, launch.seconds
+        return rows, t_disp, feed, launch, out, k
 
     def _layout_pad(self, idx, k: int):
         """Bucket layout: which slot fills each of the k rows, and which
@@ -2414,11 +2511,14 @@ class BatchScheduler:
             )
 
     def _dispatch_entries_locked(
-        self, entries, submitter: "_PendingFrame | None", cause: str
+        self, entries, submitter: "_PendingFrame | None", cause: str,
+        window=None,
     ):
-        """Dispatch + hand every rider its per-slot readback.
+        """Dispatch + hand every rider its per-slot readback, and hand the
+        launched step to the ready watcher.
         ``cause``: why now, one of DISPATCH_CAUSES (the dispatch span and
-        ``batchsched_dispatch_cause_total`` carry it).
+        ``batchsched_dispatch_cause_total`` carry it).  ``window``: the
+        dispatcher's wait for riders before this dispatch, (start, end).
         ``submitter``: the EXACT pending whose submit is running this
         dispatch inline (None = dispatcher thread — every future gets the
         marker; there is no caller to re-raise into).  Identity matters:
@@ -2428,16 +2528,17 @@ class BatchScheduler:
         its future, so only the submitted pending itself may skip the
         future machinery (code-review r1)."""
         occ = len(entries)
+        frames = ",".join(f"{s}:{p.seq}" for s, plist in entries for p in plist)
         try:
             with hop(
                 "dispatch", k=self._bucket_for(occ), riders=occ, cause=cause,
-                frames=",".join(
-                    f"{s}:{p.seq}" for s, plist in entries for p in plist
-                ),
+                frames=frames,
             ) as dispatch:
                 inflight = self._batches_in_flight(dispatch.t0)
                 starved = self._device_drained_locked()
-                rows, t_disp, feed, launch_s = self._step_batch_locked(entries)
+                rows, t_disp, feed, launch, out, k = self._step_batch_locked(
+                    entries
+                )
         except Exception as e:
             # a dispatch failing must unblock EVERY rider's future (the
             # other sessions' fetches would otherwise hang out the full
@@ -2458,8 +2559,11 @@ class BatchScheduler:
             raise
         batch = _DispatchedBatch(
             rows, entries, t_disp, occ, feed, cause, inflight, starved,
-            dispatch.seconds, launch_s,
+            dispatch.seconds, (launch.t0, launch.t1), out, self._dispatch_seq,
+            k, frames, submitter, window, self._epoch,
         )
+        self._newest = batch
+        self._ready_q.put(batch)
         self._maybe_bank_rows_locked()
         if any(b.resolved for b in self._batches):
             # drop resolved batches WHEREVER they sit — the ring exists
@@ -2500,7 +2604,6 @@ class BatchScheduler:
         one session's readback never serializes another's.  The first
         resolver (any row) does the per-batch accounting."""
         out = batch.host[row]
-        d2h = 0
         if out is None:
             with batch.rlocks[row]:
                 out = batch.host[row]
@@ -2533,14 +2636,14 @@ class BatchScheduler:
                     # fetches never re-transfer, so this meter is the
                     # fetch-isolation story as a live counter
                     devtel.note_d2h(arr.nbytes)
-                    d2h = arr.nbytes
                     batch.host[row] = arr
                     batch.rows[row] = None  # release the device buffer
                     out = arr
         t1 = time.monotonic()
         first = False
         with self._stats_lock:
-            self._d2h_bytes += d2h  # what devtel was told, kept here too
+            if batch.ready_by is None or t1 < batch.ready_by:
+                batch.ready_by = t1  # the row is on the host: the step is done
             if not batch.resolved:
                 batch.resolved = True
                 first = True
@@ -2597,8 +2700,10 @@ class BatchScheduler:
                 # someone was missing when the dispatcher last looked, so
                 # the dispatch that follows is the window's ("window");
                 # otherwise every live session was ready and only the
-                # in-flight cap held the step back ("backpressure")
-                missed = False
+                # in-flight cap held the step back ("backpressure").
+                # ``waited``: from the first to the end of the last wait
+                # for riders since then, for the ready watcher's gap booking
+                missed, waited = False, None
                 while not self._stop:
                     waiting = self._waiting_slots()
                     g = self._guard
@@ -2616,7 +2721,7 @@ class BatchScheduler:
                         self._has_work.wait(timeout=0.1)
                         continue
                     if not waiting:
-                        missed = False
+                        missed, waited = False, None
                         self._has_work.wait(timeout=0.5)
                         continue
                     if (
@@ -2649,8 +2754,9 @@ class BatchScheduler:
                     )
                     if remain <= 0.0:
                         break  # window expired: go with who showed up
-                    with hop("window_wait", on="window"):
+                    with hop("window_wait", on="window") as w:
                         self._has_work.wait(timeout=remain)
+                    waited = (w.t0 if waited is None else waited[0], w.t1)
                 if self._stop:
                     break
                 entries = []
@@ -2660,7 +2766,8 @@ class BatchScheduler:
                         entries.append((s, plist))
                 if entries:
                     self._dispatch_entries_locked(
-                        entries, None, "window" if missed else "backpressure"
+                        entries, None, "window" if missed else "backpressure",
+                        waited,
                     )
         # drain on stop
         for q in self._queues:
@@ -2675,7 +2782,6 @@ class BatchScheduler:
         rehearse(): set-up's steps are not serving's).  Fixed key sets,
         so snapshot() copies them without the lock."""
         self.steps_total = 0
-        self._occ.clear()
         self._waits.clear()
         self._occ_hist: dict = {}
         self._hop_ms = dict.fromkeys(COUNTED_HOPS, 0.0)
@@ -2689,8 +2795,19 @@ class BatchScheduler:
         # /config or datachannel writes of a row's conditioning scale
         self._cnet_rows = 0
         self._cnet_scale_writes = 0
-        self._h2d_bytes = 0
-        self._d2h_bytes = 0
+        # from the ready watcher's stamps: by bucket k, the ready-to-ready
+        # milliseconds and count of the steps queued behind the one before
+        # (the device time of each); by GAP_CAUSES, the device's idle gaps
+        # between two steps; by RESIDENCE_PIECES, a fetched frame's time in
+        # the program in 0.1 ms bins (bin -> frames)
+        self._ready_ms: dict = {}
+        self._ready_n: dict = {}
+        self._gap_ms = dict.fromkeys(GAP_CAUSES, 0.0)
+        self._gap_max_ms = dict.fromkeys(GAP_CAUSES, 0.0)
+        self._gap_n = dict.fromkeys(GAP_CAUSES, 0)
+        self._res_hist = {piece: {} for piece in RESIDENCE_PIECES}
+        self._last_ready: float | None = None  # this generation's newest
+        self._epoch += 1
 
     def _count_hop_locked(self, name: str, seconds: float):
         ms = 1e3 * seconds
@@ -2699,12 +2816,19 @@ class BatchScheduler:
         if ms > self._hop_max_ms[name]:
             self._hop_max_ms[name] = ms
 
-    def _fold_frame(self, p: _PendingFrame, await_s: float, finish_s):
+    def _fold_frame(self, p: _PendingFrame, await_s: float, finish_s,
+                    t_end: float | None = None):
         """Fold one fetched frame's hop stamps into the counters: the one
         short critical section a frame that the counters add (the batch's
         own stamps ride _note_step, which the first resolver already
-        runs)."""
+        runs), and where the ready watcher's stamps wait to be booked.
+        ``t_end``: when its fetch returned the output; with the track's
+        pick-up stamp and the step it rode, the frame's residence is split
+        at the launch's return and the earliest sight of the step done (the
+        watcher's, or this fetch's own: the row is on the host).  A frame
+        the track did not stamp is left out."""
         with self._stats_lock:
+            self._book_ready_locked()
             for name, seconds in (
                 ("hold", p.hold_s), ("pull_wait", p.pull_wait_s),
                 ("coerce", p.coerce_s),
@@ -2714,32 +2838,41 @@ class BatchScheduler:
             ):
                 if seconds is not None:
                     self._count_hop_locked(name, seconds)
-            if p.stage_s is not None:
-                self._h2d_bytes += p.frame.nbytes  # as devtel.note_h2d
+            if t_end is None or p.t_pickup is None or p.readback is None:
+                return
+            batch = p.readback[0]
+            seen = [t for t in (batch.ready, batch.ready_by) if t is not None]
+            if seen:
+                self._fold_residence_locked(
+                    p.t_pickup, batch.launch[1], min(seen), t_end
+                )
+
+    def _fold_residence_locked(self, t_pick, t_launch, ready, t_end):
+        """One frame's residence, pick-up to fetch return, and its three
+        pieces: to the launch's return, on the device (to the step's ready
+        stamp, or the fetch's return where the stamp woke later), after it.
+        Each stamp is binned from the pick-up, so the pieces' bins sum to
+        the residence's bin exactly."""
+        ready = min(ready, t_end)
+        r, lo, d = (
+            int((t - t_pick) * _RES_BINS_PER_S) for t in (t_end, t_launch, ready)
+        )
+        for piece, b in zip(RESIDENCE_PIECES, (r, lo, d - lo, r - d)):
+            self._res_hist[piece] = _bump(self._res_hist[piece], b)
 
     def _note_step(self, dt_s: float, batch: _DispatchedBatch):
         occupancy, entries, feed = batch.occupancy, batch.entries, batch.feed
         with self._stats_lock:  # dispatcher + inline-fetch callers
             self.steps_total += 1
             self._count_hop_locked("dispatch", batch.dispatch_s)
-            self._count_hop_locked("launch", batch.launch_s)
+            self._count_hop_locked("launch", batch.launch[1] - batch.launch[0])
             self._cause_n[batch.cause] += 1
             self._inflight_n[min(batch.inflight, len(self._inflight_n) - 1)] += 1
             if batch.starved:
                 self._starved_n += 1
             if self.has_controlnet:
                 self._cnet_rows += occupancy
-            self._occ.append(occupancy)
-            # copy-on-new-key: snapshot() iterates this dict WITHOUT the
-            # stats lock (it must never block on a dispatch) — replacing
-            # the dict wholesale when a new occupancy first appears keeps
-            # every published dict iteration-safe forever after
-            if occupancy in self._occ_hist:
-                self._occ_hist[occupancy] += 1
-            else:
-                hist = dict(self._occ_hist)
-                hist[occupancy] = 1
-                self._occ_hist = hist
+            self._occ_hist = _bump(self._occ_hist, occupancy)
             for _, plist in entries:
                 for p in plist:
                     if p.t_dispatch is not None:
@@ -2756,11 +2889,101 @@ class BatchScheduler:
             except Exception:
                 logger.exception("batchsched on_step hook failed")
 
+    def _watch_ready(self):
+        """The ready watcher: takes the launched steps in dispatch order
+        and blocks until each has left the device, inside
+        ``rtc:step_ready`` (``step``, ``k``, ``frames`` as the step's
+        ``rtc:launch`` and ``rtc:dispatch`` carry them: in a profiler trace
+        the span ends after that step's ``jit_bucket`` event, by the wait
+        for the GIL).  Its end is one sighting of the step done
+        (_book_ready_locked books the earliest).  A step that raises is
+        booked nothing; the watcher lives on."""
+        while True:
+            batch = self._ready_q.get()
+            if batch is None:
+                return
+            ready = None
+            try:
+                with hop(
+                    "step_ready", step=batch.step, k=batch.k, frames=batch.frames
+                ) as wait:
+                    jax.block_until_ready(batch.out)
+                ready = wait.t1
+            except Exception:
+                pass  # the riders' fetches raise it; nothing to book
+            self._note_ready(batch, ready)
+
+    def _note_ready(self, batch: _DispatchedBatch, ready: float | None):
+        """The watcher's record of one step (``ready`` None: it raised):
+        drop the output, keep the stamp, hand the step to the counters.
+        No lock and no booking here: the watcher wakes when a step ends,
+        which is often while the next launch holds the GIL, so it holds
+        the GIL for as few bytecodes as it can."""
+        batch.out = None
+        batch.ready = ready
+        self._stamped.append(batch)
+        self._ready_seq = batch.step
+
+    def _book_ready_locked(self):
+        """Book the steps the watcher stamped since the last call, in
+        dispatch order (stats lock held).  A step's stamp is the earlier of
+        the watcher's wake-up and ``ready_by`` (a thread that held the GIL
+        while the step ended delays the watcher, not the step).  Against
+        the previous step's stamp: a step whose launch returned before it
+        was queued behind that step, so the device ran it from that stamp
+        to its own (``step_ready``); any other launch ended a gap of the
+        device, from that stamp to the launch's return (the call enqueues
+        the program before it returns, so its host time is the device's to
+        wait out), booked to the GAP_CAUSES member that covers most of
+        it."""
+        while self._stamped:
+            batch = self._stamped.popleft()
+            if batch.epoch != self._epoch:
+                continue  # launched before the counters' last reset
+            ready = batch.ready
+            if ready is not None and batch.ready_by is not None:
+                ready = batch.ready = min(ready, batch.ready_by)
+            prev, self._last_ready = self._last_ready, ready
+            if ready is None or prev is None:
+                continue
+            t1 = batch.launch[1]
+            if t1 < prev:
+                self._ready_ms = _bump(self._ready_ms, batch.k, 1e3 * (ready - prev))
+                self._ready_n = _bump(self._ready_n, batch.k)
+                continue
+            cause = _gap_cause(prev, t1, *self._gap_spans(batch))
+            ms = 1e3 * (t1 - prev)
+            self._gap_ms[cause] += ms
+            self._gap_n[cause] += 1
+            if ms > self._gap_max_ms[cause]:
+                self._gap_max_ms[cause] = ms
+
+    @staticmethod
+    def _gap_spans(batch: _DispatchedBatch):
+        """(pull_wait, hold, window_wait) intervals before a launch, each
+        (start, end) or None: the launching frame's track's wait for its
+        camera and its hold (the submitting frame of an inline dispatch,
+        else the rider picked up last), and the dispatcher's wait for
+        riders."""
+        p = batch.launcher
+        if p is None or p.t_pickup is None:
+            picked = [q for _, pl in batch.entries for q in pl if q.t_pickup is not None]
+            p = max(picked, key=lambda q: q.t_pickup, default=None)
+        pull = hold = None
+        if p is not None:
+            if p.pull_wait_s is not None:
+                pull = (p.t_pickup - p.pull_wait_s, p.t_pickup)
+            if p.t_hold is not None and p.hold_s is not None:
+                hold = (p.t_hold, p.t_hold + p.hold_s)
+        return pull, hold, batch.window
+
     def close(self):
         with self._has_work:
             self._stop = True
             self._has_work.notify()
         self._thread.join(timeout=10)
+        self._ready_q.put(None)
+        self._ready_thread.join(timeout=10)
 
     # -- observability --------------------------------------------------------
 
@@ -2770,10 +2993,13 @@ class BatchScheduler:
         return sorted_vals[min(n - 1, int(n * frac))]
 
     def snapshot(self) -> dict:
-        """/metrics gauges — O(1) int reads + two <=512-float reservoirs
-        (safe_list: the obs retry-copy idiom for lock-free appenders),
-        never a frame-queue traversal."""
-        occ = sorted(safe_list(self._occ))
+        """/metrics gauges — O(1) int reads, one <=512-float reservoir
+        (safe_list: the obs retry-copy idiom for lock-free appenders) and
+        the copy-on-new-key dicts, never a frame-queue traversal.  Books
+        the ready stamps not yet booked first (the stats lock, which no
+        dispatch holds), so the stamps' counters are current."""
+        with self._stats_lock:
+            self._book_ready_locked()
         waits = sorted(safe_list(self._waits))
         out = {
             "batchsched_sessions": self.active.count(True),
@@ -2801,8 +3027,30 @@ class BatchScheduler:
                 str(i): n for i, n in enumerate(list(self._inflight_n)) if n
             },
             "batchsched_dispatch_starved_total": self._starved_n,
-            "batchsched_h2d_bytes_total": self._h2d_bytes,
-            "batchsched_d2h_bytes_total": self._d2h_bytes,
+            # from the ready watcher's stamps (_book_ready_locked): by bucket k,
+            # the queued steps' ready-to-ready milliseconds and count; by
+            # GAP_CAUSES, the device's idle gaps between steps; a fetched
+            # frame's time in the program by piece (RESIDENCE_PIECES), bins
+            # of 0.1 ms keyed by their lower edge in ms
+            "batchsched_step_ready_ms_total": {
+                str(k): round(v, 4) for k, v in sorted(self._ready_ms.items())
+            },
+            "batchsched_step_ready_count": {
+                str(k): n for k, n in sorted(self._ready_n.items())
+            },
+            "batchsched_device_gap_ms_total": {
+                c: round(v, 4) for c, v in self._gap_ms.items()
+            },
+            "batchsched_device_gap_count": dict(self._gap_n),
+            "batchsched_device_gap_ms_max": {
+                c: round(v, 4) for c, v in self._gap_max_ms.items()
+            },
+            **{
+                f"batchsched_{piece}_ms_hist": {
+                    f"{b / 10:.1f}": n for b, n in sorted(hist.items())
+                }
+                for piece, hist in self._res_hist.items()
+            },
             "batchsched_attention_paths": dict(self.attention_paths),
             "batchsched_f32_relayout_copies": dict(self.relayout_copies),
             "batchsched_taesd_fold": dict(self.taesd_fold),
@@ -2832,9 +3080,6 @@ class BatchScheduler:
                 if live:
                     hist[str(self._slot_shard(s))] += 1
             out["batchsched_shard_sessions"] = hist
-        if occ:
-            out["batchsched_occupancy_p50"] = self._percentile(occ, 0.5)
-            out["batchsched_occupancy_max"] = occ[-1]
         if waits:
             out["batchsched_window_wait_ms_p50"] = round(
                 1e3 * self._percentile(waits, 0.5), 3

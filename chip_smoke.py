@@ -435,7 +435,7 @@ async def serve(run: Run, label: str) -> list:
             problems.append("batchsched_steps_total did not advance")
         if cold:
             hist = metrics.get("batchsched_occupancy_hist", {})
-            if not hist.get("1") or metrics.get("batchsched_occupancy_max", 0) < 2:
+            if not hist.get("1") or max(map(int, hist), default=0) < 2:
                 problems.append(
                     f"both the k=1 and the k=2 step must have run: occupancy {hist}")
             if a.last is not None and b.last is not None:

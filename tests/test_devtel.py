@@ -331,7 +331,7 @@ def test_rehearsed_scheduler_compiles_nothing_while_serving(bundle, cfg):
     try:
         s.rehearse()
         assert s.steps_total == 0 and s.snapshot()["batchsched_sessions"] == 0
-        assert "batchsched_occupancy_max" not in s.snapshot()
+        assert s.snapshot()["batchsched_occupancy_hist"] == {}
         assert s.window_s == 10.0 and s.free_slots == 2
         assert p.serving_compiles == 0 and p.warmup_compiles == p.compiles_total
 

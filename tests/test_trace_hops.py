@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from ai_rtc_agent_tpu.models import registry
+from ai_rtc_agent_tpu.obs import devtel
 from ai_rtc_agent_tpu.obs import trace as T
 from ai_rtc_agent_tpu.obs.trace import FrameTrace, STAGES, hop
 from ai_rtc_agent_tpu.stream.scheduler import (
@@ -122,11 +123,13 @@ def test_one_helper_builds_every_profiler_annotation():
 def test_bare_ndarray_session_counts_every_hop(bundle, cfg, rng):
     """No FrameTrace rides a bare ndarray, and every hop is still counted:
     each per-frame hop once a frame, each per-step hop once a step."""
+    plane = devtel.activate(devtel.DevTelPlane())
     s = _sched(bundle, cfg, max_sessions=1)
     try:
         a = s.claim("a", prompt="p", seed=1)
         n = 6
         handles = []
+        dev0 = plane.snapshot()
         for i in range(n):
             a.note_pull_wait(0.001 * (i + 1))
             handles.append(a.submit(_frame(rng)))
@@ -160,10 +163,13 @@ def test_bare_ndarray_session_counts_every_hop(bundle, cfg, rng):
         assert set(snap["batchsched_dispatch_inflight_hist"]) <= {"0", "1"}
         # the first step finds the device drained, by definition
         assert 1 <= snap["batchsched_dispatch_starved_total"] <= n
-        assert snap["batchsched_h2d_bytes_total"] == n * H * W * 3
-        assert snap["batchsched_d2h_bytes_total"] == n * H * W * 3
+        # the bytes each way are devtel's (devtel_h2d_bytes_total / _d2h_)
+        dev = plane.snapshot()
+        assert dev["devtel_h2d_bytes_total"] - dev0["devtel_h2d_bytes_total"] == n * H * W * 3
+        assert dev["devtel_d2h_bytes_total"] - dev0["devtel_d2h_bytes_total"] == n * H * W * 3
     finally:
         s.close()
+        devtel.deactivate(plane)
 
 
 def test_counters_are_cumulative_and_window_by_subtraction(bundle, cfg, rng):
