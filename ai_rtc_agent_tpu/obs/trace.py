@@ -87,6 +87,9 @@ STAGES = (
                           # pipeline the device paces (counter only)
     "pull_wait",          # the track's wait for its source (counter only:
                           # no span is held across an await)
+    "early_return",       # the track fetched a frame before its next pull,
+                          # the source pacing it with time to spare (counter
+                          # only)
     "coerce",             # duck-typed frame -> [H,W,3] uint8 (child of submit)
     "stage_h2d",          # stage_frame: the H2D copy is started (child of submit)
     "enqueue",            # scheduler lock + queue push; holds an inline dispatch

@@ -12,7 +12,7 @@ reference lib/tracks.py:21-25) and the DROP_FRAMES OBS-stutter workaround
   TPU step can NEVER stall the event loop (the reference blocks its loop on
   GPU inference inside recv(), lib/tracks.py:24,38 — SURVEY.md hazard list).
   Ordering stays strict because recv() calls are serialized per track.
-* PIPELINE_DEPTH frames are kept in flight on the device (pipeline
+* Up to PIPELINE_DEPTH frames are kept in flight on the device (pipeline
   submit/fetch): recv() submits the new frame, then fetches the result of
   the frame submitted `depth` calls ago — dispatch, device compute and
   readback overlap across consecutive frames, which is where the TPU's
@@ -22,6 +22,16 @@ reference lib/tracks.py:21-25) and the DROP_FRAMES OBS-stutter workaround
   it binds is the freshest the source has at the last moment that still
   keeps the device fed, instead of one that waits a whole step in the
   device's queue while fresher ones are thrown away.
+* While the source sets the pace with time to spare, the frame in flight
+  is fetched BEFORE the next pull, and returned as soon as its step is
+  done rather than a source period later, when the next frame has been
+  pulled and submitted.  The track reads it from its own clock and the
+  ready stamp the session hands back on the handle (``_spare``): the
+  newest pull still waited for its source by more than the lead after
+  the newest frame's step came back.  The pulls land where they did, at
+  the source's pace; where the pull stops waiting (the camera outpaces
+  the step, the host stalled) the pipeline fills back to depth at the
+  next call.
 
 Overload control (resilience/overload.py): the track is the INGEST hop of
 the frame path.  When an ``overload`` control plane is attached, every
@@ -123,23 +133,32 @@ class _PullHold:
         self.seconds = max(0.0, min(self.seconds + step, cap))
 
 
-def _stamp_handle(handle, **stamps):
-    """The track's own stamps of a frame ride the handle ``submit``
-    returned, to be folded into the scheduler's counters when it is
-    fetched: ``t_pickup`` (its pull ended), and for a held pull ``t_hold``
-    (the hold began) and ``hold_s`` (hop ``hold``).  A wrapper around the
-    session hands its handle on as it is (the benchmark's) or inside a
-    tuple of its own (the supervisor's, the agent's timing one); another
-    pipeline's handle has no place for them."""
+def _session_frame(handle):
+    """The session's own record of a frame inside the handle ``submit``
+    returned, or None.  A wrapper around the session hands its handle on
+    as it is (the benchmark's) or inside a tuple of its own (the
+    supervisor's, the agent's timing one); another pipeline's handle has
+    no such record."""
     stack = [handle]
     while stack:
         h = stack.pop()
         if isinstance(h, tuple):
             stack.extend(h)
         elif hasattr(h, "hold_s"):
-            for name, value in stamps.items():
-                setattr(h, name, value)
-            return
+            return h
+    return None
+
+
+def _stamp_handle(handle, **stamps):
+    """The track's own stamps of a frame ride its session's record, to be
+    folded into the scheduler's counters when it is fetched: ``t_pickup``
+    (its pull ended), for a held pull ``t_hold`` (the hold began) and
+    ``hold_s`` (hop ``hold``), for a frame fetched before the next pull
+    ``early_s`` (hop ``early_return``)."""
+    rec = _session_frame(handle)
+    if rec is not None:
+        for name, value in stamps.items():
+            setattr(rec, name, value)
 
 
 class VideoStreamTrack:
@@ -174,6 +193,11 @@ class VideoStreamTrack:
         self._hold = _PullHold()
         self._clock = time.monotonic
         self._sleep = asyncio.sleep
+        # whether the newest pull found no frame in flight (its wait then
+        # came after the last fetch), and the newest fetched frame's
+        # submit-to-ready (None: its handle carried no ready stamp)
+        self._pulled_empty = False
+        self._ready_s = None
         # in-flight bound: the submit loops below never hold more than
         # `pipeline_depth` entries (single-frame path) / batches (fbs path)
         self._pending: deque = deque(maxlen=self.pipeline_depth)
@@ -264,6 +288,24 @@ class VideoStreamTrack:
             ov.note_delivered(ov.frame_age(frame))
         return frame
 
+    def _spare(self) -> float | None:
+        """The pipelined ``recv()``'s choice: the seconds the track expects
+        to wait for its source after the frame in flight comes back, where
+        that is more than the lead (the frame is then fetched before the
+        next pull), else None (the pipeline is filled to depth).  The newest
+        pull's wait, less the newest fetched frame's time from its submit's
+        return to its step's ready stamp where that pull had a frame in
+        flight; the wait alone where it had none, since the fetch then came
+        before it.  The pulls land where they would have: the source paces
+        them either way.  A handle with no ready stamp never starts it."""
+        if self._pulled_empty:
+            spare = self._pull_wait_s
+        elif self._ready_s is None:
+            return None
+        else:
+            spare = self._pull_wait_s - self._ready_s
+        return spare if spare > _HOLD_LEAD_S else None
+
     async def recv(self):
         fbs = self._fbs
         if fbs > 1 and hasattr(self.pipeline, "submit_batch"):
@@ -289,15 +331,19 @@ class VideoStreamTrack:
                 return out.frame
             return out
 
-        # pipelined path: keep `depth` frames in flight, return the oldest
+        # pipelined path: up to `depth` frames in flight, the oldest
+        # returned; one, the frame just submitted, while the source paces
+        # the track with time to spare
+        spare = self._spare()
+        depth = self.pipeline_depth if spare is None else 1
         hold, clock = self._hold, self._clock
-        while len(self._pending) < self.pipeline_depth:
+        while len(self._pending) < depth:
             t_held, held = clock(), hold.seconds > 0.0
             if held:
                 # on the event loop, yielding: a counter only (hop
                 # ``hold``), as ``pull_wait``: no span across an await
                 await self._sleep(hold.seconds)
-            t_pull = clock()
+            t_pull, self._pulled_empty = clock(), not self._pending
             frame = await self._pull_fresh()
             t_pickup = clock()
             handle = await asyncio.to_thread(self.pipeline.submit, frame)
@@ -307,12 +353,22 @@ class VideoStreamTrack:
                 )
             else:
                 _stamp_handle(handle, t_pickup=t_pickup)
-            self._pending.append((frame, handle))
-        src, handle = self._pending.popleft()
+            self._pending.append((frame, handle, clock()))
+        src, handle, t_submitted = self._pending.popleft()
+        if spare is not None:
+            _stamp_handle(handle, early_s=spare)
         t_fetch = clock()
         out = await asyncio.to_thread(self.pipeline.fetch, handle, src)
         now = clock()
-        hold.observe(now - t_pull, now - t_fetch, self._pull_wait_s, now)
+        if spare is None:
+            # the hold reads a fetch with a newer frame in flight behind it.
+            # (None is held while the track returns early: its pulls wait.
+            # The first fetch after that comes a long cycle after the last
+            # one the hold read, so it holds nothing either)
+            hold.observe(now - t_pull, now - t_fetch, self._pull_wait_s, now)
+        # when the step it rode was first seen done, where the handle says
+        t_ready = getattr(_session_frame(handle), "t_ready", None)
+        self._ready_s = None if t_ready is None else t_ready - t_submitted
         if isinstance(out, ShedFrame):
             # unsupervised tier (SUPERVISOR=0): no resilience wrapper to
             # unwrap the bounded-queue shed marker — deliver the pixels

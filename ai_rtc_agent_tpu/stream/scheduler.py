@@ -147,7 +147,7 @@ SESSION_SNAPSHOT_SCHEMA = 2
 # from the obs/trace.py STAGES taxonomy, the closed key set of the counters
 COUNTED_HOPS = (
     "hold", "pull_wait", "coerce", "stage_h2d", "enqueue_lock_wait", "dispatch",
-    "launch", "await_row", "finish_output", "encode_prompt",
+    "launch", "await_row", "finish_output", "encode_prompt", "early_return",
 )
 # why a step was dispatched when it was (``batchsched_dispatch_cause_total``):
 # solo = the one-live-session path in _enqueue; inline_full = this submit
@@ -286,6 +286,7 @@ class _PendingFrame:
         "frame", "frame_dev", "future", "trace", "t_enq", "t_dispatch",
         "occupancy", "skipped", "readback", "seq", "pull_wait_s",
         "coerce_s", "stage_s", "lock_wait_s", "hold_s", "t_hold", "t_pickup",
+        "early_s",
     )
 
     def __init__(self, frame, trace=None, seq=0, pull_wait_s=None,
@@ -312,9 +313,24 @@ class _PendingFrame:
         self.hold_s: float | None = None
         self.t_hold: float | None = None
         self.t_pickup: float | None = None
+        # the track fetched this frame before its next pull: the seconds it
+        # then expected to wait for its source (hop ``early_return``)
+        self.early_s: float | None = None
         # (batch, row) of the _DispatchedBatch this frame rode — the
         # submitter resolves it directly at fetch, bypassing the future
         self.readback: tuple | None = None
+
+    @property
+    def t_ready(self) -> float | None:
+        """The earliest sight of the step this frame rode done (the ready
+        watcher's wake-up, a drain check, a fetch with its row on the
+        host), for the track's choice of when to fetch; None before any,
+        or for a frame that rode no step."""
+        if self.readback is None:
+            return None
+        batch = self.readback[0]
+        seen = [t for t in (batch.ready, batch.ready_by) if t is not None]
+        return min(seen) if seen else None
 
 
 class ScheduledSession:
@@ -2830,7 +2846,8 @@ class BatchScheduler:
         with self._stats_lock:
             self._book_ready_locked()
             for name, seconds in (
-                ("hold", p.hold_s), ("pull_wait", p.pull_wait_s),
+                ("hold", p.hold_s), ("early_return", p.early_s),
+                ("pull_wait", p.pull_wait_s),
                 ("coerce", p.coerce_s),
                 ("stage_h2d", p.stage_s),
                 ("enqueue_lock_wait", p.lock_wait_s),
@@ -2840,11 +2857,10 @@ class BatchScheduler:
                     self._count_hop_locked(name, seconds)
             if t_end is None or p.t_pickup is None or p.readback is None:
                 return
-            batch = p.readback[0]
-            seen = [t for t in (batch.ready, batch.ready_by) if t is not None]
-            if seen:
+            ready = p.t_ready
+            if ready is not None:
                 self._fold_residence_locked(
-                    p.t_pickup, batch.launch[1], min(seen), t_end
+                    p.t_pickup, p.readback[0].launch[1], ready, t_end
                 )
 
     def _fold_residence_locked(self, t_pick, t_launch, ready, t_end):

@@ -137,6 +137,9 @@ def test_bare_ndarray_session_counts_every_hop(bundle, cfg, rng):
                 handles[-1].hold_s = 0.002 * (i + 1)
             if len(handles) == 2:  # depth 2, as the track keeps it
                 a.fetch(handles.pop(0))
+        # the last frame fetched before a next pull, as the track does
+        # where its source leaves time to spare
+        handles[-1].early_s = 0.009
         for h in handles:
             a.fetch(h)
         snap = s.snapshot()
@@ -150,6 +153,8 @@ def test_bare_ndarray_session_counts_every_hop(bundle, cfg, rng):
         assert count["hold"] == n - 2  # the frames whose pull was held
         assert ms["hold"] == pytest.approx(2.0 * (3 + 4 + 5 + 6), rel=1e-6)
         assert snap["batchsched_hop_ms_max"]["hold"] == pytest.approx(12.0)
+        assert count["early_return"] == 1
+        assert ms["early_return"] == pytest.approx(9.0)
         assert count["dispatch"] == count["launch"] == snap["batchsched_steps_total"] == n
         assert ms["pull_wait"] == pytest.approx(1.0 + 2 + 3 + 4 + 5 + 6, rel=1e-6)
         assert snap["batchsched_hop_ms_max"]["pull_wait"] == pytest.approx(6.0)

@@ -5,6 +5,7 @@ latest-wins source, a fake sleep that only moves the clock.  No test sleeps
 on the wall clock."""
 
 import asyncio
+import time
 import types
 
 import numpy as np
@@ -55,6 +56,7 @@ class World:
             end=start + self.short_steps.get(n, self.step_s),
             slack=self.free_at - self.now,   # landed this long before the
             hold_s=None,                     # running step ended
+            early_s=None, t_ready=None,
         )
         self.free_at = rec.end
         self.steps.append(rec)
@@ -62,6 +64,7 @@ class World:
 
     def fetch(self, rec, src_frame=None):
         self.now = max(self.now, rec.end + self.tail_s)
+        rec.t_ready = rec.end   # the step's ready stamp rides its record
         return ("stylized", rec.frame)
 
     def __call__(self, frame):
@@ -203,6 +206,9 @@ def test_depth_three_is_held_by_the_same_law():
 
 @pytest.mark.parametrize("step_ms,fps", [(18, 30), (26, 30), (5, 60), (52, 15)])
 def test_source_bound_loop_holds_nothing_and_pulls_as_the_parent(step_ms, fps):
+    """Where the track returns its frames early it keeps one frame fewer in
+    flight, so its 30 returns take one pull fewer: the parent is driven
+    until it has pulled as many."""
     w = World(step_ms * MS, period_s=1 / fps)
     _drive(_track(w), 30)
     assert w.sleeps == []
@@ -212,7 +218,7 @@ def test_source_bound_loop_holds_nothing_and_pulls_as_the_parent(step_ms, fps):
 
     async def parent():
         pending = []
-        for _ in range(30):
+        while len(ref.pulled) < len(w.pulled):
             await _parent_recv(ref, pending)
 
     asyncio.run(parent())
@@ -402,6 +408,193 @@ def test_the_seconds_reach_a_handle_that_the_agents_wrappers_nest():
     assert [r.hold_s for r in w.steps if r.hold_s] == pytest.approx(w.sleeps)
 
 
+# (g) below the knee a frame returns when its step is done --------------------
+
+def _drive_timed(track, world, n):
+    """n returns -> {frame: when it was returned}, in the order returned."""
+    returned = {}
+
+    async def go():
+        for _ in range(n):
+            out = await track.recv()
+            assert out[1] not in returned   # never returned twice
+            returned[out[1]] = world.now
+
+    asyncio.run(go())
+    return returned
+
+
+def _returned_early(world, returned):
+    """{frame: whether it was returned early} of the frames a drive returned."""
+    recs = {r.frame: r for r in world.steps}
+    return {k: recs[k].early_s is not None for k in returned}
+
+
+def _parent_run(world, pulls):
+    """The parent's pipelined loop on ``world`` until it has pulled
+    ``pulls`` frames -> {frame: when it was returned}."""
+    returned = {}
+
+    async def go():
+        pending = []
+        while len(world.pulled) < pulls:
+            out = await _parent_recv(world, pending)
+            returned[out[1]] = world.now
+
+    asyncio.run(go())
+    return returned
+
+
+@pytest.mark.parametrize("step_ms,fps", [(18, 15), (18, 30), (26, 24), (52, 15), (5, 60)])
+def test_below_the_knee_a_frame_returns_when_its_step_is_done(step_ms, fps):
+    """From the third frame on every frame comes back its submit, its step
+    and the fetch's tail after its pull, not a source period later; the
+    source hands out the same frames at the same instants, and the device
+    runs the same steps at the same times, as under the parent."""
+    w = World(step_ms * MS, period_s=1 / fps)
+    track = _track(w)
+    returned = _drive_timed(track, w, 30)
+    when_pulled = dict(w.pulled)
+    assert list(returned) == [k for k, _ in w.pulled]   # in order, none lost
+    for k in list(returned)[2:]:
+        assert returned[k] - when_pulled[k] == pytest.approx(
+            w.submit_s + w.step_s + w.tail_s
+        ), k
+    assert w.sleeps == []
+    assert all(r.early_s > LEAD for r in w.steps[1:]) and w.steps[0].early_s is None
+
+    ref = World(step_ms * MS, period_s=1 / fps)
+    parent = _parent_run(ref, len(w.pulled))
+    assert w.pulled == ref.pulled
+    assert [r.end for r in w.steps] == [r.end for r in ref.steps]
+    # the parent returned each of them the next frame's pull and submit later
+    for k in list(returned)[2:-1]:
+        assert parent[k] - returned[k] == pytest.approx(
+            w.period_s - w.step_s - w.tail_s
+        ), k
+
+
+@pytest.mark.parametrize("world", [
+    # turbo512.solo60's host and step: the camera's period less the submit
+    # is about the step, nowhere near the lead to spare
+    dict(step_s=14.45 * MS, period_s=1 / 60, submit_s=2.35 * MS, tail_s=1.0 * MS),
+    # 3.8 ms to spare after the submit, the step and the tail: under the lead
+    dict(step_s=26 * MS, period_s=1 / 30),
+])
+def test_at_the_knee_it_never_returns_early(world):
+    w = World(**world)
+    track = _track(w)
+    returned = _drive_timed(track, w, 40)
+    assert all(r.early_s is None for r in w.steps)
+    ref = World(**world)
+    assert _parent_run(ref, len(w.pulled)) == returned
+    assert w.pulled == ref.pulled
+    assert [r.end for r in w.steps] == [r.end for r in ref.steps]
+
+
+@pytest.mark.parametrize("step_ms", [18, 26, 52, 100])
+def test_a_device_paced_loop_never_returns_early(step_ms):
+    w = World(step_ms * MS, period_s=1 / 120)
+    track = _track(w)
+    _drive(track, 40)
+    assert all(r.early_s is None for r in w.steps)
+    assert len(w.sleeps) == 38
+
+
+def test_a_camera_that_outpaces_the_step_ends_it_within_one_frame():
+    """Early at 15 fps, then the camera delivers 120: the frame in flight
+    comes back early, the next call fills the pipeline to depth again, the
+    hold takes over, and the device is never left idle again.  Every frame
+    pulled is returned once, in order."""
+    w = World(18 * MS, period_s=1 / 15)
+    track = _track(w)
+    returned = _drive_timed(track, w, 10)
+    assert w.steps[-1].early_s is not None
+    w.period_s = 1 / 120
+    n = len(w.steps)
+    modes = []
+    for _ in range(30):
+        modes.append(_returned_early(w, _drive_timed(track, w, 1)))
+        returned.update({k: None for k in modes[-1]})
+    modes = [early for m in modes for early in m.values()]
+    assert modes[0] and not any(modes[1:])
+    assert list(returned) == [k for k, _ in w.pulled][:len(returned)]
+    assert all(r.idle == 0.0 for r in w.steps[n + 2:])
+    assert w.sleeps and max(w.sleeps) < w.step_s
+    assert w.steps[-2].slack == pytest.approx(w.slack_wanted, abs=0.05 * MS)
+
+
+def test_a_stalled_fetch_ends_it_within_one_frame_and_it_comes_back():
+    """The host stalls for 300 ms inside an early fetch: the next pull does
+    not wait (the source has frames due), so the call after it fills to
+    depth; the one after that finds the camera pacing again and returns
+    early.  No frame is lost, reordered or returned twice."""
+    w = World(18 * MS, period_s=1 / 15)
+    track = _track(w)
+    returned = _drive_timed(track, w, 10)
+    fetch = w.fetch
+
+    def stalled(rec, src=None):
+        w.fetch = fetch
+        out = fetch(rec, src)
+        w.now += 0.3
+        return out
+
+    w.fetch = stalled
+    modes = []
+    for _ in range(6):
+        modes.append(_returned_early(w, _drive_timed(track, w, 1)))
+        returned.update({k: None for k in modes[-1]})
+    # the stalled call, the one whose pull found frames due, the refill,
+    # then early again
+    modes = [early for m in modes for early in m.values()]
+    assert modes == [True, True, False, True, True, True]
+    assert list(returned) == [k for k, _ in w.pulled][:len(returned)]
+    assert w.sleeps == []
+
+
+def test_a_handle_with_no_ready_stamp_never_returns_early():
+    """Below the knee all the same: the tupled handle has no record to carry
+    the stamp, so the track keeps the parent's fixed depth."""
+    w = World(18 * MS, period_s=1 / 15)
+    recs = {}
+
+    class Tupled:
+        def submit(self, frame):
+            recs[frame] = w.submit(frame)
+            return ("dev", np.zeros(3), frame, True)
+
+        def fetch(self, handle, src=None):
+            return w.fetch(recs.pop(handle[2]), src)
+
+    track = _track(w, pipeline=Tupled())
+    returned = _drive_timed(track, w, 20)
+    ref = World(18 * MS, period_s=1 / 15)
+    assert _parent_run(ref, len(w.pulled)) == returned
+
+
+def test_the_ready_stamp_reaches_through_the_agents_wrappers():
+    """``(("live", handle, frame), t_submit)``, as ``_TimedPipeline`` nests
+    the supervisor's handle: read there, and stamped there."""
+    w = World(18 * MS, period_s=1 / 15)
+
+    class Nested:
+        def submit(self, frame):
+            return ("live", w.submit(frame), frame), 12.5
+
+        def fetch(self, handle, src=None):
+            return w.fetch(handle[0][1], src)
+
+    track = _track(w, pipeline=Nested())
+    returned = _drive_timed(track, w, 12)
+    assert all(r.early_s > LEAD for r in w.steps[1:])
+    when_pulled = dict(w.pulled)
+    for k in list(returned)[2:]:
+        assert returned[k] - when_pulled[k] == pytest.approx(
+            w.submit_s + w.step_s + w.tail_s
+        )
+
+
 # (f) under the benchmark's wrapper, into the scheduler's counters -------------
 
 class NarrowWrapper:
@@ -412,6 +605,7 @@ class NarrowWrapper:
 
     def __init__(self, inner, world):
         self._inner, self._world, self._recs = inner, world, {}
+        self.fetched = []   # the session's handles, in the order fetched
 
     @property
     def frame_buffer_size(self):
@@ -424,7 +618,12 @@ class NarrowWrapper:
 
     def fetch(self, handle, src_frame=None):
         out = self._inner.fetch(handle, src_frame)
-        self._world.fetch(self._recs.pop(id(handle)))
+        rec = self._recs.pop(id(handle))
+        self._world.fetch(rec)
+        # the fake device's end is the step's earliest sight done, on the
+        # track's clock (the real step's stamps are on the wall clock)
+        handle.readback[0].ready_by = rec.end
+        self.fetched.append(handle)
         return out
 
     def __call__(self, frame):
@@ -498,5 +697,70 @@ def test_source_bound_session_counts_no_hold(sched, rng):
         after = sched.snapshot()["batchsched_hop_count"]
         assert after["await_row"] - before["await_row"] == 10
         assert after["hold"] == before["hold"] and w.sleeps == []
+    finally:
+        sess.release()
+
+
+def test_early_return_under_a_narrow_wrapper_and_the_scheduler_counts_it(sched, rng):
+    """Below the knee, a real session: every frame but the first is fetched
+    right after its submit, and hop ``early_return`` counts each of them
+    at its fetch, with the spare the track read; nothing is held."""
+    w = World(18 * MS, period_s=1 / 15)
+    sess = sched.claim("early", prompt="p", seed=3)
+    try:
+        wrapped = NarrowWrapper(sess, w)
+        track = _track(w, pipeline=wrapped)
+        track.track = _real_frames(w, rng)
+        before = sched.snapshot()
+        outs = _drive(track, 12)
+        assert all(o.shape == (32, 32, 3) and o.dtype == np.uint8 for o in outs)
+        snap = sched.snapshot()
+        count, ms = snap["batchsched_hop_count"], snap["batchsched_hop_ms_total"]
+        b_count, b_ms = before["batchsched_hop_count"], before["batchsched_hop_ms_total"]
+        assert count["await_row"] - b_count["await_row"] == 12
+        assert count["early_return"] - b_count["early_return"] == 11
+        spares = [h.early_s for h in wrapped.fetched]
+        assert spares[0] is None and all(s > LEAD for s in spares[1:])
+        assert ms["early_return"] - b_ms["early_return"] == pytest.approx(
+            1e3 * sum(spares[1:]), rel=1e-6
+        )
+        # once a pull has come after an early fetch, the spare is the
+        # camera's period less the submit, the step and the fetch's tail
+        assert spares[3:] == pytest.approx(
+            [w.period_s - w.submit_s - w.step_s - w.tail_s] * 9
+        )
+        assert count["hold"] == b_count["hold"] and w.sleeps == []
+    finally:
+        sess.release()
+
+
+def test_a_session_at_the_knee_counts_no_early_return(sched, rng):
+    w = World(14.45 * MS, period_s=1 / 60, submit_s=2.35 * MS, tail_s=1.0 * MS)
+    sess = sched.claim("knee", prompt="p", seed=4)
+    try:
+        track = _track(w, pipeline=NarrowWrapper(sess, w))
+        track.track = _real_frames(w, rng)
+        before = sched.snapshot()["batchsched_hop_count"]
+        _drive(track, 12)
+        after = sched.snapshot()["batchsched_hop_count"]
+        assert after["await_row"] - before["await_row"] == 12
+        assert after["early_return"] == before["early_return"]
+    finally:
+        sess.release()
+
+
+def test_a_fetched_frame_carries_its_steps_ready_stamp(sched, rng):
+    """On the wall clock, with no fake device: the stamp lies between the
+    frame's enqueue and its fetch's return; a frame that rode no step has
+    none."""
+    from ai_rtc_agent_tpu.stream.scheduler import _PendingFrame
+
+    sess = sched.claim("stamp", prompt="p", seed=5)
+    try:
+        handle = sess.submit(rng.integers(0, 256, (32, 32, 3), np.uint8))
+        sess.fetch(handle)
+        t_back = time.monotonic()
+        assert handle.t_enq <= handle.t_ready <= t_back
+        assert _PendingFrame(np.zeros((32, 32, 3), np.uint8)).t_ready is None
     finally:
         sess.release()
