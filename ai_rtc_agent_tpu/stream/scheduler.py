@@ -153,8 +153,14 @@ COUNTED_HOPS = (
 # solo = the one-live-session path in _enqueue; inline_full = this submit
 # completed the batch; window = the dispatcher went with who showed up;
 # backpressure = the dispatcher, every live session ready, held back only
-# by the in-flight cap until a batch resolved
-DISPATCH_CAUSES = ("solo", "inline_full", "window", "backpressure")
+# by the in-flight cap until a batch resolved; none_due = a live session
+# was missing, but the device had drained and no missing session's frame
+# was predicted inside the window, so the step went without waiting it
+# out (in _enqueue, or by the dispatcher once the device drained)
+DISPATCH_CAUSES = ("solo", "inline_full", "window", "backpressure", "none_due")
+# enqueue stamps kept a slot to predict its next frame from (_due_before):
+# four intervals, so a host stall keeps its slot "due" four frames at most
+_ENQ_STAMPS = 5
 # what the host was doing while the device sat idle between two steps
 # (``batchsched_device_gap_*``): pull_wait = the launching frame's track
 # waited for its camera; hold = that track held the pull; window_wait = the
@@ -199,6 +205,20 @@ def _gap_cause(g0: float, g1: float, pull_wait, hold, window_wait) -> str:
         end = max(end, hi)
     cover["host"] = (g1 - g0) - union
     return max(GAP_CAUSES, key=cover.get)
+
+
+def _due_before(stamps, until: float) -> bool:
+    """Is a session whose newest enqueues were at ``stamps`` (oldest
+    first) predicted to enqueue again before ``until``?  Its next frame is
+    expected one shortest recent interval after its newest, less the
+    intervals' spread (longest minus shortest: the jitter it has shown).
+    With fewer than two intervals on record nothing is known: yes."""
+    if len(stamps) < 3:
+        return True
+    t = list(stamps)
+    ivs = [b - a for a, b in zip(t, t[1:])]
+    lo = min(ivs)
+    return t[-1] + lo - (max(ivs) - lo) < until
 
 
 class CapacityError(RuntimeError):
@@ -870,6 +890,10 @@ class BatchScheduler:
             DeadlineQueue(self.queue_bound, on_evict=self._evict)
             for _ in range(S)
         ]
+        # per slot, its session's newest enqueue stamps (the dispatch
+        # lock; cleared at claim and release): _none_due_locked predicts a
+        # missing session's next frame from them
+        self._enq_stamps = [deque(maxlen=_ENQ_STAMPS) for _ in range(S)]
         # guards the template engine during heavy builds (text-encode +
         # prepare); deliberately separate from the step/states lock
         self._heavy_lock = threading.Lock()
@@ -886,6 +910,9 @@ class BatchScheduler:
         # dispatch lock)
         self._batches: deque = deque(maxlen=16)
         self._throttled = False
+        # _windowing: the dispatcher is waiting out a coalescing window; the
+        # ready watcher wakes it when a step leaves the device
+        self._windowing = False
         # launched steps in dispatch order (put under the dispatch lock),
         # None to stop: what the ready watcher blocks on one at a time.  A
         # thread control queue, not the frame path: it holds the steps the
@@ -1097,6 +1124,7 @@ class BatchScheduler:
             with self._has_work:
                 self._install_locked(slot, state)
                 self._sessions[slot] = sess
+                self._enq_stamps[slot].clear()
         except Exception:
             # a failed install (e.g. states poisoned by a concurrent step
             # failure) must not leak the slot into permanent 503s
@@ -1140,6 +1168,7 @@ class BatchScheduler:
         with self._lock:
             self.active[slot] = False
             self._sessions.pop(slot, None)
+            self._enq_stamps[slot].clear()
         # drain this slot's window outside the step lock; waiters (there
         # should be none on an orderly teardown) unblock as cancelled
         q = self._queues[slot]
@@ -1404,6 +1433,7 @@ class BatchScheduler:
                 # tick without perturbing its existing riders)
                 self._install_locked(slot, row)
                 self._sessions[slot] = sess
+                self._enq_stamps[slot].clear()
         except Exception:
             with self._lock:
                 self.active[slot] = False
@@ -2185,6 +2215,7 @@ class BatchScheduler:
             return
         with hop("enqueue", slot=slot, seq=pending.seq) as enqueue, self._has_work:
             pending.lock_wait_s = time.monotonic() - enqueue.t0
+            self._enq_stamps[slot].append(pending.t_enq)
             room = (
                 self._batches_in_flight(pending.t_enq) < self.PIPELINE_DEPTH
             )
@@ -2203,14 +2234,18 @@ class BatchScheduler:
                 )
                 return
             self._queues[slot].push(pending, stamp=pending.t_enq)
-            if room and len(self._waiting_slots()) >= self.active.count(
-                True
-            ):
+            waiting = self._waiting_slots()
+            if room and len(waiting) >= self.active.count(True):
                 # fast path: THIS frame completed the batch (every live
                 # session has a full fbs group waiting) — dispatch NOW on
                 # the caller thread: no window, no dispatcher handoff;
                 # each rider's fetch resolves its own per-slot row
-                self._dispatch_inline_locked(pending)
+                self._dispatch_inline_locked(pending, "inline_full")
+                return
+            if room and self._none_due_locked(waiting):
+                # the window would buy nothing: the same inline dispatch
+                # with who is waiting
+                self._dispatch_inline_locked(pending, "none_due")
                 return
             self._has_work.notify()
 
@@ -2230,7 +2265,7 @@ class BatchScheduler:
             plist.append(got[0])
         return plist or None
 
-    def _dispatch_inline_locked(self, submitter: _PendingFrame):
+    def _dispatch_inline_locked(self, submitter: _PendingFrame, cause: str):
         entries = []
         for s in self._waiting_slots():
             plist = self._pop_group(s)
@@ -2238,7 +2273,26 @@ class BatchScheduler:
                 entries.append((s, plist))
         if not entries:
             return
-        self._dispatch_entries_locked(entries, submitter, "inline_full")
+        self._dispatch_entries_locked(entries, submitter, cause)
+
+    def _none_due_locked(self, waiting) -> bool:
+        """May the partial batch of ``waiting`` go now rather than wait
+        out the window (from its oldest enqueue)?  Only where waiting
+        would buy nothing: no live session missing from it is predicted
+        to enqueue before the window ends (``_due_before``), and the
+        device has drained, so waiting buys no device time, only latency.
+        ``fbs > 1`` keeps its window."""
+        oldest = self._oldest_enqueue(waiting)
+        if self.fbs != 1 or self.window_s <= 0.0 or oldest is None:
+            return False
+        until = oldest + self.window_s
+        if any(
+            _due_before(self._enq_stamps[s], until)
+            for s in range(self.max_sessions)
+            if self.active[s] and s not in waiting
+        ):
+            return False
+        return self._device_drained_locked()
 
     def _device_drained_locked(self) -> bool:
         """Has every step launched before now left the device?  The ready
@@ -2730,12 +2784,13 @@ class BatchScheduler:
         while True:
             with self._has_work:
                 # someone was missing when the dispatcher last looked, so
-                # the dispatch that follows is the window's ("window");
+                # the dispatch that follows is the window's ("window", or
+                # "none_due" where the window would have bought nothing);
                 # otherwise every live session was ready and only the
                 # in-flight cap held the step back ("backpressure").
                 # ``waited``: from the first to the end of the last wait
                 # for riders since then, for the ready watcher's gap booking
-                missed, waited = False, None
+                cause, waited = "backpressure", None
                 while not self._stop:
                     waiting = self._waiting_slots()
                     g = self._guard
@@ -2753,7 +2808,7 @@ class BatchScheduler:
                         self._has_work.wait(timeout=0.1)
                         continue
                     if not waiting:
-                        missed, waited = False, None
+                        cause, waited = "backpressure", None
                         self._has_work.wait(timeout=0.5)
                         continue
                     if (
@@ -2775,7 +2830,7 @@ class BatchScheduler:
                         # to wait for): dispatch NOW — the single-session
                         # fast path never pays the window
                         break
-                    missed = True
+                    cause = "window"
                     if self.window_s <= 0.0:
                         break
                     oldest = self._oldest_enqueue(waiting)
@@ -2786,8 +2841,15 @@ class BatchScheduler:
                     )
                     if remain <= 0.0:
                         break  # window expired: go with who showed up
+                    if self._none_due_locked(waiting):
+                        cause = "none_due"
+                        break
+                    # the ready watcher wakes this wait when the device
+                    # drains (_note_ready): the window may then buy nothing
+                    self._windowing = True
                     with hop("window_wait", on="window") as w:
                         self._has_work.wait(timeout=remain)
+                    self._windowing = False
                     waited = (w.t0 if waited is None else waited[0], w.t1)
                 if self._stop:
                     break
@@ -2797,10 +2859,7 @@ class BatchScheduler:
                     if plist is not None:
                         entries.append((s, plist))
                 if entries:
-                    self._dispatch_entries_locked(
-                        entries, None, "window" if missed else "backpressure",
-                        waited,
-                    )
+                    self._dispatch_entries_locked(entries, None, cause, waited)
         # drain on stop
         for q in self._queues:
             while True:
@@ -2955,6 +3014,14 @@ class BatchScheduler:
         batch.ready = ready
         self._stamped.append(batch)
         self._ready_seq = batch.step
+        if self._windowing and self._lock.acquire(blocking=False):
+            # the dispatcher waits out a window with the device running:
+            # wake it to look again (never blocks the watcher; a stamp
+            # that races the wait's start falls back on the window's end)
+            try:
+                self._has_work.notify()
+            finally:
+                self._lock.release()
 
     def _book_ready_locked(self):
         """Book the steps the watcher stamped since the last call, in

@@ -873,6 +873,113 @@ def test_parallel_package_imports_nothing_from_stream_or_server():
 
 
 # ---------------------------------------------------------------------------
+# when a partial batch waits out the coalescing window: enqueue schedules on
+# a fake clock, a step that hands the frames back, and a device whose state
+# the test sets
+# ---------------------------------------------------------------------------
+
+_WIN = 0.003  # the coalescing window, s
+_PERIOD, _STAGGER = 0.066, 0.022  # three 15 fps sources, one every 22 ms
+_STAGGERED = (0.0, _STAGGER, 2 * _STAGGER)
+_ALIGNED = (0.0, 0.0001, 0.0002)
+
+
+class _Clock:
+    def __init__(self):
+        self.t = 100.0
+
+    def monotonic(self):
+        return self.t
+
+
+def _schedule(offsets, first_round, rounds=4):
+    """(round, slot, t) of every enqueue in time order: slot i at its
+    offset plus one source period a round, from round ``first_round[i]``."""
+    ev = [
+        (r, i, 100.0 + r * _PERIOD + off)
+        for r in range(rounds)
+        for i, off in enumerate(offsets)
+        if r >= first_round[i]
+    ]
+    return sorted(ev, key=lambda e: e[2])
+
+
+@pytest.mark.parametrize("fbs,offsets,first_round,drained,drain_last,want", [
+    # staggered, the device drained: once the other two sessions' next frames
+    # are predicted 22 and 44 ms off, each frame goes at once, alone
+    (1, _STAGGERED, (0, 0, 0), True, False, [("none_due", 1)] * 3),
+    # aligned: the other two are due inside the window, so it still
+    # coalesces, and the third frame completes one k=3 step
+    (1, _ALIGNED, (0, 0, 0), True, False, [("inline_full", 3)] * 3),
+    # a busy device: the window is waited out
+    (1, _STAGGERED, (0, 0, 0), False, False, [("window", 1)] * 3),
+    # busy at the submit, drained inside the window: the dispatcher sends
+    # the frame then, without waiting the rest of the window
+    (1, _STAGGERED, (0, 0, 0), False, True, [("none_due", 1)] * 3),
+    # slot 2 has one enqueue on record when slots 0 and 1 submit: nothing
+    # predicts it, so they wait; its own frame finds the others not due
+    (1, _STAGGERED, (0, 0, 2), True, False,
+     [("window", 1), ("window", 1), ("none_due", 1)]),
+    # fbs > 1 keeps its window
+    (2, _STAGGERED, (0, 0, 0), True, False, [("window", 1)] * 3),
+], ids=["staggered", "aligned", "busy", "drains_in_window", "fresh_slot", "fbs2"])
+def test_a_partial_batch_waits_only_where_a_rider_can_come(
+    bundle, monkeypatch, fbs, offsets, first_round, drained, drain_last, want,
+):
+    cfg = registry.default_stream_config(
+        "tiny-test", t_index_list=(0,), num_inference_steps=1,
+        timestep_spacing="trailing", scheduler="turbo", cfg_type="none",
+        height=32, width=32, frame_buffer_size=fbs,
+    )
+    clock = _Clock()
+    monkeypatch.setattr(scheduler_mod, "time", clock)
+    s = BatchScheduler(
+        bundle.stream_models, bundle.params, cfg, bundle.encode_prompt,
+        max_sessions=3, window_ms=1e3 * _WIN, prewarm=False,
+    )
+    device = {"drained": drained}
+    monkeypatch.setattr(s, "_bucket_step", lambda k, v="full": (
+        lambda params, states, frames, idx: (states, frames)
+    ))
+    monkeypatch.setattr(s, "_device_drained_locked", lambda: device["drained"])
+    try:
+        sessions = [s.claim(f"s{i}", prompt="p", seed=i) for i in range(3)]
+        events = _schedule(offsets, first_round)
+        last = events[-1][0]
+        frame = np.zeros((32, 32, 3), np.uint8)
+        open_, got = [], []
+        for n, (rnd, slot, t) in enumerate(events):
+            clock.t = t
+            device["drained"] = drained
+            handles = sessions[slot].submit_batch([frame] * fbs)
+            open_.append((rnd, handles[0], sessions[slot], handles))
+            if n + 1 < len(events) and events[n + 1][2] < t + _WIN:
+                continue  # the next frame comes inside the window
+            if any(h.readback is None for _, h, _, _ in open_):
+                # queued for the dispatcher: the device drains, or the
+                # window runs out
+                if drain_last and rnd == last:
+                    device["drained"] = True
+                else:
+                    clock.t = t + 2 * _WIN
+                with s._has_work:
+                    s._has_work.notify()
+            for rnd_, h, sess, hs in open_:
+                sess.fetch_batch(hs)
+                batch = h.readback[0]
+                if rnd_ == last:
+                    got.append((batch.cause, batch.occupancy))
+                    if batch.cause == "none_due" and not drain_last:
+                        assert batch.window is None  # no window_wait
+            open_ = []
+        assert got == want
+        cause = s.snapshot()["batchsched_dispatch_cause_total"]
+        assert set(cause) == set(scheduler_mod.DISPATCH_CAUSES)
+    finally:
+        s.close()
+
+
+# ---------------------------------------------------------------------------
 # agent wiring — a duck-typed scheduler stands in so the HTTP surface is
 # covered without model compiles
 # ---------------------------------------------------------------------------

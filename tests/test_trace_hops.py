@@ -163,6 +163,7 @@ def test_bare_ndarray_session_counts_every_hop(bundle, cfg, rng):
         # one session: every step is the solo path's
         assert snap["batchsched_dispatch_cause_total"] == {
             "solo": n, "inline_full": 0, "window": 0, "backpressure": 0,
+            "none_due": 0,
         }
         assert sum(snap["batchsched_dispatch_inflight_hist"].values()) == n
         assert set(snap["batchsched_dispatch_inflight_hist"]) <= {"0", "1"}
@@ -209,6 +210,8 @@ def test_two_sessions_give_inline_full_and_window_causes(bundle, cfg, rng):
         cause = snap["batchsched_dispatch_cause_total"]
         assert set(cause) == set(DISPATCH_CAUSES)
         assert cause["inline_full"] == 1 and cause["window"] == 1
+        # b has one enqueue on record: nothing predicts it, so a waits
+        assert cause["none_due"] == 0
         assert sum(cause.values()) == snap["batchsched_steps_total"] == 2
         assert snap["batchsched_occupancy_hist"] == {"1": 1, "2": 1}
         assert snap["batchsched_hop_count"]["dispatch"] == 2
@@ -227,7 +230,10 @@ def test_backpressure_cause_when_only_the_inflight_cap_held_the_step(bundle, cfg
         for h in hs:
             a.fetch(h)
         cause = s.snapshot()["batchsched_dispatch_cause_total"]
-        assert cause == {"solo": 2, "inline_full": 0, "window": 0, "backpressure": 1}
+        assert cause == {
+            "solo": 2, "inline_full": 0, "window": 0, "backpressure": 1,
+            "none_due": 0,
+        }
     finally:
         s.close()
 
