@@ -164,6 +164,22 @@ def layer_norm(p, x, eps: float = 1e-5):
     return (y * p["scale"] + p["bias"]).astype(x.dtype)
 
 
+def modulated_norm(x, shift, scale, eps: float = 1e-6):
+    """LayerNorm without affine (float32 statistics), then ``* (1 + scale)
+    + shift``: the adaptive norm of the transformer denoisers (MMDiT,
+    FLUX); shift / scale [B, 1, D]."""
+    xf = x.astype(jnp.float32)
+    mean = xf.mean(-1, keepdims=True)
+    var = jnp.square(xf - mean).mean(-1, keepdims=True)
+    y = (xf - mean) * jax.lax.rsqrt(var + eps)
+    return (y * (1.0 + scale.astype(jnp.float32)) + shift.astype(jnp.float32)).astype(x.dtype)
+
+
+def gelu_tanh_ff(p, x):
+    """``out(GELU-tanh(proj(x)))``: the transformer denoisers' feed-forward."""
+    return linear(p["out"], jax.nn.gelu(linear(p["proj"], x), approximate=True))
+
+
 def silu(x):
     return x * jax.nn.sigmoid(x)
 

@@ -35,10 +35,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .layers import conv2d, init_conv, init_linear, linear, silu, timestep_embedding
+from .layers import (
+    conv2d,
+    gelu_tanh_ff,
+    init_conv,
+    init_linear,
+    linear,
+    modulated_norm,
+    silu,
+    timestep_embedding,
+)
 
 TIME_PROJ_DIM = 256  # the timestep sinusoid's width (diffusers' Timesteps(256))
-LN_EPS = 1e-6
 
 
 @dataclass(frozen=True)
@@ -148,20 +156,6 @@ def pos_embed(cfg: MMDiTConfig, gh: int, gw: int) -> np.ndarray:
     return emb.astype(np.float32)
 
 
-def _modulated_norm(x, shift, scale):
-    """LayerNorm without affine (float32 statistics), then ``* (1 + scale)
-    + shift``; shift / scale [B, 1, D]."""
-    xf = x.astype(jnp.float32)
-    mean = xf.mean(-1, keepdims=True)
-    var = jnp.square(xf - mean).mean(-1, keepdims=True)
-    y = (xf - mean) * jax.lax.rsqrt(var + LN_EPS)
-    return (y * (1.0 + scale.astype(jnp.float32)) + shift.astype(jnp.float32)).astype(x.dtype)
-
-
-def _ff(p, x):
-    return linear(p["out"], jax.nn.gelu(linear(p["proj"], x), approximate=True))
-
-
 def _attend(q, k, v, heads: int, attn_impl: str):
     """[B, Lq, D] x [B, Lk, D] -> [B, Lq, D], softmax(q k^T / sqrt(d)) v by head."""
     b, lq, inner = q.shape
@@ -182,13 +176,13 @@ def _joint_block(p, x, c, temb, heads: int, attn_impl: str, last: bool):
     e = silu(temb)[:, None, :]
     with jax.named_scope("adaln"):
         sh1, sc1, g1, sh2, sc2, g2 = jnp.split(linear(p["norm1"]["linear"], e), 6, axis=-1)
-        xn = _modulated_norm(x, sh1, sc1)
+        xn = modulated_norm(x, sh1, sc1)
         mc = linear(p["norm1_context"]["linear"], e)
         if last:  # AdaLN-continuous: scale first, then shift
             csc1, csh1 = jnp.split(mc, 2, axis=-1)
         else:
             csh1, csc1, cg1, csh2, csc2, cg2 = jnp.split(mc, 6, axis=-1)
-        cn = _modulated_norm(c, csh1, csc1)
+        cn = modulated_norm(c, csh1, csc1)
     lx = x.shape[1]
     with jax.named_scope("joint_attn"):
         a = p["attn"]
@@ -202,10 +196,10 @@ def _joint_block(p, x, c, temb, heads: int, attn_impl: str, last: bool):
         if not last:
             c = c + cg1 * linear(a["to_add_out"], o[:, lx:])
     with jax.named_scope("ff"):
-        x = x + g2 * _ff(p["ff"], _modulated_norm(x, sh2, sc2))
+        x = x + g2 * gelu_tanh_ff(p["ff"], modulated_norm(x, sh2, sc2))
     if not last:
         with jax.named_scope("context_ff"):
-            c = c + cg2 * _ff(p["ff_context"], _modulated_norm(c, csh2, csc2))
+            c = c + cg2 * gelu_tanh_ff(p["ff_context"], modulated_norm(c, csh2, csc2))
     return x, c
 
 
@@ -240,7 +234,7 @@ def apply_mmdit(p, x, timesteps, context, cfg: MMDiTConfig, added_cond=None,
             scale, shift = jnp.split(
                 linear(p["norm_out"]["linear"], silu(temb)[:, None, :]), 2, axis=-1
             )
-            z = linear(p["proj_out"], _modulated_norm(z, shift, scale))
+            z = linear(p["proj_out"], modulated_norm(z, shift, scale))
             z = z.reshape(n, gh, gw, pch, pch, cfg.out_channels)
             z = z.transpose(0, 1, 3, 2, 4, 5).reshape(n, h, w, cfg.out_channels)
     return z

@@ -23,6 +23,7 @@ import dataclasses
 import glob
 import logging
 import os
+import zlib
 from dataclasses import dataclass
 from functools import partial
 from typing import Callable
@@ -40,6 +41,7 @@ from ..stream.engine import (
 )
 from . import clip as C
 from . import controlnet as CN
+from . import flux as F
 from . import loader as LD
 from . import lora as LR
 from . import mmdit as M
@@ -55,10 +57,10 @@ class ModelBundle:
     params: dict
     stream_models: StreamModels
     encode_prompt: Callable
-    unet_cfg: U.UNetConfig | M.MMDiTConfig  # the denoiser's configuration
+    unet_cfg: U.UNetConfig | M.MMDiTConfig | F.FluxConfig  # the denoiser's configuration
     clip_cfg: C.CLIPTextConfig
     taesd_cfg: T.TAESDConfig
-    family: str  # sd15 | sd21 | sdxl | sd3 | tiny | tinyxl | tinysd3
+    family: str  # sd15 | sd21 | sdxl | sd3 | flux | tiny | tinyxl | tinysd3 | tinyflux
     loaded_real_weights: bool
 
 
@@ -85,12 +87,19 @@ def compose_model_id(model_id: str, controlnet: str | None) -> str:
     return f"{model_id}+{controlnet}"
 
 
-# the families whose denoiser is the rectified-flow transformer (MMDiT)
-FLOW_FAMILIES = ("sd3", "tinysd3")
+# the families whose denoiser is a rectified-flow transformer, and its kind
+# (``StreamModels.denoiser``): SD3's MMDiT, FLUX's double/single-stream one
+FLOW_FAMILIES = {"sd3": "mmdit", "tinysd3": "mmdit", "flux": "flux", "tinyflux": "flux"}
+# the flow ladder's shift each flow family was trained with (its scheduler
+# config): SD3's FlowMatchEulerDiscreteScheduler shift 3.0; FLUX.1-schnell
+# samples unshifted
+_FLOW_SHIFT = {"mmdit": 3.0, "flux": 1.0}
 
 
 def family_of(model_id: str) -> str:
     m = split_model_id(model_id)[0].lower()
+    if "flux" in m:
+        return "tinyflux" if ("tiny" in m or "test" in m) else "flux"
     if "sd3" in m or "stable-diffusion-3" in m:
         return "tinysd3" if ("tiny" in m or "test" in m) else "sd3"
     if ("tiny" in m or "test" in m) and "xl" in m:
@@ -110,21 +119,25 @@ def default_stream_config(model_id: str, **overrides) -> StreamConfig:
     model_id, side_network = split_model_id(model_id)
     m = model_id.lower()
     if fam in FLOW_FAMILIES:
-        # SD3 + Flash Diffusion's 4-step distillation as a stream batch:
-        # four stages on the shift-3 flow ladder, no guidance (the card's
-        # guidance 0), 16-channel latents, pooled text in the state row.
-        # The fused epilogue computes epsilon prediction only
+        # a rectified-flow transformer as a stream batch: no guidance,
+        # 16-channel latents, pooled text in the state row, the ladder
+        # shifted as the family was trained.  SD3 + Flash Diffusion's
+        # 4-step distillation: four stages on the shift-3 ladder (the
+        # card's guidance 0).  FLUX.1-schnell: two stages of its unshifted
+        # ladder, img2img at strength one half (no guidance input).  The
+        # fused epilogue computes epsilon prediction only
         base = dict(
-            t_index_list=(18, 26, 35, 45),
+            t_index_list=(25, 40) if FLOW_FAMILIES[fam] == "flux" else (18, 26, 35, 45),
             num_inference_steps=50,
             scheduler="flow",
             prediction_type="flow",
+            flow_shift=_FLOW_SHIFT[FLOW_FAMILIES[fam]],
             cfg_type="none",
             latent_channels=16,
             use_added_cond=True,
             use_fused_epilogue=False,
         )
-        if fam == "tinysd3":
+        if fam in ("tinysd3", "tinyflux"):
             base.update(height=64, width=64, latent_scale=4)
     elif "turbo" in m and fam != "sdxl":
         base = dict(
@@ -212,9 +225,10 @@ def default_stream_config(model_id: str, **overrides) -> StreamConfig:
             ) from e
     cfg = StreamConfig(**base)
     if cfg.scheduler == "flow":
+        kind = FLOW_FAMILIES.get(fam, "transformer")
         conflicts = [
             what for what, on in (
-                ("DeepCache (UNET_CACHE: the MMDiT has no capture/cached pair)",
+                (f"DeepCache (UNET_CACHE: the {kind} has no capture/cached pair)",
                  cfg.unet_cache_interval >= 2),
                 ("a side network (ControlNet residuals feed UNet blocks)",
                  cfg.use_controlnet),
@@ -224,8 +238,8 @@ def default_stream_config(model_id: str, **overrides) -> StreamConfig:
         ]
         if conflicts:
             raise ValueError(
-                f"{model_id}: the rectified-flow denoiser does not compose "
-                f"with {'; '.join(conflicts)} — unset it"
+                f"{model_id}: the rectified-flow denoiser ({kind}) does not "
+                f"compose with {'; '.join(conflicts)} — unset it"
             )
     if cfg.unet_cache_interval >= 2 and cfg.use_controlnet:
         raise ValueError(
@@ -272,6 +286,21 @@ def _model_configs(fam: str):
         return (
             M.MMDiTConfig.tiny(),
             _tiny_sd3_tower(),
+            T.TAESDConfig(width=8, num_stages=2, blocks_per_stage=1, latent_channels=16),
+        )
+    if fam == "flux":
+        # one chip's stage of a four-stage pipeline (FluxConfig.schnell_stage);
+        # CLIP ViT-L for the pooled text (its pooler output, no projection);
+        # TAEF1 (madebyollin/taef1): TAESD at 16 latent channels
+        return (
+            F.FluxConfig.schnell_stage(),
+            C.CLIPTextConfig.sd15(),
+            T.TAESDConfig(latent_channels=16),
+        )
+    if fam == "tinyflux":
+        return (
+            F.FluxConfig.tiny(),
+            C.CLIPTextConfig.tiny(),
             T.TAESDConfig(width=8, num_stages=2, blocks_per_stage=1, latent_channels=16),
         )
     raise ValueError(fam)
@@ -347,10 +376,11 @@ def load_model_bundle(
     model_id, side = split_model_id(model_id)
     controlnet = controlnet or side
     fam = family_of(model_id)
-    flow = fam in FLOW_FAMILIES
+    kind = FLOW_FAMILIES.get(fam, "unet")
+    flow = kind != "unet"
     if flow and (controlnet or lora_dict):
         raise ValueError(
-            f"{model_id}: the rectified-flow denoiser (MMDiT) takes no "
+            f"{model_id}: the rectified-flow denoiser ({kind}) takes no "
             + ("side network (ControlNet residuals feed UNet blocks)"
                if controlnet else "LoRA (the fuse maps UNet modules)")
         )
@@ -358,9 +388,9 @@ def load_model_bundle(
     key = jax.random.PRNGKey(seed)
     ku, kc, kt = jax.random.split(key, 3)
 
+    init = {"unet": U.init_unet, "mmdit": M.init_mmdit, "flux": F.init_flux}[kind]
     params = {
-        **({"mmdit": M.init_mmdit(ku, unet_cfg)} if flow
-           else {"unet": U.init_unet(ku, unet_cfg)}),
+        kind: init(ku, unet_cfg),
         "clip": C.init_clip_text(kc, clip_cfg),
         "taesd": T.init_taesd(kt, taesd_cfg),
     }
@@ -368,7 +398,7 @@ def load_model_bundle(
     dual_tower = clip2_cfg is not None
     if dual_tower:
         params["clip2"] = C.init_clip_text(jax.random.fold_in(kc, 1), clip2_cfg)
-    if fam in ("tiny", "tinyxl", "tinysd3"):
+    if fam in ("tiny", "tinyxl", "tinysd3", "tinyflux"):
         latent_scale = 4
     cnet_num_down = {8: 3, 4: 2, 2: 1}.get(latent_scale)
     if controlnet is not None and cnet_num_down is None:
@@ -405,8 +435,8 @@ def load_model_bundle(
     loaded = False
     if snap and flow:
         logger.warning(
-            "%s: no key map for the MMDiT transformer's checkpoint yet — "
-            "the snapshot at %s is not read", model_id, snap,
+            "%s: no key map for the %s transformer's checkpoint yet — "
+            "the snapshot at %s is not read", model_id, kind, snap,
         )
     elif snap:
         loaded = _try_load_weights(params, snap, fam, unet_cfg, clip_cfg, taesd_cfg, dtype)
@@ -464,7 +494,7 @@ def load_model_bundle(
             )
 
     tok = TK.find_clip_tokenizer(snap or "", max_length=clip_cfg.max_length)
-    if fam in ("tiny", "tinyxl", "tinysd3"):
+    if fam in ("tiny", "tinyxl", "tinysd3", "tinyflux"):
         tok = TK.HashTokenizer(
             vocab_size=clip_cfg.vocab_size, max_length=clip_cfg.max_length
         )
@@ -541,13 +571,21 @@ def load_model_bundle(
             p["mmdit"], x, t, ctx, unet_cfg, added_cond=added, attn_impl=attn_impl,
         )
 
+    def flux_apply(p, x, t, ctx, added):
+        return F.apply_flux(
+            p["flux"], x, t, ctx, unet_cfg, added_cond=added, attn_impl=attn_impl,
+        )
+
     def denoiser(height: int, width: int) -> dict:
-        info = {"kind": "mmdit" if flow else "unet",
-                "latent_channels": taesd_cfg.latent_channels}
-        if flow:
+        info = {"kind": kind, "latent_channels": taesd_cfg.latent_channels}
+        if kind == "mmdit":
             cell = (1 << taesd_cfg.num_stages) * unet_cfg.patch_size
             # image tokens, then CLIP's and T5's text tokens
             info["joint_tokens"] = (height // cell) * (width // cell) + 2 * clip_cfg.max_length
+        elif kind == "flux":
+            cell = (1 << taesd_cfg.num_stages) * 2  # a token packs 2x2 latents
+            # T5's text tokens, then image tokens
+            info["joint_tokens"] = unet_cfg.text_tokens + (height // cell) * (width // cell)
         return info
 
     def vae_encode(p, img):
@@ -565,6 +603,14 @@ def load_model_bundle(
         ids = np.asarray([tok(prompt)], np.int32)
         ids_neg = np.asarray([tok("")], np.int32)
         out_c = clip_jit(params["clip"], jnp.asarray(ids))
+        if kind == "flux":
+            # FLUX (BFL's ``prepare``): T5's states are the text stream,
+            # CLIP-L's pooler output the pooled vector.  No T5 encoder is
+            # held: its states are a stand-in drawn from the prompt
+            def t5(text):
+                return t5_stand_in(text, unet_cfg.text_tokens, unet_cfg.joint_attention_dim)
+
+            return t5(prompt), t5(""), {"pooled": np.asarray(out_c["pooled"])}
         out_u = clip_jit(params["clip"], jnp.asarray(ids_neg))
         if not dual_tower:
             return np.asarray(out_c["hidden"]), np.asarray(out_u["hidden"])
@@ -595,7 +641,7 @@ def load_model_bundle(
     return ModelBundle(
         params=params,
         stream_models=StreamModels(
-            unet=mmdit_apply if flow else unet_apply,
+            unet={"unet": unet_apply, "mmdit": mmdit_apply, "flux": flux_apply}[kind],
             vae_encode=vae_encode,
             vae_decode=vae_decode,
             controlnet=controlnet_apply if controlnet is not None else None,
@@ -611,6 +657,16 @@ def load_model_bundle(
         family=fam,
         loaded_real_weights=loaded,
     )
+
+
+def t5_stand_in(prompt: str, tokens: int, width: int) -> np.ndarray:
+    """[1, tokens, width] float32 in place of T5-XXL's encoder states, which
+    no family here holds (4.76e9 parameters that run once a prompt): standard
+    normal from a generator seeded by the prompt's CRC-32, so that a prompt
+    always gets the same states, every token its own, and two prompts
+    differ."""
+    rng = np.random.default_rng(zlib.crc32(prompt.encode("utf-8")))
+    return rng.standard_normal((1, tokens, width), dtype=np.float32)
 
 
 def _try_load_weights(params, snap, fam, unet_cfg, clip_cfg, taesd_cfg, dtype) -> bool:

@@ -46,7 +46,9 @@ def interpret_default() -> bool:
 # the ``name=`` each kernel in this package gives its pallas_call; the name
 # rides the custom call's op_name through jit, vmap and the scalar-prefetch
 # batching loop
-KERNEL_NAMES = ("flash_attention", "fused_stream_epilogue", "joint_attention")
+KERNEL_NAMES = (
+    "flash_attention", "fused_stream_epilogue", "joint_attention", "rope_attention",
+)
 
 
 def mosaic_kernel_counts(hlo_text: str) -> dict:
@@ -162,8 +164,9 @@ def count_attention_paths():
     """-> a Counter of the ``flash_attention`` calls traced inside the
     block, by the layout their operands took: ``packed`` (``[B, L, H*D]``,
     as the projections leave them) or ``per_head`` (transposed to a head a
-    program), and of the ``joint_attention`` calls (``joint``: the MMDiT's,
-    24 a pass at SD3's depth).  A jitted function's Python body runs when
+    program), of the ``joint_attention`` calls (``joint``: the MMDiT's, 24 a
+    pass at SD3's depth) and of the ``rope_attention`` calls (``rope``:
+    FLUX's, 15 a pass at ``fluxschnell2x512``'s depth).  A jitted function's Python body runs when
     it is traced, so wrap its ``lower()``: the evidence, beside
     ``mosaic_kernel_counts``, of which kernel variant a compiled step
     holds."""

@@ -37,6 +37,11 @@ Measured on the chip at every shape the SD2.1 (head dim 64, B=1) and SD1.5
 
 Non-causal (diffusion attention has no mask).  Interpret mode on an
 explicitly requested CPU so the hermetic suite exercises the same code path.
+
+The transformer denoisers' joint attention runs ``_attn_kernel``'s body over
+a whole K in one pass: ``joint_attention`` (SD3's MMDiT) as it is,
+``rope_attention`` (FLUX) after RMS-normalizing q and k by head and
+rotating them by their tokens' rotary positions in VMEM.
 """
 
 from __future__ import annotations
@@ -47,6 +52,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import interpret_default, note_attention_path
 
@@ -288,6 +294,129 @@ def joint_attention(q, k, v, interpret: bool | None = None):
         name="joint_attention",
     )(q, k, v)
     return out.reshape(b, lq, h, d)
+
+
+# rows a ``rope_attention`` program normalizes and rotates at once: bounds
+# the float32 temporaries beside the score tile
+_ROPE_CHUNK = 256
+_RMS_EPS = 1e-6
+
+
+def _rotate_pairs(x, cos, sin):
+    """``x`` [rows, lanes] with each adjacent lane pair (2j, 2j+1) rotated:
+    (a, b) -> (a cos - b sin, a sin + b cos), in float32, back in ``x``'s
+    dtype.  ``cos`` holds each pair's cosine in both its lanes, ``sin`` the
+    sine negated in the even lane.  A lane's partner comes from a roll by
+    one lane either way; which way brings it is read off the lane index
+    rolled the same way, so nothing rests on the roll's direction."""
+    xf = x.astype(jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, xf.shape, 1)
+    back = pltpu.roll(xf, 1, 1)
+    ahead = pltpu.roll(xf, xf.shape[1] - 1, 1)
+    partner = jnp.where(pltpu.roll(lane, 1, 1) == (lane ^ 1), back, ahead)
+    return (xf * cos + partner * sin).astype(x.dtype)
+
+
+def _norm_rotate(x, scale, cos, sin):
+    """A head's rows [rows, head_dim]: RMS-normed over the head (float32
+    statistics, back in ``x``'s dtype), times the learned ``scale`` (rounded
+    to ``x``'s dtype, as a product in that dtype is), then rotated."""
+    xf = x.astype(jnp.float32)
+    y = (xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + _RMS_EPS))
+    y = (y.astype(x.dtype).astype(jnp.float32) * scale).astype(x.dtype)
+    return _rotate_pairs(y, cos, sin)
+
+
+def _rope_attn_kernel(q_ref, k_ref, v_ref, cos_ref, sin_ref, qs_ref, ks_ref, o_ref,
+                      qr_ref, kr_ref, *, heads: int, scale: float, text_tokens: int):
+    """One (batch, head group, q-block) program: q and k RMS-normed and
+    rotated into VMEM, then ``_attn_kernel``'s single pass over the whole
+    K.  A token before ``text_tokens`` takes row 0 of the norms' scales,
+    every later one row 1."""
+    bq, lk = q_ref.shape[0], k_ref.shape[0]
+    d = q_ref.shape[1] // heads
+    row0 = pl.multiple_of(pl.program_id(2) * bq, 8 if bq % 8 == 0 else 1)
+
+    def prepare(src, dst, scale_ref, first, rows):
+        for r in range(0, rows, _ROPE_CHUNK):
+            n = min(_ROPE_CHUNK, rows - r)
+            at = pl.ds(first + r, n)
+            token = first + r + jax.lax.broadcasted_iota(jnp.int32, (n, d), 0)
+            s = jnp.where(token < text_tokens, scale_ref[0:1, :], scale_ref[1:2, :])
+            for h in range(heads):
+                lanes = slice(h * d, (h + 1) * d)
+                dst[r:r + n, lanes] = _norm_rotate(
+                    src[r:r + n, lanes], s, cos_ref[at, :], sin_ref[at, :]
+                )
+
+    prepare(k_ref, kr_ref, ks_ref, 0, lk)
+    prepare(q_ref, qr_ref, qs_ref, row0, bq)
+    _attn_kernel(qr_ref, kr_ref, v_ref, o_ref, heads=heads, block_k=lk, scale=scale)
+
+
+def rope_attention(qkv, cos, sin, q_scale, k_scale, heads: int, text_tokens: int = 0,
+                   interpret: bool | None = None):
+    """FLUX's attention over its joint sequence (text tokens, then image
+    tokens), with q/k RMS norms and rotary positions.
+
+    ``qkv`` [B, L, C]: a fused projection's output whose first ``3*H*D``
+    columns are q, k and v side by side (``C`` may hold more after them: a
+    single block's MLP input, which the kernel does not read), each read in
+    place by its column block.  ``cos`` / ``sin`` [L, D/2]: each token's
+    angle for each adjacent pair (2j, 2j+1) of a head.  ``q_scale`` /
+    ``k_scale`` [2, D]: the RMS norms' learned scales of the text tokens
+    (the first ``text_tokens``) and of the rest.  -> [B, L, H*D].
+
+    Per program the kernel normalizes and rotates its q block and the whole
+    K of its heads into VMEM (float32, back to the operands' dtype, as the
+    reference rounds), then runs ``_attn_kernel``'s single pass over the
+    whole K.  K and V of a head group sit whole in one block and the
+    queries come in ``_joint_block_q`` blocks, as in ``joint_attention``
+    (1280 joint tokens at 512x512: two blocks of 640); the tables are one
+    block fetched once a call.  Heads of 128 are one lane tile a program.
+    The norm is here and not before the call: computed on q and k outside,
+    a reduction over each head's lanes makes XLA re-lay both out in
+    float32."""
+    if interpret is None:
+        interpret = interpret_default()
+    b, l, c = qkv.shape
+    d = q_scale.shape[-1]
+    inner = heads * d
+    if cos.shape != (l, d // 2) or c < 3 * inner:
+        raise ValueError(
+            f"rope_attention: q, k, v of one joint sequence with a table row a "
+            f"token; got qkv {qkv.shape}, {heads} heads of {d}, tables {cos.shape}"
+        )
+    group = math.gcd(min(128 // math.gcd(128, d), heads), heads)
+    width, blocks = group * d, heads // group
+    block_q = _joint_block_q(l, l, qkv.dtype.itemsize)
+    rows = pl.cdiv(l, block_q) * block_q
+    # each pair's cosine in both lanes, its sine negated in the even one,
+    # zero rows past L
+    cos2 = jnp.repeat(jnp.asarray(cos, jnp.float32), 2, axis=-1)
+    sin2 = jnp.stack([-sin, sin], axis=-1).reshape(l, d).astype(jnp.float32)
+    cos2, sin2 = (jnp.pad(t, ((0, rows - l), (0, 0))) for t in (cos2, sin2))
+    q_scale, k_scale = (jnp.asarray(s, jnp.float32).reshape(2, d) for s in (q_scale, k_scale))
+    note_attention_path("rope")
+    q_spec = pl.BlockSpec((None, block_q, width), lambda n, g, i: (n, i, g))
+    k_spec = pl.BlockSpec((None, l, width), lambda n, g, i: (n, 0, blocks + g))
+    v_spec = pl.BlockSpec((None, l, width), lambda n, g, i: (n, 0, 2 * blocks + g))
+    table_spec = pl.BlockSpec((rows, d), lambda n, g, i: (0, 0))
+    scale_spec = pl.BlockSpec((2, d), lambda n, g, i: (0, 0))
+    return pl.pallas_call(
+        partial(_rope_attn_kernel, heads=group, scale=1.0 / math.sqrt(d),
+                text_tokens=text_tokens),
+        grid=(b, blocks, rows // block_q),
+        in_specs=[q_spec, k_spec, v_spec, table_spec, table_spec, scale_spec, scale_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b, l, inner), qkv.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((block_q, width), qkv.dtype),
+            pltpu.VMEM((l, width), qkv.dtype),
+        ],
+        interpret=interpret,
+        name="rope_attention",
+    )(qkv, qkv, qkv, cos2, sin2, q_scale, k_scale)
 
 
 def _xla_attention(q, k, v):

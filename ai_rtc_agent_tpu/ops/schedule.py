@@ -125,22 +125,20 @@ def _t_indices(t_index_list: Sequence[int], num_inference_steps: int) -> np.ndar
     return idx
 
 
-# SD3's ``scheduler/scheduler_config.json`` (FlowMatchEulerDiscreteScheduler):
-# the one rectified-flow ladder the registry serves
-FLOW_SHIFT = 3.0
-
-
 def flow_sigmas(
-    num_inference_steps: int, num_train_steps: int = DEFAULT_TRAIN_STEPS
+    num_inference_steps: int, shift: float,
+    num_train_steps: int = DEFAULT_TRAIN_STEPS,
 ) -> np.ndarray:
     """The descending noise levels of a rectified-flow model's
     ``num_inference_steps``-step ladder: diffusers'
-    ``FlowMatchEulerDiscreteScheduler(shift=FLOW_SHIFT).set_timesteps(n)``.
+    ``FlowMatchEulerDiscreteScheduler(shift=shift).set_timesteps(n)``, with
+    the shift the model's scheduler config states (SD3 3.0; FLUX.1-schnell
+    1.0, where the ladder is the training ladder's ends spanned evenly).
     The training ladder is ``s = t / T`` for t = T .. 1, shifted as
     ``shift * s / (1 + (shift - 1) * s)``; the inference ladder spans its
     ends evenly in unshifted units (the smallest level is the shifted one,
     read as unshifted) and is shifted again: sigma_0 = 1, pure noise."""
-    T, shift = num_train_steps, FLOW_SHIFT
+    T = num_train_steps
 
     def shifted(s):
         return shift * s / (1.0 + (shift - 1.0) * s)
@@ -150,12 +148,12 @@ def flow_sigmas(
 
 
 def flow_sub_sigmas(
-    t_index_list: Sequence[int], num_inference_steps: int
+    t_index_list: Sequence[int], num_inference_steps: int, shift: float
 ) -> np.ndarray:
     """t_index_list -> each stage's noise level on the flow ladder, noisiest
     first: :func:`sub_timesteps` for a rectified-flow model."""
     idx = _t_indices(t_index_list, num_inference_steps)
-    return flow_sigmas(num_inference_steps)[idx]
+    return flow_sigmas(num_inference_steps, shift)[idx]
 
 
 def batched_sub_timesteps(

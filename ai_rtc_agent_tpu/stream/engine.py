@@ -61,7 +61,10 @@ class StreamConfig:
     use_denoising_batch: bool = True
     do_add_noise: bool = True
     prediction_type: str = "epsilon"  # epsilon | v_prediction | sample | flow
-    scheduler: str = "lcm"  # lcm | turbo | flow (rectified flow: SD3)
+    scheduler: str = "lcm"  # lcm | turbo | flow (rectified flow: SD3, FLUX)
+    # the flow ladder's shift (scheduler "flow" only): 3.0 for SD3, 1.0
+    # (unshifted) for FLUX.1-schnell; the registry sets it by family
+    flow_shift: float = 1.0
     timestep_spacing: str = "leading"
     dtype: str = "float32"  # compute dtype: float32 | bfloat16
     similar_image_filter: bool = False
@@ -121,24 +124,28 @@ class StreamConfig:
 @dataclass
 class StreamModels:
     """Apply-fn bundle the engine drives (duck-typed, so any model family —
-    SD1.5/SD2.1/SDXL UNets, ControlNet variants, the SD3 MMDiT — plugs in).
+    SD1.5/SD2.1/SDXL UNets, ControlNet variants, the SD3 MMDiT, FLUX — plugs
+    in).
 
     unet(params, x, t, context, added_cond) -> model_out   [B,h,w,C]
-        the denoiser, whatever its kind: a UNet, or the MMDiT
+        the denoiser, whatever its kind: a UNet, the MMDiT
         (models/mmdit.py: context [B,154,4096], added_cond["text_embeds"]
-        the pooled text [B,2048], t the float 1000*sigma, out the velocity)
+        the pooled text [B,2048]) or FLUX (models/flux.py: context T5's
+        states [B,256,4096], the pooled text CLIP-L's [B,768]); for both
+        transformers t is the float 1000*sigma and out the velocity
     vae_encode(params, img01_nhwc) -> latents              [N,h,w,C]
     vae_decode(params, latents) -> img01_nhwc              [N,H,W,3]
     controlnet(params, x, t, context, cond_img, added_cond, scale)
         -> (down_residuals, mid_residual)                  [optional, UNet]
     vae_fold(image_width) -> {"encode" | "decode": pixels one 128-lane
         row holds in the VAE's passes}                      [optional]
-    denoiser(height, width) -> {"kind": "unet" | "mmdit",
-        "latent_channels": C, and for the MMDiT "joint_tokens": image +
-        text tokens of its joint attention at that frame}  [optional]
+    denoiser(height, width) -> {"kind": "unet" | "mmdit" | "flux",
+        "latent_channels": C, and for the transformers "joint_tokens":
+        image + text tokens of their joint attention at that frame}
+        [optional]
         (served in the scheduler's snapshot and /health)
 
-    ``C`` is ``StreamConfig.latent_channels``: 4, 16 for the MMDiT.  What
+    ``C`` is ``StreamConfig.latent_channels``: 4, 16 for the transformers.  What
     composes with which denoiser (DeepCache, a side network, w8, fbs) is in
     docs/serving.md; the registry refuses the rest at configuration time.
     """
@@ -158,7 +165,9 @@ class StreamModels:
 
 def _coeff_state(cfg: StreamConfig, schedule: S.NoiseSchedule, t_index_list):
     if cfg.scheduler == "flow":
-        sig = S.flow_sub_sigmas(list(t_index_list), cfg.num_inference_steps)
+        sig = S.flow_sub_sigmas(
+            list(t_index_list), cfg.num_inference_steps, cfg.flow_shift
+        )
         c = L.make_flow_coeffs(
             np.repeat(sig, cfg.frame_buffer_size), cfg.frame_buffer_size
         )

@@ -130,16 +130,16 @@ _SIGMAS = (0.838480194017785, 0.7275816499905606, 0.5480456026058632, 0.21659347
 
 
 def test_the_flow_ladder_against_hand_values():
-    assert S.FLOW_SHIFT == 3.0
-    ladder = S.flow_sigmas(50)
+    assert registry.default_stream_config("jasperai/flash-sd3").flow_shift == 3.0
+    ladder = S.flow_sigmas(50, 3.0)
     assert ladder.shape == (50,) and ladder[0] == 1.0
     np.testing.assert_allclose(ladder[-1], 3 * 0.002994012 / (1 + 2 * 0.002994012), rtol=1e-6)
     assert (np.diff(ladder) < 0).all()
     np.testing.assert_allclose(
-        S.flow_sub_sigmas((18, 26, 35, 45), 50), _SIGMAS, rtol=1e-12
+        S.flow_sub_sigmas((18, 26, 35, 45), 50, 3.0), _SIGMAS, rtol=1e-12
     )
     with pytest.raises(ValueError, match="strictly increasing"):
-        S.flow_sub_sigmas((26, 18), 50)
+        S.flow_sub_sigmas((26, 18), 50, 3.0)
 
 
 def test_the_flow_coefficients_against_hand_values():

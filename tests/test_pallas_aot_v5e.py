@@ -24,7 +24,7 @@ from ai_rtc_agent_tpu.ops.pallas import (
     lane_sparse_outputs,
     mosaic_kernel_counts,
 )
-from ai_rtc_agent_tpu.ops.pallas.attention import flash_attention
+from ai_rtc_agent_tpu.ops.pallas.attention import flash_attention, rope_attention
 from ai_rtc_agent_tpu.ops.pallas.fused_scheduler import fused_stream_epilogue
 
 
@@ -76,6 +76,28 @@ def test_flash_attention_compiles_for_v5e(v5e, q_shape, kv_shape, vmap_k):
         v5e(kv_shape, jnp.bfloat16),
     ).compile()
     assert mosaic_kernel_counts(compiled.as_text()) == {"flash_attention": 1}
+
+
+@pytest.mark.parametrize("columns", [3 * 3072, 3 * 3072 + 12288])
+@pytest.mark.parametrize("vmap_k", [1, 2])
+def test_rope_attention_compiles_for_v5e(v5e, vmap_k, columns):
+    """FLUX's attention at ``fluxschnell2x512``'s shapes (two stream rows,
+    256 + 1024 joint tokens, 24 heads of 128; a double block's fused q/k/v,
+    a single block's ``linear1`` output with the MLP's input after them)
+    under ``vmap`` as the bucket step runs it: the norm's lane reduction,
+    the rotation's lane rolls, the scratch q and K and the whole-K single
+    pass are what Mosaic has to accept."""
+    fn = jax.vmap(
+        lambda x, c, s, qs, ks: rope_attention(x, c, s, qs, ks, 24, 256, interpret=False),
+        in_axes=(0, None, None, None, None),
+    )
+    table, scale = v5e((1280, 64), jnp.float32), v5e((2, 128), jnp.bfloat16)
+    with count_attention_paths() as paths:
+        compiled = jax.jit(fn).lower(
+            v5e((vmap_k, 2, 1280, columns), jnp.bfloat16), table, table, scale, scale
+        ).compile()
+    assert dict(paths) == {"rope": 1}
+    assert mosaic_kernel_counts(compiled.as_text()) == {"rope_attention": 1}
 
 
 # (B, tokens, heads, head_dim, context width or None for self-attention): the

@@ -32,6 +32,13 @@ MMDIT_SCOPES = (
     "mmdit", "patch_embed", "time_text_embed", "block_0", "block_1",
     "adaln", "joint_attn", "context_ff", "norm_out",
 )
+# FLUX's (models/flux.py): its embedders, a double block's members (its image
+# stream's ``ff`` and text stream's ``context_ff`` as the MMDiT names them), a
+# single block's members, the output
+FLUX_SCOPES = (
+    "flux", "embed", "double_0", "single_0", "single_1", "adaln", "rope_attn",
+    "ff", "context_ff", "linear1", "linear2", "norm_out",
+)
 _WRAPPED = re.compile(r"^[a-z_]+\((.*)\)$")
 
 
@@ -80,6 +87,12 @@ def flow_scope_components():
     return _components("tinysd3-test")
 
 
+@pytest.fixture(scope="module")
+def flux_scope_components():
+    """FLUX's bucket step alone."""
+    return _components("tinyflux-test")
+
+
 @pytest.mark.parametrize("scope", STEP_SCOPES + UNET_SCOPES)
 def test_lowered_bucket_step_carries_the_scope(scope_components, scope):
     assert scope in scope_components
@@ -88,6 +101,11 @@ def test_lowered_bucket_step_carries_the_scope(scope_components, scope):
 @pytest.mark.parametrize("scope", STEP_SCOPES + MMDIT_SCOPES + ("ff",))
 def test_lowered_flow_bucket_step_carries_the_scope(flow_scope_components, scope):
     assert scope in flow_scope_components
+
+
+@pytest.mark.parametrize("scope", STEP_SCOPES + FLUX_SCOPES)
+def test_lowered_flux_bucket_step_carries_the_scope(flux_scope_components, scope):
+    assert scope in flux_scope_components
 
 
 def test_two_tower_family_names_its_addition_embedding():
@@ -111,21 +129,24 @@ def test_two_tower_family_names_its_addition_embedding():
 
 
 def test_no_scope_contains_a_mosaic_kernels_name(
-    scope_components, flow_scope_components
+    scope_components, flow_scope_components, flux_scope_components
 ):
     """benchmark/trace_reduce.py and mosaic_kernel_counts find a kernel by
     that substring of an op's name: a scope named after one would add its
     wrapper's casts and transposes to the kernel's time.  The kernel's own
     ``pallas_call(name=...)`` is the only component that may carry it.
-    ``joint_attention`` is the flow family's alone, every other kernel the
-    UNet family's."""
-    for comps in (scope_components, flow_scope_components):
+    ``joint_attention`` is the MMDiT's alone, ``rope_attention`` FLUX's,
+    every other kernel the UNet family's."""
+    families = (scope_components, flow_scope_components, flux_scope_components)
+    for comps in families:
         for kernel in KERNEL_NAMES:
             assert [c for c in comps if kernel in c and c != kernel] == []
         assert "bucket" in comps  # the readers match the program by it
+    owner = {"joint_attention": flow_scope_components, "rope_attention": flux_scope_components}
     for kernel in KERNEL_NAMES:
-        own = flow_scope_components if kernel == "joint_attention" else scope_components
+        own = owner.get(kernel, scope_components)
         assert kernel in own  # the pallas_call's own name
+        assert [f for f in families if f is not own and kernel in f] == []
 
 
 def test_scoped_step_is_bit_identical_to_the_unscoped_one(monkeypatch):
