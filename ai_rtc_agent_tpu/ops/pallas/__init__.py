@@ -164,8 +164,9 @@ def count_attention_paths():
     """-> a Counter of the ``flash_attention`` calls traced inside the
     block, by the layout their operands took: ``packed`` (``[B, L, H*D]``,
     as the projections leave them) or ``per_head`` (transposed to a head a
-    program), of the ``joint_attention`` calls (``joint``: the MMDiT's, 24 a
-    pass at SD3's depth) and of the ``rope_attention`` calls (``rope``:
+    program), of the ``joint_attention`` calls (the MMDiT's, 24 a pass at
+    SD3's depth: ``joint_kloop`` where K is met in blocks, ``joint`` where
+    it is one block) and of the ``rope_attention`` calls (``rope``:
     FLUX's, 15 a pass at ``fluxschnell2x512``'s depth).  A jitted function's Python body runs when
     it is traced, so wrap its ``lower()``: the evidence, beside
     ``mosaic_kernel_counts``, of which kernel variant a compiled step

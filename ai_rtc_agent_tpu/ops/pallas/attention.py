@@ -38,10 +38,10 @@ Measured on the chip at every shape the SD2.1 (head dim 64, B=1) and SD1.5
 Non-causal (diffusion attention has no mask).  Interpret mode on an
 explicitly requested CPU so the hermetic suite exercises the same code path.
 
-The transformer denoisers' joint attention runs ``_attn_kernel``'s body over
-a whole K in one pass: ``joint_attention`` (SD3's MMDiT) as it is,
-``rope_attention`` (FLUX) after RMS-normalizing q and k by head and
-rotating them by their tokens' rotary positions in VMEM.
+Joint attention: ``joint_attention`` (SD3's MMDiT) in a body of its own,
+``_joint_kernel`` (K in blocks, a ragged last block); ``rope_attention``
+(FLUX) in ``_attn_kernel``'s single pass over the whole K after RMS-norming
+q and k by head and rotating them by their rotary positions in VMEM.
 """
 
 from __future__ import annotations
@@ -250,41 +250,114 @@ def flash_attention(
 
 
 def _joint_block_q(lq: int, lk: int, itemsize: int) -> int:
-    """Queries a ``joint_attention`` program takes: the fewest blocks whose
-    float32 score tile ``[block_q, lk]`` (lanes padded to 128) keeps inside
-    the single-pass budget, split evenly and rounded up to 8 sublanes.  At
-    SD3's 1178 joint tokens: two blocks of 592, the last one 6 rows short."""
+    """Queries a program of one pass over the whole K takes (``rope_attention``):
+    the fewest blocks whose float32 score tile ``[block_q, lk]`` (lanes padded
+    to 128) keeps inside the single-pass budget, split evenly and rounded up
+    to 8 sublanes.  At FLUX's 1280 joint tokens: two blocks of 640."""
     tile = _TILE_SINGLE_PASS * 2 // itemsize
     blocks = -(-lq * _round_up(lk, 128) // tile)
     return min(lq, _round_up(-(-lq // blocks), 8))
 
 
-def joint_attention(q, k, v, interpret: bool | None = None):
+# keys a ``joint_attention`` program meets at once: a longer K is met in
+# blocks of this many and one ragged block of the rest (SD3's 1178 keys:
+# 512 + 512 + 154; chosen on the chip, PERF.md section 6)
+_JOINT_BLOCK_K = 512
+
+
+def _joint_blocks(lq: int, lk: int, itemsize: int) -> tuple:
+    """(block_q, block_k) of a ``joint_attention`` call: K in blocks of at
+    most ``_JOINT_BLOCK_K`` keys, and the queries in the fewest blocks whose
+    float32 score tile ``[block_q, block_k]`` (lanes padded to 128) keeps
+    inside the single-pass budget, split evenly on 8 sublanes: every one of
+    SD3's 1178 or 1024 queries in one block, faster on the chip than two
+    blocks of 592 (PERF.md section 6)."""
+    block_k = min(lk, _JOINT_BLOCK_K)
+    rows = _TILE_SINGLE_PASS * 2 // itemsize // _round_up(block_k, 128)
+    blocks = -(-lq // rows)
+    return min(lq, _round_up(-(-lq // blocks), 8)), block_k
+
+
+def _joint_kernel(q_ref, k_ref, v_ref, o_ref, *, heads: int, block_k: int, scale: float):
+    """One (batch, head group, q-block) program of ``joint_attention``.
+
+    K and V of the group sit whole in VMEM and are met ``block_k`` keys at
+    a time, then the keys left over as one ragged block of exactly their
+    number (a static slice to the end of the ref), unrolled, with a running
+    max and denominator, one head after the other (on the chip, 0.445 ms a
+    1178-query call; each block taken to both heads in turn, 0.463).  A
+    head is not sliced out of the group's lanes: its q keeps the group's
+    width with the other heads' lanes zero, so ``q k^T`` over the whole
+    width is that head's scores, and ``p v`` puts that head's output in its
+    own lanes (the MXU is 128 deep and 128 wide either way).  The softmax
+    is taken in base 2: ``2^(s c - max(s) c)`` with ``c = scale * log2(e)``
+    multiplying the float32 scores is ``e^(s scale - max(s) scale)``, one
+    multiply an element fewer than the scale and then ``exp``."""
+    width = q_ref.shape[-1]
+    d = width // heads
+    lk = k_ref.shape[0]
+    c = scale * math.log2(math.e)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    own = [(lane >= h * d) & (lane < (h + 1) * d) for h in range(heads)]
+    out = None
+    for h in range(heads):
+        q = jnp.where(own[h], q_ref[...], 0).astype(q_ref.dtype)
+        acc = m = l = None
+        for start in range(0, lk, block_k):
+            rows = pl.ds(start, min(block_k, lk - start))
+            s = jax.lax.dot_general(  # [bq, bk] f32
+                q, k_ref[rows, :], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_new = s.max(axis=-1, keepdims=True) * c
+            if m is not None:
+                m_new = jnp.maximum(m, m_new)
+            p = jnp.exp2(s * c - m_new)
+            pv = jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[rows, :], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            if m is None:
+                acc, l = pv, p.sum(axis=-1, keepdims=True)
+            else:
+                corr = jnp.exp2(m - m_new)
+                acc, l = acc * corr + pv, l * corr + p.sum(axis=-1, keepdims=True)
+            m = m_new
+        out = acc / l if out is None else jnp.where(own[h], acc / l, out)
+    o_ref[...] = out.astype(o_ref.dtype)
+
+
+def joint_attention(q, k, v, block_q: int | None = None, block_k: int | None = None,
+                    interpret: bool | None = None):
     """q: [B, Lq, H, D], k/v: [B, Lk, H, D] -> [B, Lq, H, D]: the MMDiT's
     attention over its joint sequence (image tokens, then text tokens).
 
     The joint length (1024 + 154 = 1178 at SD3's 512x512) has no
-    power-of-two divisor and exceeds ``flash_attention``'s 1024-key single
-    pass, so ``_choose_blocks`` would hand it to the caller's XLA fall-back.
-    Here K and V of a group of heads sit whole in one block (a block equal
-    to the array's dimension is legal at any length), every program is one
-    pass of ``_attn_kernel`` with no running rescale, and the queries come
-    in ``_joint_block_q`` blocks; the last block may run past ``Lq``: its
-    rows beyond are never written back.  Operands stay ``[B, L, H*D]`` as
-    the projections leave them, a lane-dense group of heads a program."""
+    power-of-two divisor, so ``flash_attention`` would hand it to its XLA
+    fall-back.  Here K and V of a group of heads sit whole in one block (a
+    block equal to the array's dimension is legal at any length) and
+    ``_joint_kernel`` meets them ``block_k`` keys at a time with a running
+    max and denominator, then the rest as one ragged block (1178 = 2 x 512 +
+    154: no key padded, nothing read past the end); a K of one block is a
+    single pass.  The queries come in ``block_q`` blocks; the last may run
+    past ``Lq``: its rows beyond are never written back.  ``block_q`` /
+    ``block_k`` default to ``_joint_blocks``'s.  Operands stay
+    ``[B, L, H*D]`` as the projections leave them, a lane-dense group of
+    heads a program."""
     if interpret is None:
         interpret = interpret_default()
     b, lq, h, d = q.shape
     lk = k.shape[1]
     group = math.gcd(min(128 // math.gcd(128, d), h), h)
-    block_q = _joint_block_q(lq, lk, q.dtype.itemsize)
-    note_attention_path("joint")
+    auto_q, auto_k = _joint_blocks(lq, lk, q.dtype.itemsize)
+    block_q, block_k = min(block_q or auto_q, lq), min(block_k or auto_k, lk)
+    note_attention_path("joint" if block_k == lk else "joint_kloop")
     q, k, v = (a.reshape(b, -1, h * d) for a in (q, k, v))
     q_spec = pl.BlockSpec((None, block_q, group * d), lambda n, g, i: (n, i, g))
     kv_spec = pl.BlockSpec((None, lk, group * d), lambda n, g, i: (n, 0, g))
     out = pl.pallas_call(
         partial(
-            _attn_kernel, heads=group, block_k=lk, scale=1.0 / math.sqrt(d),
+            _joint_kernel, heads=group, block_k=block_k, scale=1.0 / math.sqrt(d),
         ),
         grid=(b, h // group, pl.cdiv(lq, block_q)),
         in_specs=[q_spec, kv_spec, kv_spec],

@@ -20,10 +20,16 @@ kernel takes whole as one block, so it runs in the kernel):
 * one self-attention shape under ``vmap`` k=2 (the scheduler's bucket step)
 * the fused epilogue at B=1 ``none`` (64x64 and 128x128 latents), at B=4
   ``self`` (the reference's default stream batch), and under ``vmap`` k=2
+* ``joint_attention`` at SD3's two calls (``sd3flash4x512``: four stream
+  rows, 24 heads of 64, 1178 joint keys; queries the joint tokens or the
+  1024 image tokens of the last block), under ``vmap`` k=1 as its bucket
+  step runs them, and one at k=2
 
 Each attention line names the operand layout the call's shapes chose
 (``ops/pallas/attention.py``): ``packed`` (``[B, L, H*D]``, a group of heads
-a program; every head dim 64 call with an even head count) or ``per_head``.
+a program; every head dim 64 call with an even head count) or ``per_head``;
+each joint line the body the call took (``joint_kloop``: K in blocks and a
+ragged last block, or ``joint``: one pass).
 
 ``--tiny`` swaps in small shapes so the same code runs on the CPU in
 interpret mode (``JAX_PLATFORMS=cpu``) in seconds.  Prints one line per
@@ -81,6 +87,16 @@ ATTN_CASES_TINY = {
     "tiny_b4_cross_16x2x160_k7": ((4, 16, 2, 160), (4, 7, 2, 160), 0),
     "tiny_self_64x2x16_vmap2": ((1, 64, 2, 16), (1, 64, 2, 16), 2),
 }
+# name -> (q shape, kv shape, vmap k), for ``joint_attention``
+JOINT_CASES = {
+    "sd3_joint_1178x24x64_vmap1": ((4, 1178, 24, 64), (4, 1178, 24, 64), 1),
+    "sd3_joint_1024x24x64_k1178_vmap1": ((4, 1024, 24, 64), (4, 1178, 24, 64), 1),
+    "sd3_joint_1178x24x64_vmap2": ((4, 1178, 24, 64), (4, 1178, 24, 64), 2),
+}
+JOINT_CASES_TINY = {
+    "tiny_joint_96x2x64": ((1, 96, 2, 64), (1, 96, 2, 64), 0),
+    "tiny_joint_40x2x64_k1025_vmap2": ((1, 40, 2, 64), (1, 1025, 2, 64), 2),
+}
 # name -> (B, latent h=w, cfg_type, vmap k)
 EPILOGUE_CASES = {
     "b1_none_64": (1, 64, "none", 0),
@@ -97,7 +113,9 @@ EPILOGUE_CASES_TINY = {
 }
 
 
-def check_attention(cases: dict) -> dict:
+def check_attention(cases: dict, joint: bool = False) -> dict:
+    """``flash_attention`` (or, ``joint``, ``joint_attention``) at each case
+    against XLA attention at ``highest`` precision."""
     import jax
     import jax.numpy as jnp
 
@@ -105,6 +123,7 @@ def check_attention(cases: dict) -> dict:
     from ai_rtc_agent_tpu.ops.pallas.attention import (
         _xla_attention,
         flash_attention,
+        joint_attention,
     )
 
     def reference(q, k, v):
@@ -122,7 +141,7 @@ def check_attention(cases: dict) -> dict:
         q = jax.random.normal(k1, qs, jnp.bfloat16)
         k = jax.random.normal(k2, kvs, jnp.bfloat16)
         v = jax.random.normal(k3, kvs, jnp.bfloat16)
-        kernel, ref = flash_attention, reference
+        kernel, ref = joint_attention if joint else flash_attention, reference
         if vk:
             kernel, ref = jax.vmap(kernel), jax.vmap(ref)
         t0 = time.monotonic()
@@ -134,7 +153,7 @@ def check_attention(cases: dict) -> dict:
         ok = bool(np.isfinite(got).all() and diff < ATTN_ATOL)
         out[name] = {"ok": ok, "max_abs_diff": round(diff, 6), "path": path}
         print(
-            f"attention {name}: {path} max|d|={diff:.5f} tol={ATTN_ATOL} "
+            f"{'joint' if joint else 'attention'} {name}: {path} max|d|={diff:.5f} tol={ATTN_ATOL} "
             f"{'ok' if ok else 'FAIL'} ({time.monotonic() - t0:.1f}s)",
             flush=True,
         )
@@ -223,10 +242,11 @@ def main(argv=None) -> int:
     device = require_device()
     attn = check_attention(ATTN_CASES_TINY if args.tiny else ATTN_CASES)
     epi = check_epilogue(EPILOGUE_CASES_TINY if args.tiny else EPILOGUE_CASES)
-    ok = all(c["ok"] for c in (*attn.values(), *epi.values()))
+    joint = check_attention(JOINT_CASES_TINY if args.tiny else JOINT_CASES, joint=True)
+    ok = all(c["ok"] for c in (*attn.values(), *epi.values(), *joint.values()))
     print(json.dumps({
         "check": "kernel_parity", "ok": ok, "device": device,
-        "attention": attn, "epilogue": epi,
+        "attention": attn, "epilogue": epi, "joint_attention": joint,
     }), flush=True)
     return 0 if ok else 1
 

@@ -198,18 +198,62 @@ def test_joint_attention_matches_a_plain_softmax(lq, lk, heads, head_dim, dtype,
     v = jax.random.normal(keys[2], (2, lk, heads, head_dim), dtype)
     with count_attention_paths() as paths:
         got = PA.joint_attention(q, k, v, interpret=True)
-    assert dict(paths) == {"joint": 1}
+    # 77 keys are one block; SD3's 1178 are met in blocks and a ragged rest
+    assert dict(paths) == {"joint" if lk == 77 else "joint_kloop": 1}
     assert got.shape == q.shape and got.dtype == dtype
     want = PA._xla_attention(*(a.astype(jnp.float32) for a in (q, k, v)))
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), atol=atol)
 
 
+@pytest.mark.parametrize("vmap_k", [0, 2])
+@pytest.mark.parametrize(
+    "lq,lk,heads,head_dim,block_k,dtype,atol,path",
+    [
+        # SD3's two calls with the blocks ``_joint_blocks`` gives them
+        (1178, 1178, 2, 64, None, jnp.bfloat16, 2e-2, "joint_kloop"),
+        (1024, 1178, 2, 64, None, jnp.bfloat16, 2e-2, "joint_kloop"),
+        (200, 1024, 2, 64, 256, jnp.bfloat16, 2e-2, "joint_kloop"),  # the block divides K
+        (64, 96, 2, 64, None, jnp.bfloat16, 2e-2, "joint"),  # K shorter than a block
+        (40, 513, 2, 64, None, jnp.bfloat16, 2e-2, "joint_kloop"),  # a ragged block of one key
+        (64, 600, 4, 32, None, jnp.bfloat16, 2e-2, "joint_kloop"),  # four heads a lane tile
+        (77, 77, 2, 16, 32, jnp.float32, 2e-5, "joint_kloop"),  # float32: 2 x 32 + 13 keys
+    ],
+)
+def test_joint_attention_k_loop_matches_a_plain_softmax(
+    lq, lk, heads, head_dim, block_k, dtype, atol, path, vmap_k
+):
+    """The K loop against a plain softmax: every key weighed once, whether
+    the block divides K, K is shorter than a block, or the ragged last block
+    holds one key; alone and under ``vmap`` as the bucket step runs it."""
+    lead = (vmap_k,) if vmap_k else ()
+    keys = jax.random.split(jax.random.PRNGKey(lq * lk + vmap_k), 3)
+    q = jax.random.normal(keys[0], lead + (1, lq, heads, head_dim), dtype)
+    k = jax.random.normal(keys[1], lead + (1, lk, heads, head_dim), dtype)
+    v = jax.random.normal(keys[2], lead + (1, lk, heads, head_dim), dtype)
+    kernel = lambda q, k, v: PA.joint_attention(q, k, v, block_k=block_k, interpret=True)  # noqa: E731
+    ref = PA._xla_attention
+    if vmap_k:
+        kernel, ref = jax.vmap(kernel), jax.vmap(ref)
+    with count_attention_paths() as paths:
+        got = kernel(q, k, v)
+    assert dict(paths) == {path: 1}
+    assert got.shape == q.shape and got.dtype == dtype
+    want = ref(*(a.astype(jnp.float32) for a in (q, k, v)))
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), atol=atol)
+
+
 def test_joint_attention_query_blocks():
     """The fewest query blocks whose float32 score rows over the whole K fit
-    the single-pass budget, split evenly on 8 sublanes."""
+    the single-pass budget, split evenly on 8 sublanes (``rope_attention``'s
+    blocks); ``joint_attention`` meets SD3's 1178 keys in blocks of
+    ``_JOINT_BLOCK_K``, all its queries in one block."""
     assert PA._joint_block_q(1178, 1178, 2) == 592
     assert PA._joint_block_q(1024, 1178, 2) == 512
     assert PA._joint_block_q(77, 77, 4) == 77
+    assert PA._joint_blocks(1178, 1178, 2) == (1178, 512)
+    assert PA._joint_blocks(1024, 1178, 2) == (1024, 512)
+    assert PA._joint_blocks(96, 96, 2) == (96, 96)
+    assert PA._joint_blocks(77, 77, 4) == (77, 77)
 
 
 # every (lq, lk, heads, head_dim) the four UNet configurations reach (the

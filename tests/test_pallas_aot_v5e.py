@@ -24,7 +24,11 @@ from ai_rtc_agent_tpu.ops.pallas import (
     lane_sparse_outputs,
     mosaic_kernel_counts,
 )
-from ai_rtc_agent_tpu.ops.pallas.attention import flash_attention, rope_attention
+from ai_rtc_agent_tpu.ops.pallas.attention import (
+    flash_attention,
+    joint_attention,
+    rope_attention,
+)
 from ai_rtc_agent_tpu.ops.pallas.fused_scheduler import fused_stream_epilogue
 
 
@@ -98,6 +102,25 @@ def test_rope_attention_compiles_for_v5e(v5e, vmap_k, columns):
         ).compile()
     assert dict(paths) == {"rope": 1}
     assert mosaic_kernel_counts(compiled.as_text()) == {"rope_attention": 1}
+
+
+@pytest.mark.parametrize("lq", [1178, 1024])
+@pytest.mark.parametrize("vmap_k", [1, 2])
+def test_joint_attention_compiles_for_v5e(v5e, vmap_k, lq):
+    """SD3's joint attention at ``sd3flash4x512``'s shapes (four stream
+    rows, 24 heads of 64, 1178 joint keys; the joint tokens' queries, or the
+    1024 image tokens' of the last block) under ``vmap`` as the bucket step
+    runs it: K met in blocks with a ragged last block of the 154 keys left,
+    which Mosaic has to take as a static slice to the end of the ref, one
+    Mosaic call and no float32 re-layout around it."""
+    fn = jax.vmap(lambda q, k, v: joint_attention(q, k, v, interpret=False))
+    kv = v5e((vmap_k, 4, 1178, 24, 64), jnp.bfloat16)
+    with count_attention_paths() as paths:
+        compiled = jax.jit(fn).lower(v5e((vmap_k, 4, lq, 24, 64), jnp.bfloat16), kv, kv).compile()
+    assert dict(paths) == {"joint_kloop": 1}
+    text = compiled.as_text()
+    assert mosaic_kernel_counts(text) == {"joint_attention": 1}
+    assert f32_relayout_copies(text) == {"count": 0, "bytes": 0}
 
 
 # (B, tokens, heads, head_dim, context width or None for self-attention): the
