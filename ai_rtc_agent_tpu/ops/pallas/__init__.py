@@ -166,8 +166,9 @@ def count_attention_paths():
     as the projections leave them) or ``per_head`` (transposed to a head a
     program), of the ``joint_attention`` calls (the MMDiT's, 24 a pass at
     SD3's depth: ``joint_kloop`` where K is met in blocks, ``joint`` where
-    it is one block) and of the ``rope_attention`` calls (``rope``:
-    FLUX's, 15 a pass at ``fluxschnell2x512``'s depth).  A jitted function's Python body runs when
+    it is one block) and of the ``rope_attention`` calls (FLUX's, 15 a
+    pass at ``fluxschnell2x512``'s depth: ``rope_kloop`` where K is met in
+    blocks, ``rope`` where it is one block).  A jitted function's Python body runs when
     it is traced, so wrap its ``lower()``: the evidence, beside
     ``mosaic_kernel_counts``, of which kernel variant a compiled step
     holds."""

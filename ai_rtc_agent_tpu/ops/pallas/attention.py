@@ -40,8 +40,8 @@ explicitly requested CPU so the hermetic suite exercises the same code path.
 
 Joint attention: ``joint_attention`` (SD3's MMDiT) in a body of its own,
 ``_joint_kernel`` (K in blocks, a ragged last block); ``rope_attention``
-(FLUX) in ``_attn_kernel``'s single pass over the whole K after RMS-norming
-q and k by head and rotating them by their rotary positions in VMEM.
+(FLUX) in the same body after RMS-norming q and k by head and rotating them
+by their rotary positions in VMEM, each head's K once.
 """
 
 from __future__ import annotations
@@ -249,37 +249,32 @@ def flash_attention(
     return out[:, :lq]
 
 
-def _joint_block_q(lq: int, lk: int, itemsize: int) -> int:
-    """Queries a program of one pass over the whole K takes (``rope_attention``):
-    the fewest blocks whose float32 score tile ``[block_q, lk]`` (lanes padded
-    to 128) keeps inside the single-pass budget, split evenly and rounded up
-    to 8 sublanes.  At FLUX's 1280 joint tokens: two blocks of 640."""
-    tile = _TILE_SINGLE_PASS * 2 // itemsize
-    blocks = -(-lq * _round_up(lk, 128) // tile)
-    return min(lq, _round_up(-(-lq // blocks), 8))
-
-
 # keys a ``joint_attention`` program meets at once: a longer K is met in
 # blocks of this many and one ragged block of the rest (SD3's 1178 keys:
 # 512 + 512 + 154; chosen on the chip, PERF.md section 6)
 _JOINT_BLOCK_K = 512
+# the same for ``rope_attention`` (FLUX's 1280 keys: 640 + 640, 2.7 % faster
+# on the chip than 512 + 512 + 256; PERF.md section 6)
+_ROPE_BLOCK_K = 640
 
 
-def _joint_blocks(lq: int, lk: int, itemsize: int) -> tuple:
-    """(block_q, block_k) of a ``joint_attention`` call: K in blocks of at
-    most ``_JOINT_BLOCK_K`` keys, and the queries in the fewest blocks whose
-    float32 score tile ``[block_q, block_k]`` (lanes padded to 128) keeps
-    inside the single-pass budget, split evenly on 8 sublanes: every one of
-    SD3's 1178 or 1024 queries in one block, faster on the chip than two
-    blocks of 592 (PERF.md section 6)."""
-    block_k = min(lk, _JOINT_BLOCK_K)
+def _joint_blocks(lq: int, lk: int, itemsize: int, most_k: int = _JOINT_BLOCK_K) -> tuple:
+    """(block_q, block_k) of a ``joint_attention`` call (``rope_attention``'s
+    with ``most_k`` its own): K in blocks of at most ``most_k`` keys, and
+    the queries in the fewest blocks whose float32 score tile ``[block_q,
+    block_k]`` (lanes padded to 128) keeps inside the single-pass budget,
+    split evenly on 8 sublanes: every one of SD3's 1178 or 1024 queries in
+    one block, faster on the chip than two blocks of 592, and every one of
+    FLUX's 1280 (PERF.md section 6)."""
+    block_k = min(lk, most_k)
     rows = _TILE_SINGLE_PASS * 2 // itemsize // _round_up(block_k, 128)
     blocks = -(-lq // rows)
     return min(lq, _round_up(-(-lq // blocks), 8)), block_k
 
 
 def _joint_kernel(q_ref, k_ref, v_ref, o_ref, *, heads: int, block_k: int, scale: float):
-    """One (batch, head group, q-block) program of ``joint_attention``.
+    """One (batch, head group, q-block) program of ``joint_attention``, and
+    of ``rope_attention`` over its prepared q and K.
 
     K and V of the group sit whole in VMEM and are met ``block_k`` keys at
     a time, then the keys left over as one ragged block of exactly their
@@ -292,16 +287,18 @@ def _joint_kernel(q_ref, k_ref, v_ref, o_ref, *, heads: int, block_k: int, scale
     own lanes (the MXU is 128 deep and 128 wide either way).  The softmax
     is taken in base 2: ``2^(s c - max(s) c)`` with ``c = scale * log2(e)``
     multiplying the float32 scores is ``e^(s scale - max(s) scale)``, one
-    multiply an element fewer than the scale and then ``exp``."""
+    multiply an element fewer than the scale and then ``exp``.  A group of
+    one head is the whole width, and its q is taken as it is."""
     width = q_ref.shape[-1]
     d = width // heads
     lk = k_ref.shape[0]
     c = scale * math.log2(math.e)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
-    own = [(lane >= h * d) & (lane < (h + 1) * d) for h in range(heads)]
+    if heads > 1:
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+        own = [(lane >= h * d) & (lane < (h + 1) * d) for h in range(heads)]
     out = None
     for h in range(heads):
-        q = jnp.where(own[h], q_ref[...], 0).astype(q_ref.dtype)
+        q = q_ref[...] if heads == 1 else jnp.where(own[h], q_ref[...], 0).astype(q_ref.dtype)
         acc = m = l = None
         for start in range(0, lk, block_k):
             rows = pl.ds(start, min(block_k, lk - start))
@@ -401,14 +398,17 @@ def _norm_rotate(x, scale, cos, sin):
 
 
 def _rope_attn_kernel(q_ref, k_ref, v_ref, cos_ref, sin_ref, qs_ref, ks_ref, o_ref,
-                      qr_ref, kr_ref, *, heads: int, scale: float, text_tokens: int):
-    """One (batch, head group, q-block) program: q and k RMS-normed and
-    rotated into VMEM, then ``_attn_kernel``'s single pass over the whole
-    K.  A token before ``text_tokens`` takes row 0 of the norms' scales,
-    every later one row 1."""
+                      qr_ref, kr_ref, *, heads: int, block_k: int, scale: float,
+                      text_tokens: int):
+    """One (batch, head group, q-block) program: the group's K RMS-normed
+    and rotated into VMEM by the group's first q-block program (the scratch
+    outlives the q-block axis, which runs in order), its q block the same
+    way, then ``_joint_kernel``'s K loop over the prepared K.  A token
+    before ``text_tokens`` takes row 0 of the norms' scales, every later one
+    row 1."""
     bq, lk = q_ref.shape[0], k_ref.shape[0]
     d = q_ref.shape[1] // heads
-    row0 = pl.multiple_of(pl.program_id(2) * bq, 8 if bq % 8 == 0 else 1)
+    i = pl.program_id(2)
 
     def prepare(src, dst, scale_ref, first, rows):
         for r in range(0, rows, _ROPE_CHUNK):
@@ -422,12 +422,16 @@ def _rope_attn_kernel(q_ref, k_ref, v_ref, cos_ref, sin_ref, qs_ref, ks_ref, o_r
                     src[r:r + n, lanes], s, cos_ref[at, :], sin_ref[at, :]
                 )
 
-    prepare(k_ref, kr_ref, ks_ref, 0, lk)
-    prepare(q_ref, qr_ref, qs_ref, row0, bq)
-    _attn_kernel(qr_ref, kr_ref, v_ref, o_ref, heads=heads, block_k=lk, scale=scale)
+    @pl.when(i == 0)
+    def _():
+        prepare(k_ref, kr_ref, ks_ref, 0, lk)
+
+    prepare(q_ref, qr_ref, qs_ref, pl.multiple_of(i * bq, 8 if bq % 8 == 0 else 1), bq)
+    _joint_kernel(qr_ref, kr_ref, v_ref, o_ref, heads=heads, block_k=block_k, scale=scale)
 
 
 def rope_attention(qkv, cos, sin, q_scale, k_scale, heads: int, text_tokens: int = 0,
+                   block_q: int | None = None, block_k: int | None = None,
                    interpret: bool | None = None):
     """FLUX's attention over its joint sequence (text tokens, then image
     tokens), with q/k RMS norms and rotary positions.
@@ -440,16 +444,19 @@ def rope_attention(qkv, cos, sin, q_scale, k_scale, heads: int, text_tokens: int
     ``k_scale`` [2, D]: the RMS norms' learned scales of the text tokens
     (the first ``text_tokens``) and of the rest.  -> [B, L, H*D].
 
-    Per program the kernel normalizes and rotates its q block and the whole
-    K of its heads into VMEM (float32, back to the operands' dtype, as the
-    reference rounds), then runs ``_attn_kernel``'s single pass over the
-    whole K.  K and V of a head group sit whole in one block and the
-    queries come in ``_joint_block_q`` blocks, as in ``joint_attention``
-    (1280 joint tokens at 512x512: two blocks of 640); the tables are one
-    block fetched once a call.  Heads of 128 are one lane tile a program.
-    The norm is here and not before the call: computed on q and k outside,
-    a reduction over each head's lanes makes XLA re-lay both out in
-    float32."""
+    Each group of heads normalizes and rotates its K once and each q block
+    once into VMEM (float32, back to the operands' dtype, as the reference
+    rounds), then meets the prepared K as ``joint_attention`` does:
+    ``block_k`` keys at a time and the rest as one ragged block, with a
+    running float32 max and denominator and the softmax in base 2.  K and V
+    of a group sit whole in one block; ``block_q`` / ``block_k`` default to
+    ``_joint_blocks``'s with ``_ROPE_BLOCK_K`` (1280 joint tokens at
+    512x512: every query in one block, K in 640 + 640); with more than one
+    q block the first one's program prepares K for the rest.  The tables
+    are one block fetched once a call.  Heads of 128 are one lane tile a
+    program.  The norm is here and not before the call: computed on q and
+    k outside, a reduction over each head's lanes makes XLA re-lay both out
+    in float32."""
     if interpret is None:
         interpret = interpret_default()
     b, l, c = qkv.shape
@@ -462,7 +469,8 @@ def rope_attention(qkv, cos, sin, q_scale, k_scale, heads: int, text_tokens: int
         )
     group = math.gcd(min(128 // math.gcd(128, d), heads), heads)
     width, blocks = group * d, heads // group
-    block_q = _joint_block_q(l, l, qkv.dtype.itemsize)
+    auto_q, auto_k = _joint_blocks(l, l, qkv.dtype.itemsize, _ROPE_BLOCK_K)
+    block_q, block_k = min(block_q or auto_q, l), min(block_k or auto_k, l)
     rows = pl.cdiv(l, block_q) * block_q
     # each pair's cosine in both lanes, its sine negated in the even one,
     # zero rows past L
@@ -470,15 +478,15 @@ def rope_attention(qkv, cos, sin, q_scale, k_scale, heads: int, text_tokens: int
     sin2 = jnp.stack([-sin, sin], axis=-1).reshape(l, d).astype(jnp.float32)
     cos2, sin2 = (jnp.pad(t, ((0, rows - l), (0, 0))) for t in (cos2, sin2))
     q_scale, k_scale = (jnp.asarray(s, jnp.float32).reshape(2, d) for s in (q_scale, k_scale))
-    note_attention_path("rope")
+    note_attention_path("rope" if block_k == l else "rope_kloop")
     q_spec = pl.BlockSpec((None, block_q, width), lambda n, g, i: (n, i, g))
     k_spec = pl.BlockSpec((None, l, width), lambda n, g, i: (n, 0, blocks + g))
     v_spec = pl.BlockSpec((None, l, width), lambda n, g, i: (n, 0, 2 * blocks + g))
     table_spec = pl.BlockSpec((rows, d), lambda n, g, i: (0, 0))
     scale_spec = pl.BlockSpec((2, d), lambda n, g, i: (0, 0))
     return pl.pallas_call(
-        partial(_rope_attn_kernel, heads=group, scale=1.0 / math.sqrt(d),
-                text_tokens=text_tokens),
+        partial(_rope_attn_kernel, heads=group, block_k=block_k,
+                scale=1.0 / math.sqrt(d), text_tokens=text_tokens),
         grid=(b, blocks, rows // block_q),
         in_specs=[q_spec, k_spec, v_spec, table_spec, table_spec, scale_spec, scale_spec],
         out_specs=q_spec,
@@ -487,6 +495,9 @@ def rope_attention(qkv, cos, sin, q_scale, k_scale, heads: int, text_tokens: int
             pltpu.VMEM((block_q, width), qkv.dtype),
             pltpu.VMEM((l, width), qkv.dtype),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
         interpret=interpret,
         name="rope_attention",
     )(qkv, qkv, qkv, cos2, sin2, q_scale, k_scale)

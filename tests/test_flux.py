@@ -266,6 +266,23 @@ def test_linear1_splits_into_separate_qkv_and_mlp_weights():
 
 # -- rope_attention ----------------------------------------------------------------------
 
+def _rope_case(length, heads, head_dim, extra, dtype):
+    """(qkv, (cos, sin), (q_scale, k_scale)) of one seeded call."""
+    ks = jax.random.split(jax.random.PRNGKey(length), 4)
+    inner = heads * head_dim
+    qkv = jax.random.normal(ks[0], (2, length, 3 * inner + extra), dtype)
+    ang = jax.random.uniform(ks[1], (length, head_dim // 2), minval=-40.0, maxval=40.0)
+    scales = tuple(1.0 + 0.2 * jax.random.normal(k, (2, head_dim)) for k in ks[2:])
+    return qkv, (jnp.cos(ang), jnp.sin(ang)), scales
+
+
+def _rope_reference(qkv, rope, scales, heads, text):
+    """The model's XLA path (per-head RMS norm with the text and the image
+    tokens' scales, rotation, softmax) in float32."""
+    with jax.default_matmul_precision("highest"):
+        return F._attend(qkv.astype(jnp.float32), scales, rope, heads, text, "xla")
+
+
 @pytest.mark.parametrize(
     "length,heads,head_dim,extra,text,dtype,atol",
     [
@@ -273,31 +290,74 @@ def test_linear1_splits_into_separate_qkv_and_mlp_weights():
         (72, 2, 16, 0, 8, jnp.float32, 2e-5),
         # a single block's layout: the MLP's input after v, which is not read
         (300, 2, 128, 512, 0, jnp.float32, 2e-5),
-        # fluxschnell2x512's joint length: two query blocks of 640, 256 text tokens
+        # fluxschnell2x512's joint length: every query in one block, K in
+        # 640 + 640, 256 text tokens
         (1280, 1, 128, 0, 256, jnp.bfloat16, 2e-2),
     ],
 )
 def test_rope_attention_matches_norm_rotation_then_a_plain_softmax(
     length, heads, head_dim, extra, text, dtype, atol
 ):
-    """The kernel against the model's XLA path (per-head RMS norm with the
-    text and the image tokens' scales, rotation, softmax) in float32.  In
-    bfloat16 the normed and rotated q and k are rounded to the operands'
-    dtype before the scores, in the kernel as in the reference, so the
-    tolerance is bfloat16's on outputs of order one."""
-    ks = jax.random.split(jax.random.PRNGKey(length), 4)
-    inner = heads * head_dim
-    qkv = jax.random.normal(ks[0], (2, length, 3 * inner + extra), dtype)
-    ang = jax.random.uniform(ks[1], (length, head_dim // 2), minval=-40.0, maxval=40.0)
-    rope = (jnp.cos(ang), jnp.sin(ang))
-    scales = tuple(1.0 + 0.2 * jax.random.normal(k, (2, head_dim)) for k in ks[2:])
+    """The kernel against the model's XLA path in float32.  In bfloat16 the
+    normed and rotated q and k are rounded to the operands' dtype before the
+    scores, in the kernel as in the reference, so the tolerance is
+    bfloat16's on outputs of order one.  A K longer than ``_ROPE_BLOCK_K``
+    is met in blocks (``rope_kloop``), a shorter one in one (``rope``)."""
+    qkv, rope, scales = _rope_case(length, heads, head_dim, extra, dtype)
     with count_attention_paths() as paths:
         got = PA.rope_attention(qkv, *rope, *scales, heads, text, interpret=True)
-    assert dict(paths) == {"rope": 1}
-    assert got.shape == (2, length, inner) and got.dtype == dtype
-    with jax.default_matmul_precision("highest"):
-        want = F._attend(qkv.astype(jnp.float32), scales, rope, heads, text, "xla")
+    assert dict(paths) == {"rope_kloop" if length > PA._ROPE_BLOCK_K else "rope": 1}
+    assert got.shape == (2, length, heads * head_dim) and got.dtype == dtype
+    want = _rope_reference(qkv, rope, scales, heads, text)
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), atol=atol)
+
+
+@pytest.mark.parametrize(
+    "length,heads,head_dim,text,block_q,block_k",
+    [
+        # K of 700 in 640 + a ragged 60; 200 text tokens, inside the first
+        # block and off the 256-row preparation chunks
+        (700, 1, 128, 200, None, None),
+        # two heads a program, K in 64 + 64 + 64 + a ragged 8, the text
+        # tokens (40) ending inside the first block
+        (200, 2, 16, 40, None, 64),
+        # three query blocks (72 + 72 + 56 of a last block that runs past
+        # L): the first one's program prepares K for the other two
+        (200, 2, 16, 40, 72, 64),
+        (1280, 1, 128, 256, 640, 512),
+    ],
+)
+def test_rope_attention_meets_a_ragged_k_in_blocks(length, heads, head_dim, text,
+                                                   block_q, block_k):
+    """K met in blocks with a ragged last one, the text/image scale boundary
+    off every block edge, and K prepared once for several query blocks:
+    each against the plain float32 reference."""
+    qkv, rope, scales = _rope_case(length, heads, head_dim, 0, jnp.float32)
+    with count_attention_paths() as paths:
+        got = PA.rope_attention(qkv, *rope, *scales, heads, text, block_q=block_q,
+                                block_k=block_k, interpret=True)
+    assert dict(paths) == {"rope_kloop": 1}
+    want = _rope_reference(qkv, rope, scales, heads, text)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+def test_flux_at_512_meets_its_keys_in_blocks_in_every_call():
+    """A pass of ``fluxschnell2x512``'s stage (5 double and 10 single
+    blocks, 256 + 1024 joint tokens) calls ``rope_attention`` 15 times, each
+    meeting its 1280 keys in blocks; the tiny family's 72 keys are one
+    block (``test_each_family_traces_its_own_attention_path``)."""
+    fc = F.FluxConfig.schnell_stage()
+    params = jax.eval_shape(lambda: F.init_flux(jax.random.PRNGKey(0), fc))
+    x = jax.ShapeDtypeStruct((2, 64, 64, 16), jnp.bfloat16)
+    ctx = jax.ShapeDtypeStruct((2, fc.text_tokens, fc.joint_attention_dim), jnp.bfloat16)
+    pooled = jax.ShapeDtypeStruct((2, fc.pooled_projection_dim), jnp.bfloat16)
+    t = jax.ShapeDtypeStruct((2,), jnp.float32)
+    with count_attention_paths() as paths:
+        jax.eval_shape(
+            lambda p, x, t, c, v: F.apply_flux(p, x, t, c, fc, {"text_embeds": v}, "pallas"),
+            params, x, t, ctx, pooled,
+        )
+    assert dict(paths) == {"rope_kloop": 15}
 
 
 def test_rope_attention_refuses_tables_of_another_sequence():
@@ -309,9 +369,11 @@ def test_rope_attention_refuses_tables_of_another_sequence():
 
 
 def test_rope_attention_query_blocks():
-    """1280 joint tokens: two query blocks of 640 over the whole K."""
-    assert PA._joint_block_q(1280, 1280, 2) == 640
-    assert PA._joint_block_q(72, 72, 4) == 72
+    """1280 joint tokens: every query in one block over K in blocks of
+    ``_ROPE_BLOCK_K`` (``_joint_blocks``, ``joint_attention``'s chooser); the
+    tiny family's 72 in one block over one block of K."""
+    assert PA._joint_blocks(1280, 1280, 2, PA._ROPE_BLOCK_K) == (1280, 640)
+    assert PA._joint_blocks(72, 72, 4, PA._ROPE_BLOCK_K) == (72, 72)
 
 
 # -- the registry ----------------------------------------------------------------------

@@ -243,13 +243,8 @@ def test_joint_attention_k_loop_matches_a_plain_softmax(
 
 
 def test_joint_attention_query_blocks():
-    """The fewest query blocks whose float32 score rows over the whole K fit
-    the single-pass budget, split evenly on 8 sublanes (``rope_attention``'s
-    blocks); ``joint_attention`` meets SD3's 1178 keys in blocks of
+    """``joint_attention`` meets SD3's 1178 keys in blocks of
     ``_JOINT_BLOCK_K``, all its queries in one block."""
-    assert PA._joint_block_q(1178, 1178, 2) == 592
-    assert PA._joint_block_q(1024, 1178, 2) == 512
-    assert PA._joint_block_q(77, 77, 4) == 77
     assert PA._joint_blocks(1178, 1178, 2) == (1178, 512)
     assert PA._joint_blocks(1024, 1178, 2) == (1024, 512)
     assert PA._joint_blocks(96, 96, 2) == (96, 96)

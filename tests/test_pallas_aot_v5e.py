@@ -89,8 +89,9 @@ def test_rope_attention_compiles_for_v5e(v5e, vmap_k, columns):
     256 + 1024 joint tokens, 24 heads of 128; a double block's fused q/k/v,
     a single block's ``linear1`` output with the MLP's input after them)
     under ``vmap`` as the bucket step runs it: the norm's lane reduction,
-    the rotation's lane rolls, the scratch q and K and the whole-K single
-    pass are what Mosaic has to accept."""
+    the rotation's lane rolls, the scratch q and K, every query in one
+    block and K met in two blocks of 640 keys are what Mosaic has to
+    accept."""
     fn = jax.vmap(
         lambda x, c, s, qs, ks: rope_attention(x, c, s, qs, ks, 24, 256, interpret=False),
         in_axes=(0, None, None, None, None),
@@ -100,7 +101,7 @@ def test_rope_attention_compiles_for_v5e(v5e, vmap_k, columns):
         compiled = jax.jit(fn).lower(
             v5e((vmap_k, 2, 1280, columns), jnp.bfloat16), table, table, scale, scale
         ).compile()
-    assert dict(paths) == {"rope": 1}
+    assert dict(paths) == {"rope_kloop": 1}
     assert mosaic_kernel_counts(compiled.as_text()) == {"rope_attention": 1}
 
 
